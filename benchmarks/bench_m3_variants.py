@@ -186,7 +186,7 @@ def _deep_bench_population(args):
     shardings = None
     ctx = contextlib.nullcontext()
     if args.sharded:
-        from repro.compat import set_mesh
+        from jax import set_mesh
         from repro.distributed.sharding import (pop_axis_size,
                                                 population_shardings)
         from repro.launch.mesh import make_host_mesh

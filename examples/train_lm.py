@@ -13,8 +13,8 @@ import time
 
 import jax
 import jax.numpy as jnp
+from jax import set_mesh
 
-from repro.compat import set_mesh
 from repro.checkpoint import AsyncCheckpointer
 from repro.data import TokenTask
 from repro.launch.mesh import make_host_mesh

@@ -678,6 +678,17 @@ def fused_loss(params, x, targets, lp: LayeredPopulation,
     if loss_impl == "fused":
         cd = _resolve_compute_dtype(compute_dtype)
         cast = (lambda a: a) if cd is None else (lambda a: a.astype(cd))
+        from repro.distributed.sharding import pop_axis_size
+        n = pop_axis_size()
+        if n > 1:
+            why = _member_sharded_unsupported(lp, bd_impl, in_impl, n)
+            if why is None:
+                return _fused_loss_member_sharded(params, x, targets, lp,
+                                                  cast, n)
+            from repro.kernels.ops import _resolve_interpret
+            if not _resolve_interpret(None):
+                raise NotImplementedError(why)
+            # interpret-mode kernels are plain HLO that XLA partitions
         h = _hidden(params, x, lp, bd_impl, act_impl, None, compute_dtype,
                     in_impl)
         per = m3_loss_head(cast(h), cast(params["w_out"]), params["b_out"],
@@ -690,6 +701,64 @@ def fused_loss(params, x, targets, lp: LayeredPopulation,
     nll = -jnp.take_along_axis(
         logp, targets[:, None, None].astype(jnp.int32), axis=-1)[..., 0]
     per = nll.mean(axis=0)
+    return per.sum(), per
+
+
+def _member_sharded_unsupported(lp: LayeredPopulation, bd_impl: str,
+                                in_impl, n: int):
+    """Why ``_fused_loss_member_sharded`` cannot run this layout with its
+    members split ``n`` ways on the ambient mesh, or None when it can."""
+    from repro.distributed.sharding import mesh_axis_sizes
+    if lp.depth != 1 or _resolve_in_impl(in_impl, bd_impl) != "fused":
+        return ("member-sharded fused kernels cover depth-1 populations; "
+                "train deeper layouts on a mesh with --bd-impl einsum")
+    if mesh_axis_sizes().get("data", 1) > 1:
+        return "member-sharded fused kernels need a mesh with data axis 1"
+    pop = lp.layer_pop(0)
+    seg = np.asarray(pop.block_segment_ids)
+    p_loc = pop.num_members // n
+    if (pop.num_members % n or len(seg) % n
+            or np.any(seg.reshape(n, -1) // p_loc
+                      != np.arange(n)[:, None])):
+        return (f"the {n}-way member shards of {lp.describe()} do not own "
+                "their hidden tiles (shard_pad the layout to the mesh)")
+    return None
+
+
+def _fused_loss_member_sharded(params, x, targets, lp: LayeredPopulation,
+                               cast, n: int):
+    """``fused_loss``'s fused head with the members split over the mesh's
+    'model' axis.  XLA cannot partition a Mosaic kernel, so the fused input
+    and loss-head kernels run under ``jax.shard_map``: each shard projects
+    ITS members' hidden slice and scores them, with its slice of the
+    per-block tables (member ids rebased to the shard).  Members are
+    independent, so no collective enters the loss; the batch is whole on
+    every shard.  Layouts it cannot take are named by
+    ``_member_sharded_unsupported``."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro.distributed.sharding import POP_AXIS
+    from repro.kernels import ops
+
+    pop = lp.layer_pop(0)
+    p_loc = pop.num_members // n
+
+    def local(x, w_in, b_in, w_out, b_out, y, act_ids, mask, seg):
+        h = ops.fused_input(cast(x), cast(w_in), b_in.astype(jnp.float32),
+                            act_ids, mask, block=lp.block)
+        k = jax.lax.axis_index(POP_AXIS)
+        return ops.loss_head(cast(h), cast(w_out), b_out, y,
+                             seg - k * p_loc, block_h=lp.block)
+
+    rows, cols = P(POP_AXIS, None), P(None, POP_AXIS)
+    per = jax.shard_map(
+        local, in_specs=(P(), rows, P(POP_AXIS), cols, rows, P(),
+                         P(POP_AXIS), P(POP_AXIS), P(POP_AXIS)),
+        out_specs=P(POP_AXIS), check_vma=False)(
+        x, params["w_in"], params["b_in"], params["w_out"], params["b_out"],
+        targets, jnp.asarray(pop.block_act_ids, jnp.int32),
+        jnp.asarray(pop.hidden_mask, jnp.float32),
+        jnp.asarray(pop.block_segment_ids, jnp.int32))
     return per.sum(), per
 
 

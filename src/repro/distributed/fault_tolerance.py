@@ -6,8 +6,10 @@ length; the framework therefore treats the train loop as a RESUMABLE pure
 function of (checkpoint, step, data(step)):
 
   * ``TrainRunner`` — drives steps, checkpoints asynchronously every K
-    steps, and on ANY exception restores the last committed checkpoint and
-    replays (data is step-indexed → bitwise-identical replay).  Failure
+    steps, and on ANY exception prints it, restores the last committed
+    checkpoint and replays (data is step-indexed → bitwise-identical
+    replay: the restored state lands on the shardings the live state had
+    at that step, so the replay runs the same executables).  Failure
     injection hooks make this testable on one host
     (tests/test_fault_tolerance.py).
   * ``StragglerPolicy`` — wall-clock per-step watchdog.  On a real pod the
@@ -23,7 +25,9 @@ function of (checkpoint, step, data(step)):
 from __future__ import annotations
 
 import dataclasses
+import sys
 import time
+import traceback
 from typing import Callable, Optional
 
 import jax
@@ -67,7 +71,7 @@ def elastic_remesh(state_tree, spec_tree, axis_order=("data", "model"),
         if n % cand == 0:
             model = cand
             break
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((n // model, model), axis_order,
                      devices=np.asarray(devices))
     abstract = jax.tree.map(
@@ -77,6 +81,15 @@ def elastic_remesh(state_tree, spec_tree, axis_order=("data", "model"),
         lambda x, s: jax.device_put(np.asarray(jax.device_get(x)), s),
         state_tree, sh)
     return mesh, resharded
+
+
+def _shardings_of(tree):
+    """The sharding tree of a state whose leaves are all jax Arrays, else
+    None (host or scalar leaves place by default)."""
+    leaves = jax.tree.leaves(tree)
+    if not leaves or not all(isinstance(x, jax.Array) for x in leaves):
+        return None
+    return jax.tree.map(lambda x: x.sharding, tree)
 
 
 class TrainRunner:
@@ -142,11 +155,19 @@ class TrainRunner:
         # reads disk instead) and freed as soon as one commits.
         self._init_state_host = None if latest_steps(ckpt_dir) else \
             jax.tree.map(lambda x: np.asarray(jax.device_get(x)), state)
+        # shardings of the initial state and of the state after the last
+        # completed step: a replay restores onto them, so the restored
+        # state re-enters the jitted step with the SAME input shardings as
+        # the uninterrupted run (a spec-derived sharding that is equivalent
+        # but spelled differently keys a second executable, whose reduction
+        # order may differ in the last ulp)
+        self._init_shardings = _shardings_of(state)
+        self._live_shardings = self._init_shardings
 
-    def _put(self, host_tree):
-        if self.restore_shardings is not None:
-            return jax.tree.map(jax.device_put, host_tree,
-                                self.restore_shardings)
+    def _put(self, host_tree, shardings=None):
+        shardings = shardings or self.restore_shardings
+        if shardings is not None:
+            return jax.tree.map(jax.device_put, host_tree, shardings)
         return jax.tree.map(jax.device_put, host_tree)
 
     def _restore(self):
@@ -159,12 +180,15 @@ class TrainRunner:
                 raise RuntimeError(
                     f"no committed checkpoint under {self.ckpt.directory} "
                     "and the initial-state snapshot was already released")
-            self.state = self._put(self._init_state_host)
+            self.state = self._put(self._init_state_host,
+                                   self._init_shardings)
+            self._live_shardings = self._init_shardings
             if self.on_restore:
                 self.on_restore(0)
             return 0
-        self.state, step = restore(self.ckpt.directory, self.state,
-                                   shardings=self.restore_shardings)
+        self.state, step = restore(
+            self.ckpt.directory, self.state,
+            shardings=self._live_shardings or self.restore_shardings)
         step = self.ckpt_step_unmap(step) + 1
         if self.on_restore:
             self.on_restore(step)
@@ -180,6 +204,7 @@ class TrainRunner:
                 self.state, metrics = self.step_fn(self.state, step)
                 self.straggler.observe(step, time.time() - t0)
                 self.metrics_log.append((step, metrics))
+                self._live_shardings = _shardings_of(self.state)
                 self.ckpt.maybe_save(step, self.state)
                 if self._init_state_host is not None and self.ckpt.saved:
                     self._init_state_host = None  # a checkpoint committed
@@ -188,6 +213,10 @@ class TrainRunner:
                 raise
             except Exception as e:   # noqa: BLE001 — restart on ANY failure
                 self.restarts += 1
+                print(f"TrainRunner: step {step} failed (restart "
+                      f"{self.restarts}/{self.max_restarts}): "
+                      + "".join(traceback.format_exception_only(e)).strip(),
+                      file=sys.stderr, flush=True)
                 if self.restarts > self.max_restarts:
                     raise RuntimeError(
                         f"exceeded {self.max_restarts} restarts") from e

@@ -18,10 +18,9 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding
+from jax import set_mesh
+from jax.sharding import Mesh, NamedSharding, get_abstract_mesh
 from jax.sharding import PartitionSpec as P
-
-from repro.compat import get_abstract_mesh, set_mesh
 
 # canonical spec fragments
 BATCH_AXES = ("pod", "data")        # batch dim shards over both DP axes

@@ -28,31 +28,38 @@ parameter tile's dy^T·x over batch tiles (grid (param_tiles, b_tiles)).
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+# On-chip vector memory (VMEM) of one TPU v5e TensorCore, and Mosaic's
+# default scoped limit there: the budget below never asks for less than
+# the default nor for more than the chip has.
+VMEM_BYTES = 128 * 1024 * 1024
+_VMEM_DEFAULT_LIMIT = 16 * 1024 * 1024
+
+
 def tpu_compiler_params(dimension_semantics, *block_shapes, dtype_bytes=4):
     """Mosaic compiler params: dimension semantics (reduction dims are
     'arbitrary', independent dims 'parallel') and a VMEM budget derived from
-    the kernel's live blocks (double-buffered pipeline + accumulator slack),
-    floored so tiny-tile populations don't over-constrain the compiler.
-    Returns None when this jax build lacks the params class (the interpret
-    path ignores compiler params anyway)."""
-    cls = (getattr(pltpu, "CompilerParams", None)
-           or getattr(pltpu, "TPUCompilerParams", None))
-    if cls is None:
-        return None
-    import math
+    the kernel's live blocks — 4× their bytes (double-buffered pipeline +
+    accumulator and epilogue temporaries), at least Mosaic's default limit,
+    at most the chip's VMEM.  A kernel whose double-buffered blocks alone
+    exceed the chip fails here, naming its size, instead of inside the
+    compiler."""
     need = sum(math.prod(s) * dtype_bytes for s in block_shapes)
-    budget = max(4 * need, 2 * 1024 * 1024)
-    try:
-        return cls(dimension_semantics=tuple(dimension_semantics),
-                   vmem_limit_bytes=int(budget))
-    except TypeError:          # older signature without one of the fields
-        return cls(dimension_semantics=tuple(dimension_semantics))
+    if 2 * need > VMEM_BYTES:
+        raise ValueError(
+            f"kernel blocks need {2 * need} B of VMEM double-buffered, the "
+            f"chip has {VMEM_BYTES} B: shrink the batch or block tiles")
+    budget = min(max(4 * need, _VMEM_DEFAULT_LIMIT), VMEM_BYTES)
+    return pltpu.CompilerParams(
+        dimension_semantics=tuple(dimension_semantics),
+        vmem_limit_bytes=int(budget))
 
 
 # --------------------------------------------------------------------- #

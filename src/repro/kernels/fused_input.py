@@ -12,10 +12,11 @@ GEMM:
             g' = act'(x·W_in^T + b_in) · mask  (emitted instead of z0 when a
                                                VJP will consume it)
   backward  du = dy ⊙ g' formed in-register in ONE kernel that emits both
-            dx (du·W_in, accumulated across hidden tiles in an f32 scratch)
-            and dW_in (du^T·x, accumulated across batch tiles in an f32
-            scratch holding every hidden tile's slice).  db = Σ_b dy·g' is
-            one XLA fused reduce over arrays that exist anyway.
+            dx (du·W_in, accumulated across hidden tiles in a full-batch
+            f32 scratch) and dW_in (du^T·x, accumulated across the inner
+            batch tiles in one (block, block_f) f32 scratch — on-chip
+            memory independent of H).  db = Σ_b dy·g' is one XLA fused
+            reduce over arrays that exist anyway.
 
 Grid layout: the hidden axis is tiled at the population block size (the
 per-block activation id is scalar-prefetched, dispatched via lax.switch on
@@ -34,7 +35,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.block_diag import tpu_compiler_params
-from repro.kernels.fused_layer import _VAL_BRANCHES, _VAL_DERIV_BRANCHES
+from repro.kernels.epilogue import VAL_BRANCHES, VAL_DERIV_BRANCHES
 
 
 def pick_block_f(f_pad: int) -> int:
@@ -71,11 +72,11 @@ def _make_fwd_kernel(with_deriv: bool):
             u = acc_ref[...] + b_ref[...].astype(jnp.float32)
             m = m_ref[...].astype(jnp.float32)
             if with_deriv:
-                y, g = jax.lax.switch(act_ref[t], _VAL_DERIV_BRANCHES, u)
+                y, g = jax.lax.switch(act_ref[t], VAL_DERIV_BRANCHES, u)
                 y_ref[...] = (y * m).astype(y_ref.dtype)
                 g_ref[...] = (g * m).astype(g_ref.dtype)
             else:
-                y = jax.lax.switch(act_ref[t], _VAL_BRANCHES, u)
+                y = jax.lax.switch(act_ref[t], VAL_BRANCHES, u)
                 y_ref[...] = (y * m).astype(y_ref.dtype)
     return kernel
 
@@ -154,7 +155,7 @@ def _int8_fwd_kernel(act_ref, sc_ref, x_ref, w_ref, b_ref, m_ref, y_ref,
     def _epilogue():
         u = acc_ref[...] + b_ref[...].astype(jnp.float32)
         m = m_ref[...].astype(jnp.float32)
-        y = jax.lax.switch(act_ref[t], _VAL_BRANCHES, u)
+        y = jax.lax.switch(act_ref[t], VAL_BRANCHES, u)
         y_ref[...] = (y * m).astype(y_ref.dtype)
 
 
@@ -201,77 +202,90 @@ def fused_input_int8_fwd(x: jax.Array, w_q: jax.Array, w_scale: jax.Array,
 
 def _bwd_kernel(dy_ref, g_ref, x_ref, w_ref, dx_ref, dw_ref,
                 dx_acc_ref, dw_acc_ref):
-    """Grid (kf, i, t): feature tile OUTER (each emits an independent dx /
-    dw column stripe), batch tile middle, hidden tile INNER.  dx
-    accumulates over the inner hidden tiles; dw accumulates over the
-    middle batch tiles in a per-hidden-tile slice of a (H, block_f)
-    scratch — the dw output block (t, kf) is revisited across i, and the
-    final (complete) store at i = nb−1 is sequentially the last writer."""
-    i = pl.program_id(1)
-    nb = pl.num_programs(1)
-    t = pl.program_id(2)
-    nt = pl.num_programs(2)
-    blk = dy_ref.shape[1]
+    """Grid (kf, t, i): feature tile OUTER (each emits an independent dx /
+    dw column stripe), hidden tile middle, batch tile INNER — the same
+    two-level shape as the mid-layer backward (kernels/fused_layer.py).
+
+    dw: the (t, kf) parameter tile accumulates over the inner batch tiles
+    in a (block, block_f) f32 scratch and flushes on the last one, so the
+    scratch does not grow with the fused hidden width H.
+    dx: each batch tile's running sum over hidden tiles lives in its rows
+    of a full-batch (B, block_f) f32 scratch; x and dx are whole-batch
+    blocks resident for the feature stripe (their block index depends on
+    kf only), so x is read once per stripe and dx is written back once."""
+    t = pl.program_id(1)
+    nt = pl.num_programs(1)
+    i = pl.program_id(2)
+    nb = pl.num_programs(2)
+    bb = dy_ref.shape[0]
+    rows = pl.ds(pl.multiple_of(i * bb, bb), bb)
 
     du = dy_ref[...] * g_ref[...]          # dz0 never exists outside
                                            # this register
-    @pl.when(t == 0)
-    def _init_dx():
-        dx_acc_ref[...] = jnp.zeros_like(dx_acc_ref)
-
-    dx_acc_ref[...] += jax.lax.dot_general(
+    prev = dx_acc_ref[rows, :]
+    prev = jnp.where(t == 0, jnp.zeros_like(prev), prev)
+    acc = prev + jax.lax.dot_general(
         du, w_ref[...],
         dimension_numbers=(((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
+    dx_acc_ref[rows, :] = acc
 
     @pl.when(t == nt - 1)
     def _flush_dx():
-        dx_ref[...] = dx_acc_ref[...].astype(dx_ref.dtype)
+        dx_ref[rows, :] = acc.astype(dx_ref.dtype)
 
-    rows = pl.ds(t * blk, blk)
-    prev = dw_acc_ref[rows, :]
-    prev = jnp.where(i == 0, jnp.zeros_like(prev), prev)
-    acc = prev + jax.lax.dot_general(
-        du, x_ref[...],
+    @pl.when(i == 0)
+    def _init_dw():
+        dw_acc_ref[...] = jnp.zeros_like(dw_acc_ref)
+
+    dw_acc_ref[...] += jax.lax.dot_general(
+        du, x_ref[rows, :],
         dimension_numbers=(((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
-    dw_acc_ref[rows, :] = acc
-    dw_ref[...] = acc.astype(dw_ref.dtype)
+
+    @pl.when(i == nb - 1)
+    def _flush_dw():
+        dw_ref[...] = dw_acc_ref[...].astype(dw_ref.dtype)
 
 
 def fused_input_bwd(dy: jax.Array, gp: jax.Array, x: jax.Array,
                     w: jax.Array, *, block: int, block_b: int,
                     interpret: bool = False):
     """dy, g' (B, H), x (B, F_pad), w (H, F_pad) → (dx (B, F_pad),
-    dW (H, F_pad)) in ONE launch."""
+    dW (H, F_pad)) in ONE launch.  Batch must be padded to a block_b
+    multiple (the wrapper's ``_pad_axis`` guarantees it)."""
     b, h = dy.shape
     f_pad = x.shape[1]
+    if b % block_b:
+        raise ValueError(
+            f"fused input backward needs batch padded to a block_b "
+            f"multiple, got batch {b} with block_b {block_b}")
     block_f = pick_block_f(f_pad)
-    grid = (f_pad // block_f, b // block_b, h // block)
+    grid = (f_pad // block_f, h // block, b // block_b)
     dx, dw = pl.pallas_call(
         _bwd_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_b, block), lambda kf, i, t: (i, t)),
-            pl.BlockSpec((block_b, block), lambda kf, i, t: (i, t)),
-            pl.BlockSpec((block_b, block_f), lambda kf, i, t: (i, kf)),
-            pl.BlockSpec((block, block_f), lambda kf, i, t: (t, kf)),
+            pl.BlockSpec((block_b, block), lambda kf, t, i: (i, t)),
+            pl.BlockSpec((block_b, block), lambda kf, t, i: (i, t)),
+            pl.BlockSpec((b, block_f), lambda kf, t, i: (0, kf)),
+            pl.BlockSpec((block, block_f), lambda kf, t, i: (t, kf)),
         ],
         out_specs=[
-            pl.BlockSpec((block_b, block_f), lambda kf, i, t: (i, kf)),
-            pl.BlockSpec((block, block_f), lambda kf, i, t: (t, kf)),
+            pl.BlockSpec((b, block_f), lambda kf, t, i: (0, kf)),
+            pl.BlockSpec((block, block_f), lambda kf, t, i: (t, kf)),
         ],
-        scratch_shapes=[pltpu.VMEM((block_b, block_f), jnp.float32),
-                        pltpu.VMEM((h, block_f), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((b, block_f), jnp.float32),
+                        pltpu.VMEM((block, block_f), jnp.float32)],
         out_shape=[
             jax.ShapeDtypeStruct((b, f_pad), dy.dtype),
             jax.ShapeDtypeStruct((h, f_pad), dy.dtype),
         ],
         compiler_params=tpu_compiler_params(
             ("parallel", "arbitrary", "arbitrary"),
-            (block_b, block), (block_b, block), (block_b, block_f),
-            (block, block_f), (block_b, block_f), (block, block_f),
-            (block_b, block_f), (h, block_f)),
+            (block_b, block), (block_b, block), (b, block_f),
+            (block, block_f), (b, block_f), (block, block_f),
+            (b, block_f), (block, block_f)),
         interpret=interpret,
     )(dy, gp, x, w)
     return dx, dw

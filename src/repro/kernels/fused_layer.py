@@ -26,8 +26,9 @@ still in VMEM:
 Grid/tile metadata is the ragged flattened step layout shared with
 ``kernels/block_diag.py`` (``BlockDiagLayout``); the per-step activation id
 (the OUTPUT tile's segment activation) is scalar-prefetched and dispatched
-through ``lax.switch`` over the ten paper activations, exactly like
-kernels/seg_act.py — but only on the flush step of each output tile.
+through ``lax.switch`` over the kernel forms of the ten paper activations
+(kernels/epilogue.py), exactly like kernels/seg_act.py — but only on the
+flush step of each output tile.
 
 Mixed precision: operand tiles may be bf16 (``--compute-dtype bfloat16``);
 the accumulator and the bias add are always f32 (``preferred_element_type``
@@ -40,23 +41,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.activations import ACTIVATION_FNS
 from repro.kernels.block_diag import tpu_compiler_params
-
-
-def _deriv(fn):
-    """Elementwise derivative of an activation, via vjp at ones — traced
-    into the kernel body, so it runs on the VPU in the epilogue."""
-    def d(x):
-        return jax.vjp(fn, x)[1](jnp.ones_like(x))[0]
-    return d
-
-
-# (value, derivative) branch per activation — one lax.switch in the epilogue
-_VAL_DERIV_BRANCHES = tuple(
-    (lambda fn: (lambda x: (fn(x), _deriv(fn)(x))))(fn)
-    for fn in ACTIVATION_FNS)
-_VAL_BRANCHES = tuple(ACTIVATION_FNS)
+from repro.kernels.epilogue import VAL_BRANCHES, VAL_DERIV_BRANCHES
 
 
 # --------------------------------------------------------------------- #
@@ -86,11 +72,11 @@ def _make_fwd_kernel(with_deriv: bool):
             u = acc_ref[...] + b_ref[...].astype(jnp.float32)
             m = m_ref[...].astype(jnp.float32)
             if with_deriv:
-                y, g = jax.lax.switch(act_ref[s], _VAL_DERIV_BRANCHES, u)
+                y, g = jax.lax.switch(act_ref[s], VAL_DERIV_BRANCHES, u)
                 y_ref[...] = (y * m).astype(y_ref.dtype)
                 g_ref[...] = (g * m).astype(g_ref.dtype)
             else:
-                y = jax.lax.switch(act_ref[s], _VAL_BRANCHES, u)
+                y = jax.lax.switch(act_ref[s], VAL_BRANCHES, u)
                 y_ref[...] = (y * m).astype(y_ref.dtype)
     return kernel
 
@@ -176,7 +162,7 @@ def _int8_fwd_kernel(ins_ref, w_ids, outs_ref, first_ref, last_ref, act_ref,
     def _epilogue():
         u = acc_ref[...] + b_ref[...].astype(jnp.float32)
         m = m_ref[...].astype(jnp.float32)
-        y = jax.lax.switch(act_ref[s], _VAL_BRANCHES, u)
+        y = jax.lax.switch(act_ref[s], VAL_BRANCHES, u)
         y_ref[...] = (y * m).astype(y_ref.dtype)
 
 

@@ -5,7 +5,10 @@ Derived from the loss-head kernel (kernels/loss_head.py) by keeping its
 projection loop and REPLACING the epilogue: no targets, no NLL, no
 dlogits_base — the epilogue just adds the member bias to the still-in-VMEM
 f32 accumulator and stores the finished (block_b, O) logits tile straight
-into its member's slot of the (B, P, O) output.  With ``log_probs=True``
+into its member's slot of the member-major (P, B, O) output (member axis
+squeezed out of the block, so the block's last two dims are (block_b, O) —
+the TPU (8, 128) tiling rule; the bias is read the same way from a
+(P, 1, O) view).  With ``log_probs=True``
 the same stable logsumexp the loss head runs produces normalised
 log-probabilities instead — serving's soft-vote ensembles consume
 ``exp(log_probs)`` without any extra XLA softmax pass over the (B, P, O)
@@ -68,7 +71,7 @@ def _make_kernel(log_probs: bool):
                 lse = jnp.log(jnp.sum(jnp.exp(logits - mx), axis=1,
                                       keepdims=True)) + mx
                 logits = logits - lse
-            y_ref[...] = logits[:, None, :]
+            y_ref[...] = logits
     return kernel
 
 
@@ -76,8 +79,8 @@ def infer_head_fwd(h: jax.Array, w2: jax.Array, b2: jax.Array,
                    seg: jax.Array, num_members: int, *, block_h: int,
                    block_b: int, log_probs: bool,
                    interpret: bool = False) -> jax.Array:
-    """h (B, H), w2 (O, H), b2 (P, O) → logits (or log-probs) (B, P, O) f32.
-    Forward-only: one launch, no residual outputs."""
+    """h (B, H), w2 (O, H), b2 (P, O) → logits (or log-probs) (P, B, O)
+    f32, member-major.  Forward-only: one launch, no residual outputs."""
     b, hh = h.shape
     o = w2.shape[0]
     p = num_members
@@ -91,19 +94,20 @@ def infer_head_fwd(h: jax.Array, w2: jax.Array, b2: jax.Array,
                 pl.BlockSpec((block_b, block_h),
                              lambda i, t, seg_r: (i, t)),
                 pl.BlockSpec((o, block_h), lambda i, t, seg_r: (0, t)),
-                pl.BlockSpec((1, o), lambda i, t, seg_r: (seg_r[t], 0)),
+                pl.BlockSpec((None, 1, o),
+                             lambda i, t, seg_r: (seg_r[t], 0, 0)),
             ],
-            out_specs=pl.BlockSpec((block_b, 1, o),
-                                   lambda i, t, seg_r: (i, seg_r[t], 0)),
+            out_specs=pl.BlockSpec((None, block_b, o),
+                                   lambda i, t, seg_r: (seg_r[t], i, 0)),
             scratch_shapes=[pltpu.VMEM((block_b, o), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, p, o), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((p, b, o), jnp.float32),
         compiler_params=tpu_compiler_params(
             ("arbitrary", "arbitrary"),
             (block_b, block_h), (o, block_h), (1, o),
             (block_b, o), (block_b, o)),
         interpret=interpret,
-    )(seg, h, w2, b2)
+    )(seg, h, w2, b2.reshape(p, 1, o))
 
 
 # --------------------------------------------------------------------- #
@@ -143,7 +147,7 @@ def _make_int8_kernel(log_probs: bool):
                 lse = jnp.log(jnp.sum(jnp.exp(logits - mx), axis=1,
                                       keepdims=True)) + mx
                 logits = logits - lse
-            y_ref[...] = logits[:, None, :]
+            y_ref[...] = logits
     return kernel
 
 
@@ -152,8 +156,8 @@ def infer_head_int8_fwd(h: jax.Array, w2_q: jax.Array, w2_scale: jax.Array,
                         block_h: int, block_b: int, log_probs: bool,
                         interpret: bool = False) -> jax.Array:
     """h (B, H), w2_q (O, H) int8, w2_scale (H/block_h,) f32
-    scalar-prefetch, b2 (P, O) → logits (or log-probs) (B, P, O) f32.
-    Forward-only, one launch."""
+    scalar-prefetch, b2 (P, O) → logits (or log-probs) (P, B, O) f32,
+    member-major.  Forward-only, one launch."""
     b, hh = h.shape
     o = w2_q.shape[0]
     p = num_members
@@ -167,16 +171,17 @@ def infer_head_int8_fwd(h: jax.Array, w2_q: jax.Array, w2_scale: jax.Array,
                 pl.BlockSpec((block_b, block_h),
                              lambda i, t, seg_r, sc: (i, t)),
                 pl.BlockSpec((o, block_h), lambda i, t, seg_r, sc: (0, t)),
-                pl.BlockSpec((1, o), lambda i, t, seg_r, sc: (seg_r[t], 0)),
+                pl.BlockSpec((None, 1, o),
+                             lambda i, t, seg_r, sc: (seg_r[t], 0, 0)),
             ],
-            out_specs=pl.BlockSpec((block_b, 1, o),
-                                   lambda i, t, seg_r, sc: (i, seg_r[t], 0)),
+            out_specs=pl.BlockSpec((None, block_b, o),
+                                   lambda i, t, seg_r, sc: (seg_r[t], i, 0)),
             scratch_shapes=[pltpu.VMEM((block_b, o), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, p, o), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((p, b, o), jnp.float32),
         compiler_params=tpu_compiler_params(
             ("arbitrary", "arbitrary"),
             (block_b, block_h), (o, block_h), (1, o),
             (block_b, o), (block_b, o)),
         interpret=interpret,
-    )(seg, w2_scale, h, w2_q, b2)
+    )(seg, w2_scale, h, w2_q, b2.reshape(p, 1, o))

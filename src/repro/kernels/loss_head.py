@@ -11,14 +11,20 @@ while each member's logits tile is still in VMEM:
             (1, P) f32 scratch across the grid, ONE launch for projection
             AND loss.  The backward's seed, dlogits_base =
             (softmax(z) − onehot(target)) / B, is emitted in the same
-            epilogue (instead of the logits) — the only (B, P, O) array
+            epilogue (instead of the logits) — the only (P, B, O) array
             that ever touches HBM, and the logits never do.
   backward  ONE kernel reads dlogits_base, scales by the incoming
-            per-member cotangent d_per[m] (a (1, P) block, scalar per
-            member tile), and emits both dh (dl·W_out, direct per-tile
-            writes) and dW_out (dl^T·h, accumulated across batch tiles).
+            per-member cotangent d_per[m] (one (1, 1) block per member
+            tile), and emits both dh (dl·W_out, direct per-tile writes)
+            and dW_out (dl^T·h, accumulated across batch tiles).
             db_out = d_per ⊙ Σ_b dlogits_base is one XLA fused reduce over
             the array that exists anyway.
+
+Block shapes follow the TPU (8, 128) tiling rule: every per-member operand
+is laid out MEMBER-MAJOR with the member axis squeezed out of the block
+(bias (P, 1, O) → (1, O) blocks, d_per (P, 1, 1) → (1, 1) blocks,
+dlogits_base (P, B, O) → (block_b, O) blocks), so the last two block dims
+are always either whole array dims or (8, 128)-aligned.
 
 Grid/tile metadata is the per-block member id (``block_segment_ids``)
 scalar-prefetched exactly like kernels/m3_matmul.py: member boundaries
@@ -83,14 +89,17 @@ def _make_fwd_kernel(inv_b: float, with_dl: bool):
             valid = (tgt >= 0).astype(jnp.float32)     # −1 marks batch pad
             cols = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
             onehot = (cols == tgt).astype(jnp.float32)
-            nll = (lse[:, 0] - jnp.sum(logits * onehot, axis=1)) * valid[:, 0]
+            # every reduction keeps its operand 2-D (Mosaic lowers no
+            # rank-1 vector reductions)
+            nll = (lse - jnp.sum(logits * onehot, axis=1, keepdims=True)
+                   ) * valid                           # (bb, 1)
+            tot = jnp.sum(nll, axis=0, keepdims=True) * inv_b   # (1, 1)
             p_ = per_acc.shape[1]
             mrow = (jax.lax.broadcasted_iota(jnp.int32, (1, p_), 1)
                     == seg_t).astype(jnp.float32)
-            per_acc[...] += mrow * (jnp.sum(nll) * inv_b)
+            per_acc[...] += mrow * tot
             if with_dl:
-                dl_ref[...] = ((ex / den - onehot)
-                               * (valid * inv_b))[:, None, :]
+                dl_ref[...] = (ex / den - onehot) * (valid * inv_b)
 
         @pl.when(jnp.logical_and(i == ni - 1, t == nt - 1))
         def _flush_per():
@@ -103,7 +112,7 @@ def loss_head_fwd(h: jax.Array, w2: jax.Array, b2: jax.Array,
                   b_real: int, block_h: int, block_b: int, with_dl: bool,
                   interpret: bool = False):
     """h (B, H), w2 (O, H), b2 (P, O), targets (B, 1) int32 (−1 = pad row)
-    → per-member mean NLL (1, P) f32 [, dlogits_base (B, P, O) f32]."""
+    → per-member mean NLL (1, P) f32 [, dlogits_base (P, B, O) f32]."""
     b, hh = h.shape
     o = w2.shape[0]
     p = num_members
@@ -111,9 +120,9 @@ def loss_head_fwd(h: jax.Array, w2: jax.Array, b2: jax.Array,
     out_shape = [jax.ShapeDtypeStruct((1, p), jnp.float32)]
     out_specs = [pl.BlockSpec((1, p), lambda i, t, seg_r: (0, 0))]
     if with_dl:
-        out_shape.append(jax.ShapeDtypeStruct((b, p, o), jnp.float32))
-        out_specs.append(pl.BlockSpec((block_b, 1, o),
-                                      lambda i, t, seg_r: (i, seg_r[t], 0)))
+        out_shape.append(jax.ShapeDtypeStruct((p, b, o), jnp.float32))
+        out_specs.append(pl.BlockSpec((None, block_b, o),
+                                      lambda i, t, seg_r: (seg_r[t], i, 0)))
     res = pl.pallas_call(
         _make_fwd_kernel(1.0 / b_real, with_dl),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -123,7 +132,8 @@ def loss_head_fwd(h: jax.Array, w2: jax.Array, b2: jax.Array,
                 pl.BlockSpec((block_b, block_h),
                              lambda i, t, seg_r: (i, t)),
                 pl.BlockSpec((o, block_h), lambda i, t, seg_r: (0, t)),
-                pl.BlockSpec((1, o), lambda i, t, seg_r: (seg_r[t], 0)),
+                pl.BlockSpec((None, 1, o),
+                             lambda i, t, seg_r: (seg_r[t], 0, 0)),
                 pl.BlockSpec((block_b, 1), lambda i, t, seg_r: (i, 0)),
             ],
             out_specs=out_specs if with_dl else out_specs[0],
@@ -136,7 +146,7 @@ def loss_head_fwd(h: jax.Array, w2: jax.Array, b2: jax.Array,
             (block_b, block_h), (o, block_h), (1, o), (block_b, 1),
             (1, p), (block_b, o), (block_b, o), (1, p)),
         interpret=interpret,
-    )(seg, h, w2, b2, targets)
+    )(seg, h, w2, b2.reshape(p, 1, o), targets)
     return res
 
 
@@ -152,7 +162,7 @@ def _bwd_kernel(seg_ref, dper_ref, dl_ref, h_ref, w_ref, dh_ref, dw_ref,
     i = pl.program_id(1)
     nb = pl.num_programs(1)
 
-    dl = dl_ref[...][:, 0, :] * dper_ref[0, 0]     # (bb, O) · d_per[member]
+    dl = dl_ref[...] * dper_ref[...]               # (bb, O) · d_per[member]
     dh_ref[...] = jax.lax.dot_general(
         dl.astype(w_ref.dtype), w_ref[...],
         dimension_numbers=(((1,), (0,)), ((), ())),
@@ -175,10 +185,11 @@ def _bwd_kernel(seg_ref, dper_ref, dl_ref, h_ref, w_ref, dh_ref, dw_ref,
 def loss_head_bwd(dper: jax.Array, dl: jax.Array, h: jax.Array,
                   w2: jax.Array, seg: jax.Array, *, block_h: int,
                   block_b: int, interpret: bool = False):
-    """dper (1, P) f32, dl (B, P, O) f32 → (dh (B, H), dW_out (O, H)) in
+    """dper (P,) f32, dl (P, B, O) f32 → (dh (B, H), dW_out (O, H)) in
     ONE launch."""
     b, hh = h.shape
     o = w2.shape[0]
+    p = dl.shape[0]
     grid = (hh // block_h, b // block_b)
     dh, dw = pl.pallas_call(
         _bwd_kernel,
@@ -186,9 +197,10 @@ def loss_head_bwd(dper: jax.Array, dl: jax.Array, h: jax.Array,
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, 1), lambda t, i, seg_r: (0, seg_r[t])),
-                pl.BlockSpec((block_b, 1, o),
-                             lambda t, i, seg_r: (i, seg_r[t], 0)),
+                pl.BlockSpec((None, 1, 1),
+                             lambda t, i, seg_r: (seg_r[t], 0, 0)),
+                pl.BlockSpec((None, block_b, o),
+                             lambda t, i, seg_r: (seg_r[t], i, 0)),
                 pl.BlockSpec((block_b, block_h),
                              lambda t, i, seg_r: (i, t)),
                 pl.BlockSpec((o, block_h), lambda t, i, seg_r: (0, t)),
@@ -209,5 +221,5 @@ def loss_head_bwd(dper: jax.Array, dl: jax.Array, h: jax.Array,
             (1, 1), (block_b, o), (block_b, block_h), (o, block_h),
             (block_b, block_h), (o, block_h), (o, block_h)),
         interpret=interpret,
-    )(seg, dper, dl, h, w2)
+    )(seg, dper.reshape(p, 1, 1), dl, h, w2)
     return dh, dw

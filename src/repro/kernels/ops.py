@@ -2,8 +2,13 @@
 
 Handle: batch/feature padding to block multiples, dtype policy, the
 custom_vjp that routes the M3 backward through the transposed kernels, and
-the ``interpret`` switch (True = run the kernel body in Python on CPU; the
-container has no TPU — interpret mode is how correctness is validated here).
+the ``interpret`` switch: ``None`` (the default everywhere) compiles the
+kernels with Mosaic on a TPU backend and runs the kernel body through the
+Pallas interpreter on the CPU backend — where the tests validate them.  Any
+other backend raises: no accelerator silently falls back to the
+interpreter.  Every wrapper hands the kernels the SAME shapes on both
+backends (no platform-dependent padding), so the CPU tests exercise the
+block shapes the chip compiles.
 """
 from __future__ import annotations
 
@@ -25,11 +30,33 @@ from repro.kernels import seg_act as _segk
 
 
 def _resolve_interpret(interpret) -> bool:
-    """None → auto: compile on TPU, interpret elsewhere (CPU containers run
-    the kernel body in Python for correctness validation)."""
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
+    """None → from the backend: compiled on ``tpu``, interpreted on
+    ``cpu``.  Any other backend raises rather than interpreting on an
+    accelerator."""
+    if interpret is not None:
+        return bool(interpret)
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels compile for 'tpu' and interpret on 'cpu'; backend "
+        f"{backend!r} has neither (run with JAX_PLATFORMS=cpu or on a TPU)")
+
+
+# Mosaic tiles the last dim of every block in 128-lane units
+_LANES = 128
+
+
+def _check_block(block: int, interpret: bool):
+    """Compiled kernels tile the fused hidden axis at the population block;
+    a block that is not a whole number of lane tiles cannot compile."""
+    if not interpret and block % _LANES:
+        raise ValueError(
+            f"population block {block} is not a multiple of {_LANES}: the "
+            "compiled Pallas kernels tile the hidden axis in 128-lane "
+            f"blocks — pass --population-block {_LANES} (or a multiple)")
 
 
 def _pad_axis(x: jax.Array, axis: int, mult: int):
@@ -79,18 +106,17 @@ def m3_matmul(h: jax.Array, w2: jax.Array, block_seg_ids: np.ndarray,
 
     h (B, H), w2 (O, H), per-block member ids (H/block_h,) -> (B, M, O).
     H must already be block_h-aligned (Population guarantees this).
-    ``interpret=None`` auto-selects: compiled on TPU, interpreter elsewhere.
+    ``interpret=None`` auto-selects: compiled on TPU, interpreted on CPU.
     """
     interpret = _resolve_interpret(interpret)
+    _check_block(block_h, interpret)
     if h.shape[1] % block_h:
         raise ValueError(f"hidden axis {h.shape[1]} not {block_h}-aligned")
     block_b = min(block_b, max(8, 1 << (h.shape[0] - 1).bit_length()))
     hp, b0 = _pad_axis(h, 0, block_b)
-    # O padding: kernels keep full O in-block; pad to 128 lanes for TPU layout
-    w2p, o0 = _pad_axis(w2, 0, 128 if not interpret else 1)
     seg_t = tuple(int(s) for s in np.asarray(block_seg_ids, np.int32))
-    y = _m3_core(hp, w2p, seg_t, num_members, block_h, block_b, interpret)
-    return y[:b0, :, :o0]
+    y = _m3_core(hp, w2, seg_t, num_members, block_h, block_b, interpret)
+    return y[:b0]
 
 
 # --------------------------------------------------------------------- #
@@ -168,9 +194,10 @@ def block_diag_gemm(h: jax.Array, wb: jax.Array, layout, *,
     ``layout`` a static ``repro.core.population.BlockDiagLayout`` →
     (B, n_out_tiles·blk).  Pass-through members are identity-copied via the
     shared appended identity tile and contribute no weight gradient.
-    ``interpret=None`` auto-selects: compiled on TPU, interpreter elsewhere.
+    ``interpret=None`` auto-selects: compiled on TPU, interpreted on CPU.
     """
     interpret = _resolve_interpret(interpret)
+    _check_block(layout.block, interpret)
     if h.shape[1] != layout.n_in_tiles * layout.block:
         raise ValueError(f"input axis {h.shape[1]} != "
                          f"{layout.n_in_tiles}×{layout.block}")
@@ -248,9 +275,10 @@ def fused_layer(h: jax.Array, wb: jax.Array, b_eff: jax.Array, layout,
     static ``BlockDiagLayout``, ``block_act_ids`` the OUTPUT layer's
     per-block activation ids, ``mask`` its hidden mask →
     (B, n_out_tiles·blk) of ``act(h·W + b)·mask``.
-    ``interpret=None`` auto-selects: compiled on TPU, interpreter elsewhere.
+    ``interpret=None`` auto-selects: compiled on TPU, interpreted on CPU.
     """
     interpret = _resolve_interpret(interpret)
+    _check_block(layout.block, interpret)
     if h.shape[1] != layout.n_in_tiles * layout.block:
         raise ValueError(f"input axis {h.shape[1]} != "
                          f"{layout.n_in_tiles}×{layout.block}")
@@ -287,6 +315,7 @@ def fused_layer_infer(h: jax.Array, wb: jax.Array, b_eff: jax.Array, layout,
     residual here, it fails loudly instead (DESIGN.md §10).  The freed VMEM
     pays for the bigger default batch tile."""
     interpret = _resolve_interpret(interpret)
+    _check_block(layout.block, interpret)
     if h.shape[1] != layout.n_in_tiles * layout.block:
         raise ValueError(f"input axis {h.shape[1]} != "
                          f"{layout.n_in_tiles}×{layout.block}")
@@ -325,6 +354,7 @@ def fused_layer_infer_int8(h: jax.Array, wb_q: jax.Array,
     weight array never exists in this program."""
     interpret = _resolve_interpret(interpret)
     blk = layout.block
+    _check_block(blk, interpret)
     if h.shape[1] != layout.n_in_tiles * blk:
         raise ValueError(f"input axis {h.shape[1]} != "
                          f"{layout.n_in_tiles}×{blk}")
@@ -361,38 +391,38 @@ def fused_layer_infer_int8(h: jax.Array, wb_q: jax.Array,
 # fused input layer: dense GEMM + bias + activation epilogue            #
 # --------------------------------------------------------------------- #
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _fin_core(x, w, b, acts_s, mask_s, block, block_b, interpret):
-    """Primal (no-grad contexts, e.g. eval): single-output kernel."""
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _fin_core(x, w, b, act_ids, mask, block, block_b, interpret):
+    """Primal (no-grad contexts, e.g. eval): single-output kernel.  The
+    per-block activation ids and the hidden mask are OPERANDS (not static
+    arguments), so a member-sharded caller can hand each shard its own
+    slice (core.deep's shard_map path)."""
     return _fik.fused_input_fwd(
-        x, w, jnp.reshape(b, (1, -1)).astype(jnp.float32),
-        jnp.asarray(mask_s.arr).reshape(1, -1), jnp.asarray(acts_s.arr),
+        x, w, jnp.reshape(b, (1, -1)).astype(jnp.float32), mask, act_ids,
         block=block, block_b=block_b, with_deriv=False, interpret=interpret)
 
 
-def _fin_fwd(x, w, b, acts_s, mask_s, block, block_b, interpret):
+def _fin_fwd(x, w, b, act_ids, mask, block, block_b, interpret):
     y, gp = _fik.fused_input_fwd(
-        x, w, jnp.reshape(b, (1, -1)).astype(jnp.float32),
-        jnp.asarray(mask_s.arr).reshape(1, -1), jnp.asarray(acts_s.arr),
+        x, w, jnp.reshape(b, (1, -1)).astype(jnp.float32), mask, act_ids,
         block=block, block_b=block_b, with_deriv=True, interpret=interpret)
     return y, (x, w, gp)
 
 
-def _fin_bwd(acts_s, mask_s, block, block_b, interpret, res, dy):
+def _fin_bwd(block, block_b, interpret, res, dy):
     x, w, gp = res
     dx, dw = _fik.fused_input_bwd(dy, gp, x, w, block=block,
                                   block_b=block_b, interpret=interpret)
     # bias cotangent: one fused XLA reduce over tiles that exist anyway
     db = (dy.astype(jnp.float32) * gp.astype(jnp.float32)).sum(axis=0)
-    return dx, dw, db.astype(jnp.float32)
+    return dx, dw, db.astype(jnp.float32), None, None
 
 
 _fin_core.defvjp(_fin_fwd, _fin_bwd)
 
 
 def fused_input(x: jax.Array, w_in: jax.Array, b_in: jax.Array,
-                block_act_ids: np.ndarray, mask: np.ndarray, *,
-                block: int, block_b: int = 128,
+                block_act_ids, mask, *, block: int, block_b: int = 128,
                 interpret: bool | None = None) -> jax.Array:
     """Dense input projection + bias + per-segment activation + padding
     mask in one Pallas pass (kernels/fused_input.py; DESIGN.md §9);
@@ -400,11 +430,14 @@ def fused_input(x: jax.Array, w_in: jax.Array, b_in: jax.Array,
 
     x (B, F), w_in (H, F) the stacked first-layer weight, ``b_in`` (H,),
     ``block_act_ids`` the first hidden layer's per-block activation ids,
-    ``mask`` its hidden mask → (B, H) of ``act(x·W_in^T + b_in)·mask``.
+    ``mask`` its hidden mask → (B, H) of ``act(x·W_in^T + b_in)·mask``;
+    the two tables may be numpy constants or traced arrays (one member
+    shard's slice under ``shard_map``).
     H must already be block-aligned (Population guarantees this).
-    ``interpret=None`` auto-selects: compiled on TPU, interpreter elsewhere.
+    ``interpret=None`` auto-selects: compiled on TPU, interpreted on CPU.
     """
     interpret = _resolve_interpret(interpret)
+    _check_block(block, interpret)
     h = w_in.shape[0]
     if h % block:
         raise ValueError(f"hidden axis {h} not {block}-aligned")
@@ -419,8 +452,9 @@ def fused_input(x: jax.Array, w_in: jax.Array, b_in: jax.Array,
     fmult = 8 if x.shape[1] <= 128 else 128
     xp, _ = _pad_axis(xp, 1, fmult)
     wp, _ = _pad_axis(w_in, 1, fmult)
-    y = _fin_core(xp, wp, b_in, _StaticArray(block_act_ids, np.int32),
-                  _StaticArray(mask, np.float32), block, block_b, interpret)
+    y = _fin_core(xp, wp, b_in, jnp.asarray(block_act_ids, jnp.int32),
+                  jnp.asarray(mask, jnp.float32).reshape(1, -1), block,
+                  block_b, interpret)
     return y[:b0]
 
 
@@ -432,6 +466,7 @@ def fused_input_infer(x: jax.Array, w_in: jax.Array, b_in: jax.Array,
     unconditionally — no g' residual can be emitted, and the freed VMEM
     pays for the bigger default batch tile (DESIGN.md §10)."""
     interpret = _resolve_interpret(interpret)
+    _check_block(block, interpret)
     h = w_in.shape[0]
     if h % block:
         raise ValueError(f"hidden axis {h} not {block}-aligned")
@@ -462,6 +497,7 @@ def fused_input_infer_int8(x: jax.Array, w_q: jax.Array, w_scale: jax.Array,
     f32 scale per hidden row block dequantized inside the tile loop —
     weight bytes are never padded or upcast per call."""
     interpret = _resolve_interpret(interpret)
+    _check_block(block, interpret)
     h = w_q.shape[0]
     if h % block:
         raise ValueError(f"hidden axis {h} not {block}-aligned")
@@ -544,9 +580,10 @@ def seg_act(h: jax.Array, block_act_ids: np.ndarray, mask: np.ndarray, *,
     """One-pass per-block activation + padding mask. h (B, H) -> (B, H).
 
     Differentiable (custom VJP through the seg_act_bwd kernel).
-    ``interpret=None`` auto-selects: compiled on TPU, interpreter elsewhere.
+    ``interpret=None`` auto-selects: compiled on TPU, interpreted on CPU.
     """
     interpret = _resolve_interpret(interpret)
+    _check_block(block_h, interpret)
     if h.shape[1] % block_h:
         raise ValueError(f"hidden axis {h.shape[1]} not {block_h}-aligned")
     block_b = min(block_b, max(8, 1 << (h.shape[0] - 1).bit_length()))
@@ -561,56 +598,60 @@ def seg_act(h: jax.Array, block_act_ids: np.ndarray, mask: np.ndarray, *,
 # fused loss head: M3 projection + softmax-XE + dlogits                 #
 # --------------------------------------------------------------------- #
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _lh_core(h, w2, b2, tgt, seg_s, b_real, block_h, block_b, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _lh_core(h, w2, b2, tgt, seg, b_real, block_h, block_b, interpret):
     """Primal (no-grad contexts): per-member losses only, dlogits_base is
-    only emitted when a VJP will consume it."""
+    only emitted when a VJP will consume it.  The per-block member ids are
+    an OPERAND, so a member-sharded caller hands each shard its own."""
     per = _lhk.loss_head_fwd(
-        h, w2, b2, tgt, jnp.asarray(seg_s.arr), b2.shape[0],
-        b_real=b_real, block_h=block_h, block_b=block_b, with_dl=False,
-        interpret=interpret)
+        h, w2, b2, tgt, seg, b2.shape[0], b_real=b_real, block_h=block_h,
+        block_b=block_b, with_dl=False, interpret=interpret)
     return per[0]
 
 
-def _lh_fwd(h, w2, b2, tgt, seg_s, b_real, block_h, block_b, interpret):
+def _lh_fwd(h, w2, b2, tgt, seg, b_real, block_h, block_b, interpret):
     per, dl = _lhk.loss_head_fwd(
-        h, w2, b2, tgt, jnp.asarray(seg_s.arr), b2.shape[0],
-        b_real=b_real, block_h=block_h, block_b=block_b, with_dl=True,
-        interpret=interpret)
-    return per[0], (h, w2, dl)
+        h, w2, b2, tgt, seg, b2.shape[0], b_real=b_real, block_h=block_h,
+        block_b=block_b, with_dl=True, interpret=interpret)
+    return per[0], (h, w2, dl, seg)
 
 
-def _lh_bwd(seg_s, b_real, block_h, block_b, interpret, res, dper):
-    h, w2, dl = res
+def _lh_bwd(b_real, block_h, block_b, interpret, res, dper):
+    h, w2, dl, seg = res
     dper = dper.astype(jnp.float32)
     dh, dw = _lhk.loss_head_bwd(
-        dper.reshape(1, -1), dl, h, w2, jnp.asarray(seg_s.arr),
-        block_h=block_h, block_b=block_b, interpret=interpret)
-    # bias cotangent: one fused XLA reduce over the array that exists anyway
-    db = dper[:, None] * dl.sum(axis=0)
+        dper, dl, h, w2, seg, block_h=block_h, block_b=block_b,
+        interpret=interpret)
+    # bias cotangent: one fused XLA reduce over the (P, B, O) array that
+    # exists anyway
+    db = dper[:, None] * dl.sum(axis=1)
     # integer targets carry a float0 cotangent
     dt = np.zeros((h.shape[0], 1), jax.dtypes.float0)
-    return dh, dw, db, dt
+    return dh, dw, db, dt, None
 
 
 _lh_core.defvjp(_lh_fwd, _lh_bwd)
 
 
 def loss_head(h: jax.Array, w_out: jax.Array, b_out: jax.Array,
-              targets: jax.Array, block_seg_ids: np.ndarray, *,
+              targets: jax.Array, block_seg_ids, *,
               block_h: int, block_b: int = 128,
               interpret: bool | None = None) -> jax.Array:
     """Output projection + per-member softmax cross-entropy in one Pallas
     pass (kernels/loss_head.py; DESIGN.md §9); differentiable (fused
-    one-pass custom VJP emitting dh and dW_out together); pads B and O.
+    one-pass custom VJP emitting dh and dW_out together); pads B.
 
     h (B, H), w_out (O, H), b_out (P, O), integer targets (B,) →
     per-member mean NLL (P,) f32 — ``per.sum()`` is the scalar training
     loss and matches the XLA log_softmax reference to f32 tolerance.
+    O stays unpadded: it is the whole last dim of every head block.
+    ``block_seg_ids`` may be a numpy constant or a traced array (one
+    member shard's local ids under ``shard_map``).
     H must already be block_h-aligned (Population guarantees this).
-    ``interpret=None`` auto-selects: compiled on TPU, interpreter elsewhere.
+    ``interpret=None`` auto-selects: compiled on TPU, interpreted on CPU.
     """
     interpret = _resolve_interpret(interpret)
+    _check_block(block_h, interpret)
     if h.shape[1] % block_h:
         raise ValueError(f"hidden axis {h.shape[1]} not {block_h}-aligned")
     block_b = min(block_b, max(8, 1 << (h.shape[0] - 1).bit_length()))
@@ -618,14 +659,8 @@ def loss_head(h: jax.Array, w_out: jax.Array, b_out: jax.Array,
     # pad rows carry target −1 → zero loss weight, zero dlogits
     tp = jnp.pad(targets.astype(jnp.int32).reshape(-1, 1),
                  ((0, hp.shape[0] - b0), (0, 0)), constant_values=-1)
-    # O padding: −1e30 bias columns get zero softmax mass (and zero dW rows)
-    w2p, o0 = _pad_axis(w_out, 0, 128 if not interpret else 1)
-    pad_o = w2p.shape[0] - o0
-    b2p = b_out.astype(jnp.float32)
-    if pad_o:
-        b2p = jnp.pad(b2p, ((0, 0), (0, pad_o)), constant_values=-1e30)
-    return _lh_core(hp, w2p, b2p, tp,
-                    _StaticArray(block_seg_ids, np.int32), b0, block_h,
+    return _lh_core(hp, w_out, b_out.astype(jnp.float32), tp,
+                    jnp.asarray(block_seg_ids, jnp.int32), b0, block_h,
                     block_b, interpret)
 
 
@@ -639,27 +674,22 @@ def infer_head(h: jax.Array, w_out: jax.Array, b_out: jax.Array,
     not be able to trace a residual-emitting VJP through the head.
 
     h (B, H), w_out (O, H), b_out (P, O) → per-member logits — or, with
-    ``log_probs=True``, log-probabilities — (B, P, O) f32; pads B and O.
+    ``log_probs=True``, log-probabilities — (B, P, O) f32; pads B.
     H must already be block_h-aligned (Population guarantees this).
-    ``interpret=None`` auto-selects: compiled on TPU, interpreter elsewhere.
+    ``interpret=None`` auto-selects: compiled on TPU, interpreted on CPU.
     """
     interpret = _resolve_interpret(interpret)
+    _check_block(block_h, interpret)
     if h.shape[1] % block_h:
         raise ValueError(f"hidden axis {h.shape[1]} not {block_h}-aligned")
     block_b = min(block_b, max(8, 1 << (h.shape[0] - 1).bit_length()))
     hp, b0 = _pad_axis(h, 0, block_b)
-    # O padding: −1e30 bias columns get zero softmax mass under log_probs
-    # (and are sliced off regardless)
-    w2p, o0 = _pad_axis(w_out, 0, 128 if not interpret else 1)
-    pad_o = w2p.shape[0] - o0
-    b2p = b_out.astype(jnp.float32)
-    if pad_o:
-        b2p = jnp.pad(b2p, ((0, 0), (0, pad_o)), constant_values=-1e30)
     seg = jnp.asarray(np.asarray(block_seg_ids, np.int32))
-    y = _ihk.infer_head_fwd(hp, w2p, b2p, seg, b2p.shape[0],
-                            block_h=block_h, block_b=block_b,
+    y = _ihk.infer_head_fwd(hp, w_out, b_out.astype(jnp.float32), seg,
+                            b_out.shape[0], block_h=block_h, block_b=block_b,
                             log_probs=log_probs, interpret=interpret)
-    return y[:b0, :, :o0]
+    # the kernel emits member-major (P, B, O)
+    return jnp.transpose(y, (1, 0, 2))[:b0]
 
 
 def infer_head_int8(h: jax.Array, w_q: jax.Array, w_scale: jax.Array,
@@ -668,10 +698,9 @@ def infer_head_int8(h: jax.Array, w_q: jax.Array, w_scale: jax.Array,
                     log_probs: bool = False,
                     interpret: bool | None = None) -> jax.Array:
     """``infer_head`` over the int8 serve copy: one f32 scale per hidden
-    tile dequantized in the projection loop.  O pads with int8 zero rows
-    (exact under any scale) and −1e30 bias columns, exactly like the f32
-    head."""
+    tile dequantized in the projection loop."""
     interpret = _resolve_interpret(interpret)
+    _check_block(block_h, interpret)
     if h.shape[1] % block_h:
         raise ValueError(f"hidden axis {h.shape[1]} not {block_h}-aligned")
     if w_q.dtype != jnp.int8:
@@ -681,17 +710,12 @@ def infer_head_int8(h: jax.Array, w_q: jax.Array, w_scale: jax.Array,
                          f"({h.shape[1] // block_h},)")
     block_b = min(block_b, max(8, 1 << (h.shape[0] - 1).bit_length()))
     hp, b0 = _pad_axis(h, 0, block_b)
-    w2p, o0 = _pad_axis(w_q, 0, 128 if not interpret else 1)
-    pad_o = w2p.shape[0] - o0
-    b2p = b_out.astype(jnp.float32)
-    if pad_o:
-        b2p = jnp.pad(b2p, ((0, 0), (0, pad_o)), constant_values=-1e30)
     seg = jnp.asarray(np.asarray(block_seg_ids, np.int32))
     y = _ihk.infer_head_int8_fwd(
-        hp, w2p, w_scale.astype(jnp.float32).reshape(-1), b2p, seg,
-        b2p.shape[0], block_h=block_h, block_b=block_b,
-        log_probs=log_probs, interpret=interpret)
-    return y[:b0, :, :o0]
+        hp, w_q, w_scale.astype(jnp.float32).reshape(-1),
+        b_out.astype(jnp.float32), seg, b_out.shape[0], block_h=block_h,
+        block_b=block_b, log_probs=log_probs, interpret=interpret)
+    return jnp.transpose(y, (1, 0, 2))[:b0]
 
 
 # --------------------------------------------------------------------- #
@@ -700,7 +724,7 @@ def infer_head_int8(h: jax.Array, w_q: jax.Array, w_scale: jax.Array,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention(q, k, v, scale, causal=True, window=0,
-                    block_q=512, block_k=512, interpret=True):
+                    block_q=512, block_k=512, interpret=None):
     """Fused flash attention forward. q (B,H,Sq,dh), k/v (B,Hkv,Sk,dh).
 
     Backward recomputes through the exact dense/chunked XLA path
@@ -708,7 +732,8 @@ def flash_attention(q, k, v, scale, causal=True, window=0,
     prefill, and the recompute half of remat'd training)."""
     return _flashk.flash_attention_fwd(
         q, k, v, scale=scale, causal=causal, window=window,
-        block_q=block_q, block_k=block_k, interpret=interpret)
+        block_q=block_q, block_k=block_k,
+        interpret=_resolve_interpret(interpret))
 
 
 def _flash_fwd(q, k, v, scale, causal, window, block_q, block_k, interpret):
@@ -736,7 +761,7 @@ flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 def moe_gemm(x: jax.Array, w: jax.Array, block_expert_ids: np.ndarray, *,
              block_t: int = 128, block_d: int = 512, block_f: int = 512,
-             interpret: bool = True) -> jax.Array:
+             interpret: bool | None = None) -> jax.Array:
     """Tokens-sorted-by-expert grouped GEMM. x (T, D), w (E, D, F) -> (T, F).
 
     T must be block_t-aligned per expert run (capacity padding upstream).
@@ -753,5 +778,6 @@ def moe_gemm(x: jax.Array, w: jax.Array, block_expert_ids: np.ndarray, *,
     wp, f0 = _pad_axis(wp, 2, block_f)
     ids = jnp.asarray(np.asarray(block_expert_ids, np.int32))
     y = _moek.moe_gemm(xp, wp, ids, block_t=block_t, block_d=block_d,
-                       block_f=block_f, interpret=interpret)
+                       block_f=block_f,
+                       interpret=_resolve_interpret(interpret))
     return y[:, :f0]

@@ -4,8 +4,9 @@ hidden block in a single pass over the tensor.
 The paper applies per-member activations by split→activate→concat (or by
 masking, which reads the tensor 10×).  TPU-native version: the per-block
 activation id is scalar-prefetched; each tile is read once from VMEM and
-dispatched through ``lax.switch`` over the ten paper activations; the
-padding mask is fused into the same pass (zero HBM overhead).
+dispatched through ``lax.switch`` over the kernel forms of the ten paper
+activations (kernels/epilogue.py); the padding mask is fused into the same
+pass (zero HBM overhead).
 """
 from __future__ import annotations
 
@@ -14,13 +15,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.activations import ACTIVATION_FNS
+from repro.kernels.epilogue import DERIV_BRANCHES, VAL_BRANCHES
 
 
 def _kernel(act_ref, h_ref, mask_ref, out_ref):
     t = pl.program_id(1)
     x = h_ref[...]
-    y = jax.lax.switch(act_ref[t], ACTIVATION_FNS, x)
+    y = jax.lax.switch(act_ref[t], VAL_BRANCHES, x)
     out_ref[...] = y * mask_ref[...].astype(y.dtype)
 
 
@@ -45,28 +46,18 @@ def seg_act(h: jax.Array, block_act_ids: jax.Array, mask: jax.Array, *,
     )(block_act_ids, h, mask)
 
 
-def _vjp_branch(fn):
-    def branch(operands):
-        x, g = operands
-        return jax.vjp(fn, x)[1](g)[0]
-    return branch
-
-
-_VJP_BRANCHES = tuple(_vjp_branch(fn) for fn in ACTIVATION_FNS)
-
-
 def _bwd_kernel(act_ref, h_ref, dy_ref, mask_ref, out_ref):
     t = pl.program_id(1)
     x = h_ref[...]
     g = dy_ref[...] * mask_ref[...].astype(dy_ref.dtype)
-    out_ref[...] = jax.lax.switch(act_ref[t], _VJP_BRANCHES, (x, g))
+    out_ref[...] = g * jax.lax.switch(act_ref[t], DERIV_BRANCHES, x)
 
 
 def seg_act_bwd(h: jax.Array, dy: jax.Array, block_act_ids: jax.Array,
                 mask: jax.Array, *, block_h: int, block_b: int,
                 interpret: bool = False) -> jax.Array:
-    """dL/dh of ``seg_act``: dy·mask routed through each block's activation
-    VJP in the same one-pass tile-wise ``lax.switch`` dispatch as the
+    """dL/dh of ``seg_act``: dy·mask times each block's activation
+    derivative in the same one-pass tile-wise ``lax.switch`` dispatch as the
     forward (the cotangent of the fused mask-multiply is just another
     elementwise factor, so it fuses into the same tile read)."""
     b, hh = h.shape

@@ -20,8 +20,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
+from jax import set_mesh
 
-from repro.compat import set_mesh
 from repro.configs import ArchSpec, ShapeSpec
 from repro.distributed.sharding import BATCH_AXES, logical_to_sharding
 from repro.models import encdec, lm

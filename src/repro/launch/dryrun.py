@@ -26,8 +26,8 @@ import traceback
 
 import jax
 import numpy as np
+from jax import set_mesh
 
-from repro.compat import set_mesh
 from repro.configs import ALL_ARCH_IDS, ALL_SHAPES, get_arch, shape
 from repro.launch.cells import make_cell
 from repro.launch.hlo_cost import analyze as hlo_analyze
@@ -70,8 +70,7 @@ def run_cell(arch_id: str, shape_name: str, multi_pod: bool,
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
         mem = compiled.memory_analysis()
-        from repro.compat import cost_analysis_dict
-        cost = cost_analysis_dict(compiled)
+        cost = compiled.cost_analysis()
         hlo = compiled.as_text()
     # loop-aware static profile (XLA's cost_analysis counts while bodies
     # once — see hlo_cost.py); raw XLA numbers kept for reference

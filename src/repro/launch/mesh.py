@@ -13,7 +13,15 @@ from __future__ import annotations
 import jax
 import numpy as np
 
-from repro.compat import make_mesh
+
+
+def make_mesh(axis_shapes, axis_names, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto`` (the sharding-in-types
+    default would make each axis explicit)."""
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+        devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -15,8 +15,8 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import set_mesh
 
-from repro.compat import set_mesh
 from repro.configs import get_arch
 from repro.distributed.sharding import logical_to_sharding
 from repro.launch.mesh import make_host_mesh

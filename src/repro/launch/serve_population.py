@@ -37,8 +37,8 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import set_mesh
 
-from repro.compat import set_mesh
 from repro.core.ensemble import ENSEMBLE_MODES, ensemble_predict, real_slots
 from repro.core.selection import evaluate_population, leaderboard
 from repro.launch.launch_count import (count_pallas_launches,
@@ -180,6 +180,7 @@ class PopulationServer:
         xs = np.asarray(xs, np.float32)
         lat = np.zeros(n)
         preds = np.zeros(n, np.int64)
+        probs = np.zeros((n, self.layout.out_features), np.float32)
         unc = np.zeros(n, np.float32)
         if warmup:
             jax.block_until_ready(step(
@@ -198,6 +199,7 @@ class PopulationServer:
             out = step(self.params, jnp.asarray(buf))
             pred = np.asarray(
                 jax.block_until_ready(out["pred"]))[:nb]
+            probs[i:i + nb] = np.asarray(out["probs"])[:nb]
             mi = np.asarray(out["mutual_information"])[:nb]
             done = time.perf_counter() - t0
             # every request in the slab completes at the flush's done time;
@@ -215,6 +217,7 @@ class PopulationServer:
                                else len(self.published[mode])),
             "requests": n,
             "pred": preds,
+            "probs": probs,
             "mutual_information": unc,
             "p50_ms": float(np.percentile(lat, 50) * 1e3),
             "p99_ms": float(np.percentile(lat, 99) * 1e3),
@@ -283,6 +286,8 @@ def main(argv=None):
     ap.add_argument("--json-out", default=None)
     args = ap.parse_args(argv)
 
+    from repro.launch.cache import configure_compile_cache
+    configure_compile_cache()
     mesh = None
     if args.sharded:
         from repro.launch.mesh import make_host_mesh
@@ -317,7 +322,8 @@ def main(argv=None):
         for mode in args.modes:
             r = server.run(xr[:args.requests], mode)
             results[mode] = {k: v for k, v in r.items()
-                             if k not in ("pred", "mutual_information")}
+                             if k not in ("pred", "probs",
+                                          "mutual_information")}
             print(f"{mode:6s} members={r['members_served']:3d} "
                   f"p50={r['p50_ms']:.2f}ms p99={r['p99_ms']:.2f}ms "
                   f"{r['req_per_s']:.0f} req/s")

@@ -41,13 +41,14 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import set_mesh
 
-from repro.compat import set_mesh
 from repro.checkpoint import latest_steps, restore
 from repro.configs import get_arch
 from repro.data import TabularTask, TokenTask
 from repro.distributed import TrainRunner, StragglerPolicy
 from repro.distributed.sharding import logical_to_sharding
+from repro.launch.cache import configure_compile_cache
 from repro.launch.cells import build_optimizer
 from repro.launch.mesh import make_host_mesh
 from repro.models import encdec, lm
@@ -149,7 +150,7 @@ def parse_depth_spec(spec: str):
     return tuple(widths)
 
 
-def run_population(arch, args):
+def run_population(arch, args, report=None, mesh=None):
     """Fused population training through the layered engine (core.deep),
     DISTRIBUTION-NATIVE: the layout is shard-padded to the mesh's
     population ('model') axis, parameters are born sharded through
@@ -180,7 +181,15 @@ def run_population(arch, args):
     (``--per-member-lr``/``--per-member-momentum``/
     ``--per-member-weight-decay``) so members race heterogeneous training
     RECIPES, not just architectures.  Plain ``sgd`` reproduces the
-    historical stateless trajectory bit-for-bit."""
+    historical stateless trajectory bit-for-bit.
+
+    ``report`` (optional dict) receives the run's counters: ``restarts``
+    (TrainRunner replays, summed over segments), ``compile_s`` (wall of the
+    first chunk call — trace + compile + dispatch), ``chunk_done_s``
+    (host clock after each chunk's blocking metric read), ``first_loss`` /
+    ``last_loss`` (mean over real members) and ``per_member_last`` (the
+    last step's per-member losses, real members).  ``mesh`` overrides the
+    default ``make_host_mesh()`` over every local device."""
     from repro.checkpoint import (latest_steps, layout_from_meta,
                                   lifecycle_from_meta, load_meta,
                                   population_meta, require_optimizer_match,
@@ -287,7 +296,8 @@ def run_population(arch, args):
         model = arch.model
         lp = model.layered() if isinstance(model, Population) else model
 
-    mesh = make_host_mesh()
+    if mesh is None:
+        mesh = make_host_mesh()
     scan = max(args.scan_steps, 1)
     print(f"mesh={dict(mesh.shape)} devices={len(jax.devices())} "
           f"scan_steps={scan}")
@@ -506,7 +516,8 @@ def run_population(arch, args):
 
         total = args.steps
         print_every = max(50 // scan, 1)
-        stats = {}
+        stats = report if report is not None else {}
+        stats.update(restarts=0, chunk_done_s=[])
         pipeline = args.pipeline == "on"
         pf = None          # ONE Prefetcher for the run, retargeted per rung
         pending = []       # the in-flight chunk's DeferredMetrics (≤ 1)
@@ -604,9 +615,11 @@ def run_population(arch, args):
                     # too but must not dilute the reported loss (a sharded
                     # run prints the same numbers as its single-device twin)
                     per = np.asarray(pers[:, :lp.num_real])
+                    stats["chunk_done_s"].append(time.perf_counter())
                     stats.setdefault("first_loss", float(per[0].mean()))
                     mean = float(per[-1].mean())
                     stats["last_loss"] = mean
+                    stats["per_member_last"] = per[n - 1]
                     metrics = {"loss": mean, "step": g0 + n - 1}
                     if gnorms is not None:
                         # pre-clip global grad norm, one per inner step —
@@ -630,9 +643,12 @@ def run_population(arch, args):
                 # the segment, so crash replay and --resume stay consistent
                 sched_args = ((jnp.asarray(g0, jnp.int32),) if lr_sched
                               else ())
+                t_call = time.perf_counter()
                 p, st, _losses, pers, gnorms = chunk_fn(
                     state["params"], state["extra"], xs, ys, lr,
                     *sched_args)
+                # the first call traces and compiles before it dispatches
+                stats.setdefault("compile_s", time.perf_counter() - t_call)
                 dm = DeferredMetrics(resolve_metrics(pers, gnorms, g0, n, c))
                 if pipeline:
                     # chunk c is dispatched; NOW pay chunk c-1's host fetch
@@ -677,6 +693,7 @@ def run_population(arch, args):
                 mesh=mesh, state_specs={"params": lp.param_specs(),
                                         "extra": lp.opt_specs(opt)})
             runner.run(n_chunks)
+            stats["restarts"] += runner.restarts
             # the segment's last chunk still owes its host fetch — resolve
             # it before the rung boundary / final eval reads stats
             while pending:
@@ -992,7 +1009,10 @@ def run_population(arch, args):
         return params, lp
 
 
-def main(argv=None):
+def main(argv=None, report=None, mesh=None):
+    """Parse ``argv`` and train.  Population archs return ``(params,
+    layout)``; ``report`` and ``mesh`` pass through to
+    :func:`run_population`."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true",
@@ -1153,10 +1173,12 @@ def main(argv=None):
                          "FRAC of the slot-arch-matching survivors")
     args = ap.parse_args(argv)
 
+    configure_compile_cache()
     arch = get_arch(args.arch, reduced=args.reduced)
     if arch.kind == "population":
-        return run_population(arch, args)
-    mesh = make_host_mesh()
+        return run_population(arch, args, report=report, mesh=mesh)
+    if mesh is None:
+        mesh = make_host_mesh()
     print(f"arch={args.arch} mesh={dict(mesh.shape)} "
           f"devices={len(jax.devices())}")
     if arch.kind in ("lm", "encdec"):

@@ -28,8 +28,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 from repro.nn.common import FFN_ACTS, dense_init
 
 
@@ -265,11 +263,11 @@ def moe_apply_tp_shard_map(p, cfg: MoEConfig, x, mesh, *, tp_axis="model",
     experts_spec = {"w_gate": P(None, None, tp_axis),
                     "w_up": P(None, None, tp_axis),
                     "w_down": P(None, tp_axis, None)}
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(sp_axes, None, None), P(None, None), experts_spec),
         out_specs=(P(sp_axes, tp_axis, None), P()),
-        check=False)
+        check_vma=False)
     return fn(x, p["router"], p["experts"])
 
 
@@ -329,10 +327,10 @@ def moe_apply_shard_map(p, cfg: MoEConfig, x, mesh, *, ep_axis="model",
     }
     shared = p.get("shared", {})
     shared_spec = jax.tree.map(lambda _: P(None), shared)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(sp_axes, ep_axis, None), P(None, None),
                   experts_local_spec, shared_spec),
         out_specs=(P(sp_axes, ep_axis, None), P()),
-        check=False)
+        check_vma=False)
     return fn(x, p["router"], p["experts"], shared)

@@ -9,7 +9,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import dataclasses, json
 import jax
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.configs import get_arch
 from repro.configs.base import ShapeSpec
 from repro.launch.cells import make_cell

@@ -102,7 +102,7 @@ def test_quantize_roundtrip_error_feedback():
 def test_compressed_psum_single_axis():
     """On a 1-sized axis the compressed reduce must be a near-identity
     (quantisation only) and converge via error feedback."""
-    from repro.compat import make_mesh, shard_map
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((1,), ("pod",))
     g = {"w": jnp.asarray(np.random.default_rng(1).normal(0, 1, (64,)),
                           jnp.float32)}
@@ -114,8 +114,8 @@ def test_compressed_psum_single_axis():
     from jax.sharding import PartitionSpec as P
     spec = jax.tree.map(lambda _: P(), g)
     out, err2 = jax.jit(
-        shard_map(f, mesh=mesh, in_specs=(spec, spec),
-                  out_specs=(spec, spec), check=False))(g, err)
+        jax.shard_map(f, mesh=mesh, in_specs=(spec, spec),
+                      out_specs=(spec, spec), check_vma=False))(g, err)
     np.testing.assert_allclose(np.asarray(out["w"]), np.asarray(g["w"]),
                                atol=2e-2)
     # feeding the error back makes the two-step average exact-ish
@@ -127,7 +127,7 @@ _SHARDED_REPLAY = r"""
 import os, sys
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.core import deep
 from repro.core.population import LayeredPopulation
 from repro.distributed import TrainRunner
@@ -218,7 +218,7 @@ def test_runner_derives_restore_shardings_from_specs(tmp_path):
     """The mesh + spec-tree wiring builds the same NamedSharding tree a
     caller would hand-build (single-device degenerate case)."""
     from jax.sharding import PartitionSpec as P
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
 
     mesh = make_mesh((1, 1), ("data", "model"))
     state = {"w": jnp.zeros((8, 2))}
@@ -226,6 +226,56 @@ def test_runner_derives_restore_shardings_from_specs(tmp_path):
                     mesh=mesh, state_specs={"w": P("model", None)})
     assert r.restore_shardings is not None
     assert r.restore_shardings["w"].mesh.shape == dict(mesh.shape)
+
+
+def test_restart_prints_its_exception(tmp_path, capsys):
+    """A restart is never silent: the runner prints the step, the restart
+    count and the exception before it replays."""
+    boom = {1: True}
+
+    def failure_hook(step):
+        if boom.pop(step, False):
+            raise RuntimeError("simulated chip failure at 1")
+
+    r = TrainRunner(_step_fn, {"w": jnp.zeros(2), "n": jnp.zeros((), jnp.int32)},
+                    ckpt_dir=str(tmp_path), ckpt_every=0,
+                    failure_hook=failure_hook)
+    r.run(3)
+    assert r.restarts == 1
+    err = capsys.readouterr().err
+    assert "step 1 failed (restart 1/3)" in err, err
+    assert "RuntimeError: simulated chip failure at 1" in err, err
+
+
+def test_restore_lands_on_live_shardings(tmp_path):
+    """A crash replay restores onto the shardings the live state had after
+    the last completed step — the jitted step's OUTPUT shardings — not the
+    spec-derived ones, which may be spelled differently and key a second
+    executable."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1, 1), ("data", "model"))
+    live = NamedSharding(mesh, P("model"))
+    step = jax.jit(lambda w: w + 1.0, out_shardings=live)
+    boom = {2: True}
+
+    def failure_hook(s):
+        if boom.pop(s, False):
+            raise RuntimeError("simulated chip failure")
+
+    init = jax.device_put(jnp.zeros((8, 2)), NamedSharding(mesh, P("model", None)))
+    r = TrainRunner(lambda st, s: ({"w": step(st["w"])}, {"loss": 0.0}),
+                    {"w": init}, ckpt_dir=str(tmp_path), ckpt_every=1,
+                    failure_hook=failure_hook, mesh=mesh,
+                    state_specs={"w": P("model", None)})
+    seen = []
+    r.on_restore = lambda s: seen.append(r.state["w"].sharding)
+    r.run(4)
+    assert r.restarts == 1
+    assert seen == [live]
+    np.testing.assert_array_equal(np.asarray(r.state["w"]), 4.0)
 
 
 def test_elastic_remesh_preserves_values():

@@ -5,7 +5,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import cost_analysis_dict
 from repro.launch.hlo_cost import analyze, parse_module
 from repro.launch.hlo_stats import collective_stats, shape_bytes
 
@@ -20,7 +19,7 @@ def test_loop_free_dot_matches_xla():
     mine = analyze(c.as_text())
     want = 2 * 64 * 128 * 32
     assert abs(mine["flops"] - want) / want < 0.01
-    xla = cost_analysis_dict(c)["flops"]
+    xla = c.cost_analysis()["flops"]
     assert abs(mine["flops"] - xla) / xla < 0.05
 
 
@@ -41,7 +40,7 @@ def test_scan_trip_count_weighting():
     assert abs(mine["flops"] - want) / want < 0.01
     assert any(n == L for _, n in mine["loops"])
     # XLA undercounts exactly by the trip count
-    xla = cost_analysis_dict(c)["flops"]
+    xla = c.cost_analysis()["flops"]
     assert mine["flops"] > xla * (L - 1) / 2
 
 

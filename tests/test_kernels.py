@@ -95,3 +95,16 @@ def test_m3_kernel_used_by_population():
     y_ref = forward(params, x, pop, m3_impl="scatter")
     np.testing.assert_allclose(np.asarray(y_pallas), np.asarray(y_ref),
                                rtol=2e-5, atol=2e-5)
+
+
+def test_interpret_only_on_cpu(monkeypatch):
+    """``interpret=None`` interprets on the CPU backend, compiles on a TPU,
+    and refuses any other backend instead of interpreting on it."""
+    from repro.kernels import ops
+    assert ops._resolve_interpret(None) is True
+    assert ops._resolve_interpret(False) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops._resolve_interpret(None) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        ops._resolve_interpret(None)

@@ -241,7 +241,7 @@ def test_pad_state_rejects_unpaddable_leaves():
 def test_population_opt_shardings_structure():
     """population_opt_shardings returns one NamedSharding per state leaf
     (momentum moments follow their parameters; count replicates)."""
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.distributed.sharding import population_opt_shardings
     mesh = make_mesh((1, 1), ("data", "model"))
     opt = sgd(momentum=0.9)

@@ -165,7 +165,7 @@ def test_act_impl_matches_sliced(act_impl):
 def test_population_shardings_single_device():
     """population_shardings degrades to replication on the 1-device CPU
     (no mesh axes to shard over) but returns a full NamedSharding tree."""
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.distributed.sharding import population_shardings
     mesh = make_mesh((1, 1), ("data", "model"))
     sh = population_shardings(LP, mesh)

@@ -279,6 +279,7 @@ def _fake_server(lp, *, batch, max_latency_ms, first_call_sleep=0.0):
             time.sleep(first_call_sleep)     # stands in for jit compile
         b = xb.shape[0]
         return {"pred": jnp.zeros(b, jnp.int32),
+                "probs": jnp.zeros((b, lp.out_features), jnp.float32),
                 "mutual_information": jnp.zeros(b, jnp.float32)}
 
     for m in ("all", "topk", "best1"):

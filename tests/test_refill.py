@@ -583,7 +583,7 @@ from repro.core.lifecycle import compact_params, grow_params
 from repro.core.population import LayeredPopulation
 from repro.distributed.sharding import population_shardings
 from repro.launch.mesh import make_host_mesh
-from repro.compat import set_mesh
+from jax import set_mesh
 
 assert len(jax.devices()) == 4
 LP = LayeredPopulation(

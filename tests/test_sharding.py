@@ -35,7 +35,8 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import make_mesh, set_mesh
+from jax import set_mesh
+from repro.launch.mesh import make_mesh
 from repro.distributed.sharding import filter_spec, constrain
 mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 with set_mesh(mesh):
@@ -71,7 +72,8 @@ _SUBPROC_MOE = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import make_mesh, set_mesh
+from jax import set_mesh
+from repro.launch.mesh import make_mesh
 from repro.nn.ffn import MoEConfig, moe_init, moe_apply_dense, moe_apply_shard_map
 mesh = make_mesh((2, 4), ("data", "model"))
 cfg = MoEConfig(d_model=16, d_expert=8, num_experts=8, top_k=2,

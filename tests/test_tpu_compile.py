@@ -1,0 +1,248 @@
+"""The main path compiled for a TPU v5e that is described, not attached.
+
+Every kernel of the population engine is compiled by Mosaic for one chip of
+a ``v5e:2x2`` topology at the sizes the chip runs: the paper population's
+fused hidden width H = 1,280,000 (10,000 members, block 128), a deep
+block-128 population for the mid layers, the heads at P = 10,000, and the
+int8 serving twins.  The whole paper train step is compiled too and its
+``memory_analysis()`` is held to the chip's 16 GB.  Nothing runs: a compile
+that passes is not a chip run, but a kernel the chip's compiler refuses
+(unlowerable primitive, unaligned block, too much VMEM or SMEM) fails here.
+
+The topology is described inside a module fixture (never at import), and
+the tests steer ``ops._resolve_interpret`` with ``monkeypatch``: the CPU
+backend would otherwise pick the interpreter.
+"""
+import os
+import pathlib
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import deep
+from repro.core.activations import PAPER_TEN
+from repro.core.population import LayeredPopulation
+from repro.kernels import ops
+
+HBM_BYTES = 16 * 1000 ** 3     # one TPU v5e chip: 16 GB of HBM
+BATCH = 256
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache off meanwhile
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(autouse=True)
+def compiled_kernels(monkeypatch):
+    monkeypatch.setattr(ops, "_resolve_interpret", lambda interpret: False)
+
+
+@pytest.fixture(scope="module")
+def paper_lp():
+    from repro.configs import get_arch
+    return get_arch("parallelmlp-10k").model.layered()
+
+
+@pytest.fixture(scope="module")
+def deep_lp():
+    return LayeredPopulation(
+        100, 2, ((512, 256), (256, 128, 64), (384,), (128,)) * 4,
+        tuple(PAPER_TEN[i % 10] for i in range(16)), block=128).sorted()
+
+
+def _compile(fn, sharding, *args):
+    abstract = [jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        a) for a in args]
+    return jax.jit(fn).lower(*abstract).compile()
+
+
+def _sds(shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _n_kernels(compiled) -> int:
+    return compiled.as_text().count("tpu_custom_call")
+
+
+def test_fused_input_fwd_bwd_paper_width(one_chip, paper_lp):
+    """The input layer at H = 1,280,000, forward with its g' residual and
+    the one-pass backward (whose on-chip scratch no longer grows with H)."""
+    p0 = paper_lp.layer_pop(0)
+    h = p0.total_hidden
+    assert h == 1_280_000
+
+    def fwd_bwd(x, w, b, dy):
+        y, vjp = jax.vjp(lambda x, w, b: ops.fused_input(
+            x, w, b, p0.block_act_ids, p0.hidden_mask, block=128), x, w, b)
+        return y, vjp(dy)
+
+    c = _compile(fwd_bwd, one_chip, _sds((BATCH, 100)), _sds((h, 100)),
+                 _sds((h,)), _sds((BATCH, h)))
+    assert _n_kernels(c) == 2
+
+
+@pytest.mark.parametrize("block_b", [128, 256])
+def test_fused_layer_fwd_bwd_block128(one_chip, deep_lp, block_b):
+    """Every mid layer of a deep heterogeneous block-128 population, all
+    ten activations in the epilogue, forward and two-level backward."""
+    params = deep.abstract_params(deep_lp)
+
+    def fwd_bwd(mid, h0, dy):
+        def f(mid, h0):
+            h = h0
+            for l in range(deep_lp.depth - 1):
+                h = deep.block_diag_fused(h, mid[l]["w"], deep_lp, l,
+                                          bias=mid[l]["b"], block_b=block_b)
+            return h
+        y, vjp = jax.vjp(f, mid, h0)
+        return y, vjp(dy)
+
+    h_in = deep_lp.layer_pop(0).total_hidden
+    h_out = deep_lp.layer_pop(deep_lp.depth - 1).total_hidden
+    c = _compile(fwd_bwd, one_chip, params["mid"], _sds((BATCH, h_in)),
+                 _sds((BATCH, h_out)))
+    assert _n_kernels(c) == 2 * (deep_lp.depth - 1)
+
+
+def test_loss_head_paper_members(one_chip, paper_lp):
+    """Projection + softmax-XE + dlogits at P = 10,000 (the seg table
+    rides scalar prefetch), forward and backward."""
+    pop = paper_lp.layer_pop(0)
+
+    def fwd_bwd(h, w, b, y):
+        per, vjp = jax.vjp(lambda h, w, b: ops.loss_head(
+            h, w, b, y, pop.block_segment_ids, block_h=128), h, w, b)
+        return per, vjp(jnp.ones_like(per))
+
+    c = _compile(fwd_bwd, one_chip, _sds((BATCH, pop.total_hidden)),
+                 _sds((2, pop.total_hidden)), _sds((10_000, 2)),
+                 _sds((BATCH,), jnp.int32))
+    assert _n_kernels(c) == 2
+
+
+@pytest.mark.parametrize("log_probs", [False, True])
+def test_infer_head_paper_members(one_chip, paper_lp, log_probs):
+    pop = paper_lp.layer_pop(0)
+
+    def fwd(h, w, b):
+        return ops.infer_head(h, w, b, pop.block_segment_ids, block_h=128,
+                              log_probs=log_probs)
+
+    c = _compile(fwd, one_chip, _sds((BATCH, pop.total_hidden)),
+                 _sds((2, pop.total_hidden)), _sds((10_000, 2)))
+    assert _n_kernels(c) == 1
+
+
+def test_int8_serving_twins(one_chip, deep_lp):
+    """The int8 serving forward: all three fused-dequant twins (input,
+    mid layers, head) in one program."""
+    from repro.quant import quantize_population
+    qparams = jax.eval_shape(lambda p: quantize_population(p, deep_lp),
+                             deep.abstract_params(deep_lp))
+
+    def serve(q, x):
+        return deep.forward(q, x, deep_lp, bd_impl="fused", infer=True,
+                            weights_dtype="int8")
+
+    c = _compile(serve, one_chip, qparams, _sds((BATCH, 100)))
+    assert _n_kernels(c) == deep_lp.depth + 1
+
+
+@pytest.mark.parametrize("bd_impl", ["einsum", "fused"])
+def test_paper_train_step_fits_one_chip(one_chip, paper_lp, bd_impl):
+    """One scanned chunk of the paper population (10,000 members, batch
+    256, plain SGD) through the driver's default XLA path and through the
+    fused kernels, held to one chip's HBM."""
+    from repro.optim import sgd
+    chunk = deep.make_population_train_step(
+        paper_lp, optimizer=sgd(), bd_impl=bd_impl, scan_steps=2,
+        donate_batch=True)
+    params = deep.abstract_params(paper_lp)
+    opt_state = jax.eval_shape(sgd().init, params)
+    c = _compile(chunk, one_chip, params, opt_state,
+                 _sds((2, BATCH, 100)), _sds((2, BATCH), jnp.int32),
+                 _sds((), jnp.float32))
+    mem = c.memory_analysis()
+    total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < HBM_BYTES, mem
+    if bd_impl == "fused":
+        assert _n_kernels(c) >= 4
+
+
+def test_paper_train_step_member_sharded_four_chips(topo, one_chip,
+                                                   paper_lp):
+    """The fused paper chunk with members over 'model' on the whole
+    v5e:2x2 host: XLA cannot partition a Mosaic kernel, so the kernels run
+    under shard_map — no all-gather of weights or activations enters the
+    program, and each chip holds a quarter of the population."""
+    from jax.sharding import Mesh
+
+    from repro.distributed.sharding import (population_batch_shardings,
+                                            population_shardings)
+    from repro.optim import sgd
+    sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
+    from chip_smoke import count_collectives
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(1, 4), ("data", "model"),
+                axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    lp = paper_lp.shard_pad(4)
+    opt = sgd()
+    chunk = deep.make_population_train_step(lp, optimizer=opt,
+                                            bd_impl="fused", scan_steps=2,
+                                            donate_batch=True)
+    params = deep.abstract_params(lp)
+    psh = population_shardings(lp, mesh)
+    sh_x, sh_y = population_batch_shardings(mesh, BATCH)
+    rep = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+    args = (jax.tree.map(lambda a, s: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=s), params, psh),
+            jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=rep),
+                jax.eval_shape(opt.init, params)),
+            jax.ShapeDtypeStruct((2, BATCH, 100), jnp.float32,
+                                 sharding=sh_x),
+            jax.ShapeDtypeStruct((2, BATCH), jnp.int32, sharding=sh_y),
+            0.01)
+    with jax.set_mesh(mesh):
+        c = chunk.lower(*args).compile()
+    counts = count_collectives(c.as_text())
+    assert counts["tpu_custom_call"] == 4, counts
+    assert counts["all_gather"] == 0, counts
+    mem = c.memory_analysis()
+    assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes) < HBM_BYTES / 4, mem
+
+
+def test_block_not_lane_aligned_fails_loudly():
+    lp = LayeredPopulation(4, 2, ((5,), (3,)), ("relu", "tanh"), block=8)
+    p0 = lp.layer_pop(0)
+    x = jnp.zeros((8, 4))
+    w = jnp.zeros((p0.total_hidden, 4))
+    with pytest.raises(ValueError, match="--population-block"):
+        ops.fused_input(x, w, jnp.zeros(p0.total_hidden), p0.block_act_ids,
+                        p0.hidden_mask, block=8)

@@ -193,3 +193,26 @@ def test_resume_continues_training(tmp_path):
     jax.tree.map(lambda a, b: np.testing.assert_allclose(
         np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6),
         p_resumed, p_straight)
+
+
+def test_compile_cache_directory(monkeypatch):
+    """The entry points keep JAX's compile cache where
+    JAX_COMPILATION_CACHE_DIR says and set no other directory; unset, it
+    goes to the fixed <checkout>/.jax_cache, which git ignores."""
+    import pathlib
+
+    from repro.launch.cache import DEFAULT_DIR, configure_compile_cache
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert configure_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert configure_compile_cache() == str(DEFAULT_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(DEFAULT_DIR)
+        root = pathlib.Path(__file__).resolve().parents[1]
+        assert DEFAULT_DIR == root / ".jax_cache"
+        assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
