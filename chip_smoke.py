@@ -24,9 +24,9 @@ One chip:
 the same steps in the same process; it also prints how many all-gathers
 the compiled sharded chunk holds next to its Pallas kernels.
 
-Each phase prints its compile seconds, a steady step time (a smoke figure
-from the host clock after blocking reads, NOT a benchmark), first and last
-mean loss and ``TrainRunner`` restarts.  Checks: the backend is ``tpu``;
+Each phase prints its compile seconds, first and last mean loss and
+``TrainRunner`` restarts (the chip benchmark, ``chipbench/``, measures
+speed).  Checks: the backend is ``tpu``;
 every phase finishes with 0 restarts; losses are finite and fall; B's
 per-member losses match A's within ``LOSS_TOL``; D's ensemble
 probabilities match the XLA forward of the same parameters within
@@ -85,7 +85,7 @@ def _say(msg: str):
 
 
 def train_phase(name: str, argv, mesh=None) -> dict:
-    """One driver run → its report (restarts, compile and chunk clocks,
+    """One driver run → its report (restarts, compile seconds,
     losses) plus the trained params and layout."""
     from repro.launch.train import main
     report = {}
@@ -94,12 +94,7 @@ def train_phase(name: str, argv, mesh=None) -> dict:
         params, lp = main(list(argv) + ["--ckpt-dir", ckpt], report=report,
                           mesh=mesh)
         wall = time.perf_counter() - t0
-    done = report.get("chunk_done_s", [])
-    scan = int(argv[argv.index("--scan-steps") + 1])
-    step_s = ((done[-1] - done[-2]) / scan if len(done) >= 2
-              else float("nan"))
     _say(f"[{name}] compile {report.get('compile_s', float('nan')):.2f} s; "
-         f"smoke step time {step_s * 1e3:.3f} ms (not a benchmark); "
          f"mean loss {report.get('first_loss', float('nan')):.6f} -> "
          f"{report.get('last_loss', float('nan')):.6f}; "
          f"restarts {report.get('restarts')}; wall {wall:.1f} s")

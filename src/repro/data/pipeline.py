@@ -33,12 +33,31 @@ Bit-exactness contract: the prefetcher changes WHEN a batch is built and
 copied, never WHAT is built — ``produce(chunk_idx, staging)`` is required
 to be a pure function of the chunk index (the repo's step-indexed data
 rule), so a pipelined run's trajectory is bit-identical to the synchronous
-driver's (tests/test_pipeline.py)."""
+driver's (tests/test_pipeline.py).
+
+Both pieces trace themselves on the profiler's clock, with no switch: an
+annotation costs about a microsecond when no profiler listens.  Each span
+carries ``chunk=c``, so a chunk's producer span and the loop's spans for
+it line up in one trace:
+
+  * ``prefetch.build`` — the producer's ``produce(c, staging)`` call
+    (host fill plus ``device_put``), on the producer thread;
+  * ``prefetch.wait`` — the blocking part of :meth:`Prefetcher.get`, opened
+    only when the queue is empty, i.e. when the consumer is starved;
+  * ``metrics.resolve`` — :meth:`DeferredMetrics.force`'s first resolve,
+    the host fetch of a chunk's metrics.
+
+``Prefetcher.stats`` counts the same boundaries in plain Python numbers:
+``built``/``build_s`` (producer), ``got``, ``starved``/``wait_s``
+(consumer)."""
 from __future__ import annotations
 
 import queue
 import threading
+import time
 from typing import Any, Callable, Iterator, Mapping, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 class PrefetchError(RuntimeError):
@@ -87,9 +106,14 @@ class DeferredMetrics(Mapping):
     def resolved(self) -> bool:
         return self._value is not None
 
-    def force(self) -> dict:
+    def force(self, chunk: Optional[int] = None) -> dict:
+        """The resolved dict; the first call fetches it inside a
+        ``metrics.resolve`` span, tagged with ``chunk`` when given."""
         if self._value is None:
-            self._value = dict(self._resolve())
+            with TraceAnnotation("metrics.resolve",
+                                 **({} if chunk is None else
+                                    {"chunk": chunk})):
+                self._value = dict(self._resolve())
         return self._value
 
     def __getitem__(self, key):
@@ -131,6 +155,11 @@ class Prefetcher:
     depth : queue bound (default 2 = double buffering): the producer runs
         at most ``depth`` chunks ahead, then blocks (backpressure) until
         the consumer drains one.
+
+    ``stats`` counts what the data plane did since construction: slabs
+    ``built`` and the producer's ``build_s`` inside ``produce``; slabs
+    ``got`` by the consumer, the gets that found the queue empty
+    (``starved``) and the consumer's ``wait_s`` blocked in them.
     """
 
     _END = object()
@@ -153,6 +182,10 @@ class Prefetcher:
         self._error: Optional[BaseException] = None
         self._thread: Optional[threading.Thread] = None
         self._next = int(start)          # next chunk the consumer expects
+        # each count has one writer: the producer thread (built, build_s)
+        # or the consumer (got, starved, wait_s)
+        self.stats = {"built": 0, "got": 0, "starved": 0, "build_s": 0.0,
+                      "wait_s": 0.0}
         self._start_thread(int(start))
 
     # ----------------------------------------------------------------- #
@@ -173,7 +206,11 @@ class Prefetcher:
             for c in range(start, self._n_chunks):
                 if self._stop.is_set():
                     return
-                slab = self._produce(c, self._staging[flip])
+                t = time.perf_counter()
+                with TraceAnnotation("prefetch.build", chunk=c):
+                    slab = self._produce(c, self._staging[flip])
+                self.stats["build_s"] += time.perf_counter() - t
+                self.stats["built"] += 1
                 flip ^= 1
                 if not self._put((c, slab)):
                     return
@@ -209,23 +246,11 @@ class Prefetcher:
         discarded and the producer restarts at ``chunk_idx``."""
         if chunk_idx != self._next:
             self.seek(chunk_idx)
-        deadline = timeout
         while True:
             try:
-                item = self._q.get(timeout=min(deadline, 0.5))
+                item = self._q.get_nowait()
             except queue.Empty:
-                deadline -= 0.5
-                if self._error is not None:
-                    self._raise()
-                if not self._thread.is_alive():
-                    raise PrefetchError(
-                        f"{self._name}: producer thread died without "
-                        f"delivering chunk {chunk_idx}")
-                if deadline <= 0:
-                    raise TimeoutError(
-                        f"{self._name}: chunk {chunk_idx} not produced "
-                        f"within {timeout}s")
-                continue
+                item = self._wait(chunk_idx, timeout)
             if item is self._END:
                 if self._error is not None:
                     self._raise()
@@ -236,7 +261,33 @@ class Prefetcher:
             if c != chunk_idx:           # stale slab from before a seek
                 continue
             self._next = chunk_idx + 1
+            self.stats["got"] += 1
             return slab
+
+    def _wait(self, chunk_idx: int, timeout: float):
+        """Block for the queue's next item: the consumer is starved."""
+        self.stats["starved"] += 1
+        t = time.perf_counter()
+        deadline = timeout
+        try:
+            with TraceAnnotation("prefetch.wait", chunk=chunk_idx):
+                while True:
+                    try:
+                        return self._q.get(timeout=min(deadline, 0.5))
+                    except queue.Empty:
+                        deadline -= 0.5
+                    if self._error is not None:
+                        self._raise()
+                    if not self._thread.is_alive():
+                        raise PrefetchError(
+                            f"{self._name}: producer thread died without "
+                            f"delivering chunk {chunk_idx}")
+                    if deadline <= 0:
+                        raise TimeoutError(
+                            f"{self._name}: chunk {chunk_idx} not produced "
+                            f"within {timeout}s")
+        finally:
+            self.stats["wait_s"] += time.perf_counter() - t
 
     def _raise(self):
         err = self._error

@@ -116,6 +116,7 @@ def block_diag_fwd(x: jax.Array, wb: jax.Array, s_in: jax.Array,
             (block_b, block), (block, block), (block_b, block),
             (block_b, block)),
         interpret=interpret,
+        name="block_diag_fwd",
     )(s_in, s_w, s_out, s_first, s_last, x, wb)
 
 
@@ -173,4 +174,5 @@ def block_diag_dw(dy: jax.Array, x: jax.Array, wb_out_tile: jax.Array,
             (block_b, block), (block_b, block), (block, block),
             (block, block)),
         interpret=interpret,
+        name="block_diag_dw",
     )(wb_out_tile, wb_in_tile, dy, x)

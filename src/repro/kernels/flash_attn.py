@@ -128,6 +128,7 @@ def flash_attention_fwd(q, k, v, *, scale: float, causal: bool,
             pltpu.VMEM((block_q, dh), jnp.float32),      # output acc
         ],
         interpret=interpret,
+        name="flash_attn",
     )(qp.reshape(b * h, sq + pad_q, dh),
       kp.reshape(b * hkv, sk + pad_k, dh),
       vp.reshape(b * hkv, sk + pad_k, dh))

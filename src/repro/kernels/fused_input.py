@@ -120,6 +120,7 @@ def fused_input_fwd(x: jax.Array, w: jax.Array, bias: jax.Array,
             (block_b, block_f), (block, block_f), (1, block), (1, block),
             (block_b, block), (block_b, block), (block_b, block)),
         interpret=interpret,
+        name="fused_input_fwd" if with_deriv else "fused_input_infer",
     )(act_ids, x, w, bias, mask)
     return y
 
@@ -193,6 +194,7 @@ def fused_input_int8_fwd(x: jax.Array, w_q: jax.Array, w_scale: jax.Array,
             (block_b, block_f), (block, block_f), (1, block),
             (1, block), (block_b, block), (block_b, block)),
         interpret=interpret,
+        name="fused_input_infer_int8",
     )(act_ids, w_scale, x, w_q, bias, mask)
 
 
@@ -287,5 +289,6 @@ def fused_input_bwd(dy: jax.Array, gp: jax.Array, x: jax.Array,
             (block, block_f), (b, block_f), (block, block_f),
             (b, block_f), (block, block_f)),
         interpret=interpret,
+        name="fused_input_bwd",
     )(dy, gp, x, w)
     return dx, dw

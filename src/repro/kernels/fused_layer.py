@@ -128,6 +128,7 @@ def fused_layer_fwd(x: jax.Array, wb: jax.Array, bias: jax.Array,
             (block_b, block), (block, block), (1, block), (1, block),
             (block_b, block), (block_b, block), (block_b, block)),
         interpret=interpret,
+        name="fused_mid_fwd" if with_deriv else "fused_mid_infer",
     )(s_in, s_w, s_out, s_first, s_last, s_act, x, wb, bias, mask)
     return y
 
@@ -211,6 +212,7 @@ def fused_layer_int8_fwd(x: jax.Array, wb_q: jax.Array, wb_scale: jax.Array,
             (block_b, block), (block, block), (1, block), (1, block),
             (block_b, block), (block_b, block)),
         interpret=interpret,
+        name="fused_mid_infer_int8",
     )(s_in, s_w, s_out, s_first, s_last, s_act, wb_scale, x, wb_q, bias,
       mask)
 
@@ -325,5 +327,6 @@ def fused_layer_dx_dw(dy: jax.Array, gp: jax.Array, x: jax.Array,
             (block, block), (block_b, block), (block, block),
             (b, block), (block, block)),
         interpret=interpret,
+        name="fused_mid_bwd",
     )(s_in_t, s_w_t, s_out_t, s_first_t, s_last_t, s_q_t, dy, gp, x, wb_t)
     return dx, dwb[:n_param_blocks]

@@ -107,6 +107,7 @@ def infer_head_fwd(h: jax.Array, w2: jax.Array, b2: jax.Array,
             (block_b, block_h), (o, block_h), (1, o),
             (block_b, o), (block_b, o)),
         interpret=interpret,
+        name="infer_head",
     )(seg, h, w2, b2.reshape(p, 1, o))
 
 
@@ -184,4 +185,5 @@ def infer_head_int8_fwd(h: jax.Array, w2_q: jax.Array, w2_scale: jax.Array,
             (block_b, block_h), (o, block_h), (1, o),
             (block_b, o), (block_b, o)),
         interpret=interpret,
+        name="infer_head_int8",
     )(seg, w2_scale, h, w2_q, b2.reshape(p, 1, o))

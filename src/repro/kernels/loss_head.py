@@ -146,6 +146,7 @@ def loss_head_fwd(h: jax.Array, w2: jax.Array, b2: jax.Array,
             (block_b, block_h), (o, block_h), (1, o), (block_b, 1),
             (1, p), (block_b, o), (block_b, o), (1, p)),
         interpret=interpret,
+        name="loss_head_fwd" if with_dl else "loss_head_eval",
     )(seg, h, w2, b2.reshape(p, 1, o), targets)
     return res
 
@@ -221,5 +222,6 @@ def loss_head_bwd(dper: jax.Array, dl: jax.Array, h: jax.Array,
             (1, 1), (block_b, o), (block_b, block_h), (o, block_h),
             (block_b, block_h), (o, block_h), (o, block_h)),
         interpret=interpret,
+        name="loss_head_bwd",
     )(seg, dper.reshape(p, 1, 1), dl, h, w2)
     return dh, dw

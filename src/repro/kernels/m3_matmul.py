@@ -73,6 +73,7 @@ def m3_matmul_fwd(h: jax.Array, w2: jax.Array, block_seg_ids: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((b, num_members, o), h.dtype),
         interpret=interpret,
+        name="m3_fwd",
     )(block_seg_ids, h, w2)
 
 
@@ -108,6 +109,7 @@ def m3_matmul_dh(dy: jax.Array, w2: jax.Array, block_seg_ids: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((b, hh), dy.dtype),
         interpret=interpret,
+        name="m3_dh",
     )(block_seg_ids, dy, w2)
 
 
@@ -155,4 +157,5 @@ def m3_matmul_dw(dy: jax.Array, h: jax.Array, block_seg_ids: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((o, hh), h.dtype),
         interpret=interpret,
+        name="m3_dw",
     )(block_seg_ids, dy, h)

@@ -61,4 +61,5 @@ def moe_gemm(x: jax.Array, w: jax.Array, block_expert_ids: jax.Array, *,
         ),
         out_shape=jax.ShapeDtypeStruct((t, f), x.dtype),
         interpret=interpret,
+        name="moe_gemm",
     )(block_expert_ids, x, w)

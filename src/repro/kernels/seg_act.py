@@ -43,6 +43,7 @@ def seg_act(h: jax.Array, block_act_ids: jax.Array, mask: jax.Array, *,
         ),
         out_shape=jax.ShapeDtypeStruct((b, hh), h.dtype),
         interpret=interpret,
+        name="seg_act_fwd",
     )(block_act_ids, h, mask)
 
 
@@ -76,4 +77,5 @@ def seg_act_bwd(h: jax.Array, dy: jax.Array, block_act_ids: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((b, hh), h.dtype),
         interpret=interpret,
+        name="seg_act_bwd",
     )(block_act_ids, h, dy, mask)

@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import set_mesh
+from jax.profiler import StepTraceAnnotation
 
 from repro.checkpoint import latest_steps, restore
 from repro.configs import get_arch
@@ -53,6 +54,36 @@ from repro.launch.cells import build_optimizer
 from repro.launch.mesh import make_host_mesh
 from repro.models import encdec, lm
 from repro.optim import warmup_cosine
+
+
+class ChunkTrace:
+    """``--trace-dir``: a profiler trace of chunks ``FIRST``..``LAST`` of
+    the first segment, after chunk 0's compile, so that the ``train_chunk``
+    steps, the data plane's spans and the named kernels share one clock.
+    The trace ends when chunk ``LAST`` has finished on the device, or with
+    the segment; no later segment is traced."""
+
+    FIRST, LAST = 1, 3
+
+    def __init__(self, trace_dir):
+        self.dir = trace_dir
+        self.on = False
+        self.done = trace_dir is None
+
+    def chunk_start(self, c, state):
+        """Called before chunk ``c`` with its input ``state``."""
+        if self.on and c > self.LAST:
+            jax.block_until_ready(state)
+            self.stop()
+        elif not self.done and not self.on and c == self.FIRST:
+            jax.profiler.start_trace(self.dir)
+            self.on = True
+
+    def stop(self):
+        if self.on:
+            jax.profiler.stop_trace()
+            self.on = False
+        self.done = True
 
 
 def _init_sharded(init_fn, specs_fn, mesh):
@@ -185,8 +216,7 @@ def run_population(arch, args, report=None, mesh=None):
 
     ``report`` (optional dict) receives the run's counters: ``restarts``
     (TrainRunner replays, summed over segments), ``compile_s`` (wall of the
-    first chunk call — trace + compile + dispatch), ``chunk_done_s``
-    (host clock after each chunk's blocking metric read), ``first_loss`` /
+    first chunk call — trace + compile + dispatch), ``first_loss`` /
     ``last_loss`` (mean over real members) and ``per_member_last`` (the
     last step's per-member losses, real members).  ``mesh`` overrides the
     default ``make_host_mesh()`` over every local device."""
@@ -517,10 +547,11 @@ def run_population(arch, args, report=None, mesh=None):
         total = args.steps
         print_every = max(50 // scan, 1)
         stats = report if report is not None else {}
-        stats.update(restarts=0, chunk_done_s=[])
+        stats.update(restarts=0)
         pipeline = args.pipeline == "on"
         pf = None          # ONE Prefetcher for the run, retargeted per rung
-        pending = []       # the in-flight chunk's DeferredMetrics (≤ 1)
+        pending = []       # the in-flight (chunk, DeferredMetrics) (≤ 1)
+        tracer = ChunkTrace(args.trace_dir)
         # chunk programs keyed (layout, optimizer epoch): a rung boundary
         # that changes neither — the constant-size refill — reuses the
         # SAME traced callable, so its jitted executable is a guaranteed
@@ -615,7 +646,6 @@ def run_population(arch, args, report=None, mesh=None):
                     # too but must not dilute the reported loss (a sharded
                     # run prints the same numbers as its single-device twin)
                     per = np.asarray(pers[:, :lp.num_real])
-                    stats["chunk_done_s"].append(time.perf_counter())
                     stats.setdefault("first_loss", float(per[0].mean()))
                     mean = float(per[-1].mean())
                     stats["last_loss"] = mean
@@ -636,29 +666,33 @@ def run_population(arch, args, report=None, mesh=None):
             def step_fn(state, c):
                 g0 = seg_start + c * scan
                 n = min(scan, seg_end - g0)
-                xs, ys = (pf.get(c) if pipeline
-                          else build_slab(c, sync_staging))
-                # with a schedule, the chunk takes the chunk-start GLOBAL
-                # step and carries it through the scan — g0 is derived from
-                # the segment, so crash replay and --resume stay consistent
-                sched_args = ((jnp.asarray(g0, jnp.int32),) if lr_sched
-                              else ())
-                t_call = time.perf_counter()
-                p, st, _losses, pers, gnorms = chunk_fn(
-                    state["params"], state["extra"], xs, ys, lr,
-                    *sched_args)
+                tracer.chunk_start(c, state)
+                with StepTraceAnnotation("train_chunk", step_num=c):
+                    xs, ys = (pf.get(c) if pipeline
+                              else build_slab(c, sync_staging))
+                    # with a schedule, the chunk takes the chunk-start
+                    # GLOBAL step and carries it through the scan — g0 is
+                    # derived from the segment, so crash replay and
+                    # --resume stay consistent
+                    sched_args = ((jnp.asarray(g0, jnp.int32),) if lr_sched
+                                  else ())
+                    t_call = time.perf_counter()
+                    p, st, _losses, pers, gnorms = chunk_fn(
+                        state["params"], state["extra"], xs, ys, lr,
+                        *sched_args)
                 # the first call traces and compiles before it dispatches
                 stats.setdefault("compile_s", time.perf_counter() - t_call)
                 dm = DeferredMetrics(resolve_metrics(pers, gnorms, g0, n, c))
-                if pipeline:
-                    # chunk c is dispatched; NOW pay chunk c-1's host fetch
-                    # while c runs (the final chunk resolves after run())
-                    while pending:
-                        pending.pop(0).force()
-                    pending.append(dm)
-                else:
-                    dm.force()
+                # pipelined: chunk c is dispatched; NOW pay chunk c-1's host
+                # fetch while c runs (the final chunk resolves after run())
+                pending.append((c, dm))
+                resolve_pending(keep=1 if pipeline else 0)
                 return {"params": p, "extra": st}, dm
+
+            def resolve_pending(keep=0):
+                while len(pending) > keep:
+                    pc, pdm = pending.pop(0)
+                    pdm.force(chunk=pc)
 
             def on_restore(c):
                 # crash replay: metrics queued for the abandoned trajectory
@@ -692,12 +726,19 @@ def run_population(arch, args, report=None, mesh=None):
                 on_restore=on_restore,
                 mesh=mesh, state_specs={"params": lp.param_specs(),
                                         "extra": lp.opt_specs(opt)})
-            runner.run(n_chunks)
+            try:
+                runner.run(n_chunks)
+                # the segment's last chunk still owes its host fetch —
+                # resolve it before the rung boundary / final eval reads
+                # stats
+                resolve_pending()
+            finally:
+                tracer.stop()
             stats["restarts"] += runner.restarts
-            # the segment's last chunk still owes its host fetch — resolve
-            # it before the rung boundary / final eval reads stats
-            while pending:
-                pending.pop(0).force()
+            if pf is not None:
+                print("prefetch: " + " ".join(
+                    f"{k} {v:.4f}" if isinstance(v, float) else f"{k} {v}"
+                    for k, v in pf.stats.items()))
             # planned work, counted once per segment (a crash-replayed
             # chunk must not inflate the reported throughput)
             stats["member_steps"] = (stats.get("member_steps", 0)
@@ -1081,6 +1122,13 @@ def main(argv=None, report=None, mesh=None):
                          "until the next chunk is dispatched.  "
                          "Bit-identical trajectory to 'off' (the "
                          "synchronous build-then-dispatch loop)")
+    ap.add_argument("--trace-dir", default=None,
+                    help="population path: write a profiler trace of "
+                         "chunks 1-3 of the first segment (after the "
+                         "compile) under DIR: the train_chunk steps, the "
+                         "data plane's prefetch.build / prefetch.wait / "
+                         "metrics.resolve spans and the named Pallas "
+                         "kernels on one clock")
     ap.add_argument("--prefetch-depth", type=int, default=2,
                     help="--pipeline on: producer queue bound — how many "
                          "chunks the data plane may run ahead before "

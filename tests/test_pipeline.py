@@ -9,6 +9,7 @@ import sys
 import threading
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -196,6 +197,80 @@ def test_deferred_metrics_lazy_and_cached():
 
 
 # --------------------------------------------------------------------- #
+# counters and spans                                                    #
+# --------------------------------------------------------------------- #
+
+
+def _host_events(trace_dir) -> list:
+    """``(name, thread, stats)`` of every host event of the one profiler
+    trace under ``trace_dir``; ``thread`` is the event's line index."""
+    import pathlib
+
+    from jax.profiler import ProfileData
+    path, = pathlib.Path(trace_dir).rglob("*.xplane.pb")
+    return [(e.name, i, dict(e.stats))
+            for plane in ProfileData.from_file(str(path)).planes
+            if plane.name.startswith("/host:")
+            for i, line in enumerate(plane.lines) for e in line.events]
+
+
+def test_prefetcher_stats_count_builds_and_gets():
+    with Prefetcher(lambda c, s: c, 5) as pf:
+        for c in range(3):
+            pf.get(c)
+        deadline = time.monotonic() + 5.0
+        while pf.stats["built"] < 5 and time.monotonic() < deadline:
+            time.sleep(0.01)
+    st = pf.stats
+    assert (st["built"], st["got"]) == (5, 3)
+    assert st["build_s"] >= 0 and st["wait_s"] >= 0
+    assert 0 <= st["starved"] <= 3
+
+
+def test_prefetcher_slow_producer_starves_and_records_wait(tmp_path):
+    """A get that finds the queue empty counts ``starved`` and opens a
+    ``prefetch.wait`` span on the consumer's thread; the build it waits
+    for is a ``prefetch.build`` span of the same chunk on the producer's."""
+    def produce(c, staging):
+        time.sleep(0.05)
+        return c
+
+    with jax.profiler.trace(str(tmp_path)), Prefetcher(produce, 1) as pf:
+        assert pf.get(0) == 0
+    st = pf.stats
+    assert (st["built"], st["got"], st["starved"]) == (1, 1, 1)
+    assert st["wait_s"] > 0.02 and st["build_s"] > 0.04
+    ev = _host_events(tmp_path)
+    wait = [(t, a) for n, t, a in ev if n == "prefetch.wait"]
+    build = [(t, a) for n, t, a in ev if n == "prefetch.build"]
+    assert [a["chunk"] for _, a in wait] == [0]
+    assert [a["chunk"] for _, a in build] == [0]
+    assert wait[0][0] != build[0][0]
+
+
+def test_prefetcher_full_queue_records_no_wait(tmp_path):
+    with Prefetcher(lambda c, s: c, 4, depth=2) as pf:
+        deadline = time.monotonic() + 5.0
+        while not pf._q.full() and time.monotonic() < deadline:
+            time.sleep(0.01)
+        with jax.profiler.trace(str(tmp_path)):
+            assert [pf.get(0), pf.get(1)] == [0, 1]
+    assert pf.stats["starved"] == 0 and pf.stats["wait_s"] == 0.0
+    assert not [n for n, _t, _a in _host_events(tmp_path)
+                if n == "prefetch.wait"]
+
+
+def test_deferred_metrics_resolve_span_once(tmp_path):
+    m = DeferredMetrics(lambda: {"loss": 0.5})
+    with jax.profiler.trace(str(tmp_path)):
+        assert m.force(chunk=7) == {"loss": 0.5}
+        assert m["loss"] == 0.5          # cached: no second span
+    spans = [a for n, _t, a in _host_events(tmp_path)
+             if n == "metrics.resolve"]
+    assert spans == [{"chunk": 7}]
+
+
+# --------------------------------------------------------------------- #
 # slab builds: value parity with per-step batch()                       #
 # --------------------------------------------------------------------- #
 
@@ -293,6 +368,29 @@ def test_pipeline_bit_identical_adafactor_halving(tmp_path):
     zb = _final_ckpt_arrays(tmp_path, "off")
     for k in za.files:
         np.testing.assert_array_equal(za[k], zb[k], err_msg=k)
+
+
+def test_trace_dir_traces_chunks_one_to_three(tmp_path, capsys):
+    """``--trace-dir`` writes a profiler trace of chunks 1-3 of the first
+    segment: ``train_chunk`` steps, the data plane's spans and the
+    metric fetches, tagged with their chunk; each segment's end prints
+    the prefetcher's counters."""
+    _drive(tmp_path, "traced", True,
+           ("--trace-dir", str(tmp_path / "trace"), "--steps", "16",
+            "--halving", "12:0.5"))
+    ev = _host_events(tmp_path / "trace")
+    steps = sorted(a["step_num"] for n, _t, a in ev if n == "train_chunk")
+    assert steps == [1, 2, 3]
+    resolved = sorted(a["chunk"] for n, _t, a in ev
+                      if n == "metrics.resolve")
+    assert resolved and set(resolved) <= {0, 1, 2, 3}
+    # chunk 4's slab is built while chunks 1-3 run (chunk 1's get frees
+    # its queue slot)
+    assert 4 in [a["chunk"] for n, _t, a in ev if n == "prefetch.build"]
+    out = capsys.readouterr().out
+    lines = [ln for ln in out.splitlines() if ln.startswith("prefetch: ")]
+    assert len(lines) == 2               # one per segment
+    assert "built" in lines[0] and "starved" in lines[0]
 
 
 # --------------------------------------------------------------------- #
