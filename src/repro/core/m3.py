@@ -129,8 +129,7 @@ def m3(h: jax.Array, w2: jax.Array, pop: Population,
 
 def m3_loss_head(h: jax.Array, w2: jax.Array, b2: jax.Array,
                  targets: jax.Array, pop: Population, *,
-                 interpret: bool | None = None,
-                 block_b: int = 128) -> jax.Array:
+                 interpret: bool | None = None) -> jax.Array:
     """The training-time fusion of M3: projection + per-member bias +
     softmax cross-entropy + dlogits in one Pallas launch per direction
     (kernels/loss_head.py, DESIGN.md §9) — the logits never reach HBM.
@@ -139,8 +138,7 @@ def m3_loss_head(h: jax.Array, w2: jax.Array, b2: jax.Array,
     from repro.kernels.ops import loss_head  # lazy: kernels import pallas
     return loss_head(h, w2, b2, targets,
                      np.asarray(pop.block_segment_ids),
-                     block_h=pop.block, block_b=block_b,
-                     interpret=interpret)
+                     block_h=pop.block, interpret=interpret)
 
 
 # loss-head impls that bypass logits materialisation entirely; the name

@@ -599,34 +599,31 @@ def seg_act(h: jax.Array, block_act_ids: np.ndarray, mask: np.ndarray, *,
 # --------------------------------------------------------------------- #
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
-def _lh_core(h, w2, b2, tgt, seg, b_real, block_h, block_b, interpret):
+def _lh_core(h, w2, b2, tgt, seg, b_real, block_h, g, interpret):
     """Primal (no-grad contexts): per-member losses only, dlogits_base is
     only emitted when a VJP will consume it.  The per-block member ids are
     an OPERAND, so a member-sharded caller hands each shard its own."""
-    per = _lhk.loss_head_fwd(
-        h, w2, b2, tgt, seg, b2.shape[0], b_real=b_real, block_h=block_h,
-        block_b=block_b, with_dl=False, interpret=interpret)
-    return per[0]
+    return _lhk.loss_head_fwd(h, w2, b2, tgt, seg, b_real=b_real,
+                              block_h=block_h, g=g, with_dl=False,
+                              interpret=interpret)
 
 
-def _lh_fwd(h, w2, b2, tgt, seg, b_real, block_h, block_b, interpret):
-    per, dl = _lhk.loss_head_fwd(
-        h, w2, b2, tgt, seg, b2.shape[0], b_real=b_real, block_h=block_h,
-        block_b=block_b, with_dl=True, interpret=interpret)
-    return per[0], (h, w2, dl, seg)
+def _lh_fwd(h, w2, b2, tgt, seg, b_real, block_h, g, interpret):
+    per, dl_sum, dl = _lhk.loss_head_fwd(
+        h, w2, b2, tgt, seg, b_real=b_real, block_h=block_h, g=g,
+        with_dl=True, interpret=interpret)
+    return per, (h, w2, dl, seg, dl_sum)
 
 
-def _lh_bwd(b_real, block_h, block_b, interpret, res, dper):
-    h, w2, dl, seg = res
+def _lh_bwd(b_real, block_h, g, interpret, res, dper):
+    h, w2, dl, seg, dl_sum = res
     dper = dper.astype(jnp.float32)
-    dh, dw = _lhk.loss_head_bwd(
-        dper, dl, h, w2, seg, block_h=block_h, block_b=block_b,
-        interpret=interpret)
-    # bias cotangent: one fused XLA reduce over the (P, B, O) array that
-    # exists anyway
-    db = dper[:, None] * dl.sum(axis=1)
+    dh, dw = _lhk.loss_head_bwd(dper, dl, h, w2, seg, block_h=block_h, g=g,
+                                interpret=interpret)
+    # the bias cotangent from the batch sums the forward emitted
+    db = dper[:, None] * dl_sum
     # integer targets carry a float0 cotangent
-    dt = np.zeros((h.shape[0], 1), jax.dtypes.float0)
+    dt = np.zeros((1, h.shape[0]), jax.dtypes.float0)
     return dh, dw, db, dt, None
 
 
@@ -635,16 +632,16 @@ _lh_core.defvjp(_lh_fwd, _lh_bwd)
 
 def loss_head(h: jax.Array, w_out: jax.Array, b_out: jax.Array,
               targets: jax.Array, block_seg_ids, *,
-              block_h: int, block_b: int = 128,
-              interpret: bool | None = None) -> jax.Array:
+              block_h: int, interpret: bool | None = None) -> jax.Array:
     """Output projection + per-member softmax cross-entropy in one Pallas
     pass (kernels/loss_head.py; DESIGN.md §9); differentiable (fused
-    one-pass custom VJP emitting dh and dW_out together); pads B.
+    one-pass custom VJP emitting dh and dW_out together); pads B to 8.
 
     h (B, H), w_out (O, H), b_out (P, O), integer targets (B,) →
     per-member mean NLL (P,) f32 — ``per.sum()`` is the scalar training
     loss and matches the XLA log_softmax reference to f32 tolerance.
-    O stays unpadded: it is the whole last dim of every head block.
+    Every grid step takes the whole batch and ``loss_head.blocks_per_tile``
+    hidden blocks (about 2 MiB of h).
     ``block_seg_ids`` may be a numpy constant or a traced array (one
     member shard's local ids under ``shard_map``).
     H must already be block_h-aligned (Population guarantees this).
@@ -654,14 +651,15 @@ def loss_head(h: jax.Array, w_out: jax.Array, b_out: jax.Array,
     _check_block(block_h, interpret)
     if h.shape[1] % block_h:
         raise ValueError(f"hidden axis {h.shape[1]} not {block_h}-aligned")
-    block_b = min(block_b, max(8, 1 << (h.shape[0] - 1).bit_length()))
-    hp, b0 = _pad_axis(h, 0, block_b)
+    hp, b0 = _pad_axis(h, 0, 8)
+    g = _lhk.blocks_per_tile(h.shape[1] // block_h, hp.shape[0], block_h,
+                             hp.dtype.itemsize)
     # pad rows carry target −1 → zero loss weight, zero dlogits
-    tp = jnp.pad(targets.astype(jnp.int32).reshape(-1, 1),
-                 ((0, hp.shape[0] - b0), (0, 0)), constant_values=-1)
+    tp = jnp.pad(targets.astype(jnp.int32).reshape(1, -1),
+                 ((0, 0), (0, hp.shape[0] - b0)), constant_values=-1)
     return _lh_core(hp, w_out, b_out.astype(jnp.float32), tp,
-                    jnp.asarray(block_seg_ids, jnp.int32), b0, block_h,
-                    block_b, interpret)
+                    jnp.asarray(block_seg_ids, jnp.int32), b0, block_h, g,
+                    interpret)
 
 
 def infer_head(h: jax.Array, w_out: jax.Array, b_out: jax.Array,

@@ -13,6 +13,7 @@ from repro.core.activations import ACTIVATION_ORDER
 from repro.core.deep import init_params
 from repro.core.m3 import (FUSED_LOSS_IMPLS, LOSS_IMPLS, m3, m3_loss_head)
 from repro.core.population import LayeredPopulation
+from repro.kernels import loss_head
 
 _WIDTHS = ((5, 3), (12, 9), (7,), (17, 9, 5), (8, 8),
            (5, 3), (3, 11, 2), (24, 16), (4,), (9, 9, 9))
@@ -104,3 +105,173 @@ def test_bf16_operands_f32_loss():
     assert pf.dtype == jnp.float32
     np.testing.assert_allclose(np.asarray(pe, dtype=np.float32),
                                np.asarray(pf), rtol=5e-2, atol=5e-2)
+
+
+# Re-tiled grid: every step takes the whole batch and ``blocks_per_tile``
+# hidden blocks.  Members of 1–3 blocks of 8 lanes (26 blocks, 13 members)
+# at 8 blocks per tile put tile boundaries inside members (blocks 7|8,
+# 15|16, 23|24) and leave the last tile two blocks full.
+_TILED_WIDTHS = ((5,), (12,), (20,), (8,), (17,), (3,), (24,), (9,),
+                 (16,), (2,), (23,), (7,), (11,))
+_TILE = 8
+
+
+def _tiled_pop(o):
+    acts = tuple(ACTIVATION_ORDER[i % len(ACTIVATION_ORDER)]
+                 for i in range(len(_TILED_WIDTHS)))
+    lp = LayeredPopulation(6, o, _TILED_WIDTHS, acts, block=8)
+    return lp, lp.layer_pop(0)
+
+
+def test_tiled_layout_crosses_tile_boundaries():
+    """The layout the tiled cases below rely on: members span 1–3 blocks,
+    some cross a tile boundary, and the last tile is partial."""
+    _, pop = _tiled_pop(2)
+    seg = np.asarray(pop.block_segment_ids)
+    assert set(np.bincount(seg)) == {1, 2, 3}
+    crossing = [t for t in range(_TILE, len(seg), _TILE)
+                if seg[t - 1] == seg[t]]
+    assert crossing
+    assert len(seg) % _TILE and pop.num_members % _TILE
+
+
+def _tiled_inputs(o, b, seed):
+    lp, pop = _tiled_pop(o)
+    params = init_params(jax.random.PRNGKey(seed), lp)
+    h = jax.random.normal(jax.random.PRNGKey(seed + 1),
+                          (b, pop.total_hidden))
+    y = jax.random.randint(jax.random.PRNGKey(seed + 2), (b,), 0, o)
+    return pop, h, params["w_out"], params["b_out"], y
+
+
+def _per_ref_pop(pop, h, w2, b2, y):
+    logits = m3(h, w2, pop, impl="bucketed") + b2
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    nll = -jnp.take_along_axis(logp, y[:, None, None], axis=2)[:, :, 0]
+    return nll.mean(axis=0)
+
+
+_TILED_CASES = [(o, b) for o in (2, 3) for b in (9, 32, 256)]
+
+
+def _tile(monkeypatch, b, g):
+    """Steer the head to ``g`` blocks of 8 lanes per grid step at batch b
+    (padded to 8 rows) through the bytes a step aims to read."""
+    monkeypatch.setattr(loss_head, "TILE_BYTES", -(-b // 8) * 8 * 8 * 4 * g)
+
+
+@pytest.mark.parametrize("o,b", _TILED_CASES)
+def test_tiled_per_member_loss(monkeypatch, o, b):
+    _tile(monkeypatch, b, _TILE)
+    pop, h, w2, b2, y = _tiled_inputs(o, b, seed=11)
+    pe = _per_ref_pop(pop, h, w2, b2, y)
+    pf = m3_loss_head(h, w2, b2, y, pop)
+    np.testing.assert_allclose(np.asarray(pe), np.asarray(pf),
+                               rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("o,b", _TILED_CASES)
+def test_tiled_grads_with_per_member_cotangent(monkeypatch, o, b):
+    """h/W_out/b_out gradients under a NON-uniform per-member cotangent:
+    the reverse-order backward must carry each member's scaled dlogits
+    from its last block back over a tile boundary to its first."""
+    _tile(monkeypatch, b, _TILE)
+    pop, h, w2, b2, y = _tiled_inputs(o, b, seed=13)
+    wts = jnp.linspace(0.1, 2.0, pop.num_members)
+    ge = jax.grad(lambda *a: (_per_ref_pop(pop, *a, y) * wts).sum(),
+                  argnums=(0, 1, 2))(h, w2, b2)
+    gf = jax.grad(lambda *a: (m3_loss_head(*a, y, pop) * wts).sum(),
+                  argnums=(0, 1, 2))(h, w2, b2)
+    for a, f in zip(ge, gf):
+        assert f.shape == a.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(f),
+                                   rtol=1e-4, atol=1e-6)
+
+
+@pytest.mark.parametrize("tile", [8, 16, 26])
+def test_tiling_does_not_change_result(monkeypatch, tile):
+    """The same losses and gradients at 8, 16 and every block per tile."""
+    pop, h, w2, b2, y = _tiled_inputs(2, 32, seed=17)
+
+    def run(g):
+        _tile(monkeypatch, 32, g)
+        return jax.value_and_grad(
+            lambda hh: m3_loss_head(hh, w2, b2, y, pop).sum())(h)
+    (l1, g1), (l2, g2) = run(tile), run(1024)
+    np.testing.assert_allclose(float(l1), float(l2), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(g1), np.asarray(g2),
+                               rtol=1e-5, atol=1e-7)
+
+
+@pytest.mark.parametrize("n_blocks,batch,want", [
+    (10_000, 256, 16),      # paper-40k, one chip: 2 MiB of h a step
+    (1_792, 256, 16),       # deep-1k's last layer
+    (10_000, 32, 128),      # a smaller batch takes more blocks
+    (10_000, 8, 512),
+    (100, 8, 100),          # one tile holds every block
+    (5_000, 4096, 8),       # never fewer than the 8-row dlogits tile
+])
+def test_blocks_per_tile(n_blocks, batch, want):
+    assert loss_head.blocks_per_tile(n_blocks, batch, 128, 4) == want
+
+
+_SHARDED_SCRIPT = r"""
+import jax, jax.numpy as jnp, numpy as np
+from repro.core import deep
+from repro.core.population import LayeredPopulation
+from repro.distributed.sharding import population_shardings
+from repro.kernels import loss_head
+from repro.launch.mesh import make_mesh
+
+B = 16
+# 8 blocks of 8 lanes per grid step at B = 16, so each shard's head runs
+# several tiles with members crossing their boundaries
+loss_head.TILE_BYTES = B * 8 * 4 * 8
+# four equal quarters, so each shard owns whole members' blocks
+widths = tuple((w,) for w in (3, 12, 20, 7, 17, 24, 5, 9, 14, 2, 23, 11) * 4)
+acts = tuple(("relu", "tanh", "gelu")[i % 3] for i in range(len(widths)))
+lp = LayeredPopulation(6, 2, widths, acts, block=8).shard_pad(4)
+seg = np.asarray(lp.layer_pop(0).block_segment_ids)
+assert deep._member_sharded_unsupported(lp, "fused", None, 4) is None
+assert len(seg) // 4 > 16, len(seg)
+params = deep.init_params(jax.random.PRNGKey(0), lp)
+x = jax.random.normal(jax.random.PRNGKey(1), (B, 6))
+y = jax.random.randint(jax.random.PRNGKey(2), (B,), 0, 2)
+wts = jnp.linspace(0.5, 1.5, lp.num_members)
+
+def loss(p):
+    per = deep.fused_loss(p, x, y, lp, bd_impl="fused")[1]
+    return (per * wts).sum(), per
+
+(l1, per1), g1 = jax.value_and_grad(loss, has_aux=True)(params)
+mesh = make_mesh((1, 4), ("data", "model"))
+with jax.set_mesh(mesh):
+    ps = jax.device_put(params, population_shardings(lp, mesh))
+    (l4, per4), g4 = jax.jit(jax.value_and_grad(loss, has_aux=True))(ps)
+np.testing.assert_allclose(np.asarray(per4), np.asarray(per1),
+                           rtol=1e-5, atol=1e-6)
+jax.tree.map(lambda a, b: np.testing.assert_allclose(
+    np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-6), g4, g1)
+print("OK", len(seg))
+"""
+
+
+def test_member_sharded_head_matches_one_device():
+    """The member-sharded fused loss (``deep._fused_loss_member_sharded``:
+    the head under ``shard_map``, 4 virtual CPU devices, a ``shard_pad``ded
+    paper-like layout) against one device: per-member losses and every
+    gradient, with each shard's head running several tiles."""
+    import os
+    import pathlib
+    import subprocess
+    import sys
+    root = pathlib.Path(__file__).resolve().parents[1]
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = os.pathsep.join(
+        [str(root / "src")] + env.get("PYTHONPATH", "").split(os.pathsep))
+    r = subprocess.run([sys.executable, "-c", _SHARDED_SCRIPT], env=env,
+                       capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
+    assert "OK" in r.stdout

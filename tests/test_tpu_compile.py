@@ -128,20 +128,44 @@ def test_fused_layer_fwd_bwd_block128(one_chip, deep_lp, block_b):
     assert _n_kernels(c) == 2 * (deep_lp.depth - 1)
 
 
-def test_loss_head_paper_members(one_chip, paper_lp):
-    """Projection + softmax-XE + dlogits at P = 10,000 (the seg table
-    rides scalar prefetch), forward and backward."""
-    pop = paper_lp.layer_pop(0)
-
+def _loss_head_fwd_bwd(pop, one_chip):
     def fwd_bwd(h, w, b, y):
         per, vjp = jax.vjp(lambda h, w, b: ops.loss_head(
             h, w, b, y, pop.block_segment_ids, block_h=128), h, w, b)
         return per, vjp(jnp.ones_like(per))
 
-    c = _compile(fwd_bwd, one_chip, _sds((BATCH, pop.total_hidden)),
-                 _sds((2, pop.total_hidden)), _sds((10_000, 2)),
-                 _sds((BATCH,), jnp.int32))
+    return _compile(fwd_bwd, one_chip, _sds((BATCH, pop.total_hidden)),
+                    _sds((2, pop.total_hidden)), _sds((pop.num_members, 2)),
+                    _sds((BATCH,), jnp.int32))
+
+
+# the head's own temporaries: dlogits stored lane-dense are P·O·B f32
+# (20.5 MB at P = 10,000, B = 256); with the classes padded to 128 lanes
+# they were 1.31 GB
+HEAD_TEMP_BYTES = 64 * 1024 * 1024
+
+
+def test_loss_head_paper_members(one_chip, paper_lp):
+    """Projection + softmax-XE + dlogits at P = 10,000 (the seg table
+    rides scalar prefetch), forward and backward."""
+    pop = paper_lp.layer_pop(0)
+    c = _loss_head_fwd_bwd(pop, one_chip)
     assert _n_kernels(c) == 2
+    assert c.memory_analysis().temp_size_in_bytes <= HEAD_TEMP_BYTES
+
+
+def test_loss_head_deep_last_layer(one_chip):
+    """The head over deep-1k's last layer at B = 256: 1,024 members of 1,
+    2 and 3 blocks (1,792 blocks), so members span grid-step tiles."""
+    widths = ((512, 256), (256, 128, 64), (384,), (128,)) * 256
+    acts = tuple((PAPER_TEN[(i // 4) % 10],) * len(w)
+                 for i, w in enumerate(widths))
+    lp = LayeredPopulation(100, 2, widths, acts, block=128).sorted()
+    pop = lp.layer_pop(lp.depth - 1)
+    assert pop.total_hidden // 128 == 1792
+    c = _loss_head_fwd_bwd(pop, one_chip)
+    assert _n_kernels(c) == 2
+    assert c.memory_analysis().temp_size_in_bytes <= HEAD_TEMP_BYTES
 
 
 @pytest.mark.parametrize("log_probs", [False, True])
