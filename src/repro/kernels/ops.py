@@ -600,26 +600,27 @@ def seg_act(h: jax.Array, block_act_ids: np.ndarray, mask: np.ndarray, *,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
 def _lh_core(h, w2, b2, tgt, seg, b_real, block_h, g, interpret):
-    """Primal (no-grad contexts): per-member losses only, dlogits_base is
-    only emitted when a VJP will consume it.  The per-block member ids are
-    an OPERAND, so a member-sharded caller hands each shard its own."""
+    """Primal (no-grad contexts): per-member losses only; what the backward
+    needs (dlogits, or the carries it recomputes them from) is only
+    emitted when a VJP will consume it.  The per-block member ids are an
+    OPERAND, so a member-sharded caller hands each shard its own."""
     return _lhk.loss_head_fwd(h, w2, b2, tgt, seg, b_real=b_real,
-                              block_h=block_h, g=g, with_dl=False,
+                              block_h=block_h, g=g, for_grad=False,
                               interpret=interpret)
 
 
 def _lh_fwd(h, w2, b2, tgt, seg, b_real, block_h, g, interpret):
-    per, dl_sum, dl = _lhk.loss_head_fwd(
+    per, dl_sum, res = _lhk.loss_head_fwd(
         h, w2, b2, tgt, seg, b_real=b_real, block_h=block_h, g=g,
-        with_dl=True, interpret=interpret)
-    return per, (h, w2, dl, seg, dl_sum)
+        for_grad=True, interpret=interpret)
+    return per, (h, w2, res, seg, dl_sum, tgt)
 
 
 def _lh_bwd(b_real, block_h, g, interpret, res, dper):
-    h, w2, dl, seg, dl_sum = res
+    h, w2, kept, seg, dl_sum, tgt = res
     dper = dper.astype(jnp.float32)
-    dh, dw = _lhk.loss_head_bwd(dper, dl, h, w2, seg, block_h=block_h, g=g,
-                                interpret=interpret)
+    dh, dw = _lhk.loss_head_bwd(dper, kept, h, w2, tgt, seg, b_real=b_real,
+                                block_h=block_h, g=g, interpret=interpret)
     # the bias cotangent from the batch sums the forward emitted
     db = dper[:, None] * dl_sum
     # integer targets carry a float0 cotangent

@@ -28,7 +28,9 @@ NAMES = {
                        "fused_input_infer_int8", "fused_input_bwd"},
     "fused_layer.py": {"fused_mid_fwd", "fused_mid_infer",
                        "fused_mid_infer_int8", "fused_mid_bwd"},
-    "loss_head.py": {"loss_head_fwd", "loss_head_eval", "loss_head_bwd"},
+    "loss_head.py": {"loss_head_fwd", "loss_head_eval", "loss_head_bwd",
+                     "loss_head_many_fwd", "loss_head_many_eval",
+                     "loss_head_many_bwd"},
     "infer_head.py": {"infer_head", "infer_head_int8"},
     "seg_act.py": {"seg_act_fwd", "seg_act_bwd"},
     "block_diag.py": {"block_diag_fwd", "block_diag_dw"},

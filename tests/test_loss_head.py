@@ -151,7 +151,15 @@ def _per_ref_pop(pop, h, w2, b2, y):
     return nll.mean(axis=0)
 
 
-_TILED_CASES = [(o, b) for o in (2, 3) for b in (9, 32, 256)]
+# many classes too (helena's 100, dionis's 355): the kernels take the
+# class axis whole, so their code is the same at every class count.  The
+# tolerances are those of the two-class cases: the kernels and the XLA head
+# both sum in f32, the log-sum-exp over classes in another order, which
+# moves a loss near log(O) by a few ulps (rtol 1e-5) and a gradient entry
+# by round-off relative to its row (rtol 1e-4, atol 1e-6 for entries that
+# cancel to near zero).
+_TILED_CASES = ([(o, b) for o in (2, 3) for b in (9, 32, 256)]
+                + [(o, b) for o in (10, 100, 355) for b in (9, 256)])
 
 
 def _tile(monkeypatch, b, g):
@@ -201,6 +209,63 @@ def test_tiling_does_not_change_result(monkeypatch, tile):
     np.testing.assert_allclose(float(l1), float(l2), rtol=1e-6)
     np.testing.assert_allclose(np.asarray(g1), np.asarray(g2),
                                rtol=1e-5, atol=1e-7)
+
+
+@pytest.mark.parametrize("o", [2, 3])
+def test_recomputed_dlogits_match_stored(monkeypatch, o):
+    """At few classes the forward stores the dlogits; the backward that
+    recomputes them from the carries (the many-class path) gives the same
+    gradients, members crossing tile boundaries included."""
+    _tile(monkeypatch, 32, _TILE)
+    pop, h, w2, b2, y = _tiled_inputs(o, 32, seed=23)
+    wts = jnp.linspace(0.1, 2.0, pop.num_members)
+
+    def grads():
+        return jax.grad(lambda *a: (m3_loss_head(*a, y, pop) * wts).sum(),
+                        argnums=(0, 1, 2))(h, w2, b2)
+    assert loss_head.stores_dlogits(o)
+    stored = grads()
+    monkeypatch.setattr(loss_head, "STORE_MAX_CLASSES", 0)
+    for a, f in zip(stored, grads()):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(f),
+                                   rtol=1e-6, atol=1e-8)
+
+
+def test_member_tables_span_slabs():
+    """310 blocks at 136 a tile: ten two-block members first, so the second
+    tile ends members 126..261 and writes three 128-member slabs of a
+    member table; the last tile holds 38 blocks and clamps its slabs at
+    the table's end.  Every member's row is the one its last block wrote."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    n, g, k = 300, 136, 3
+    seg = np.repeat(np.arange(n), [2] * 10 + [1] * (n - 10))
+    nt, seg_t = loss_head._tiles(jnp.asarray(seg), g)
+    assert len(seg) == 310 and nt == 3
+    assert seg[g] == 126 and seg[2 * g - 1] == 261
+    tab_shape, tab_spec = loss_head._member_table(n, k)
+
+    def kernel(seg_ref, tab_ref):
+        t = pl.program_id(0)
+
+        @pl.when(t == 0)
+        def _zero():
+            tab_ref[...] = jnp.zeros_like(tab_ref)
+        # block j's value: its member's id + 1 + row, on K rows
+        rows = jax.lax.broadcasted_iota(jnp.int32, (k, 1), 0)
+        vals = [(seg_ref[t * g + j + 1] + 1 + rows).astype(jnp.float32)
+                for j in range(g)]
+        loss_head._put_members(tab_ref, seg_ref, t, g, vals)
+
+    tab = pl.pallas_call(
+        kernel, grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(nt,), in_specs=[],
+            out_specs=tab_spec),
+        out_shape=jax.ShapeDtypeStruct(tab_shape, jnp.float32),
+        interpret=True)(seg_t)
+    want = np.arange(1, n + 1)[:, None] + np.arange(k)[None]
+    np.testing.assert_array_equal(np.asarray(loss_head._members(tab, n)),
+                                  want)
 
 
 @pytest.mark.parametrize("n_blocks,batch,want", [
@@ -275,3 +340,61 @@ def test_member_sharded_head_matches_one_device():
                        capture_output=True, text=True, timeout=600)
     assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
     assert "OK" in r.stdout
+
+
+def _kernel_eqns(o: int) -> dict:
+    """Equations in each head kernel's traced body (sub-jaxprs included),
+    forward, backward and eval, at O classes on the tiled layout."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+    pop, h, w2, b2, y = _tiled_inputs(o, 32, seed=19)
+
+    def count(jaxpr) -> int:
+        n = 0
+        for e in jaxpr.eqns:
+            n += 1
+            for v in e.params.values():
+                for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                    if isinstance(sub, ClosedJaxpr):
+                        n += count(sub.jaxpr)
+                    elif isinstance(sub, Jaxpr):
+                        n += count(sub)
+        return n
+
+    def kernels(jaxpr, out):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                out[str(e.params["name"])] = count(
+                    e.params["jaxpr"])
+            for v in e.params.values():
+                for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                    if isinstance(sub, ClosedJaxpr):
+                        kernels(sub.jaxpr, out)
+                    elif isinstance(sub, Jaxpr):
+                        kernels(sub, out)
+        return out
+
+    def train(hh, ww, bb):
+        return jax.value_and_grad(
+            lambda *a: m3_loss_head(*a, y, pop).sum(),
+            argnums=(0, 1, 2))(hh, ww, bb)
+
+    out = kernels(jax.make_jaxpr(train)(h, w2, b2).jaxpr, {})
+    out.update(kernels(jax.make_jaxpr(
+        lambda *a: m3_loss_head(*a, y, pop))(h, w2, b2).jaxpr, {}))
+    return out
+
+
+def test_kernel_code_does_not_grow_with_classes(monkeypatch):
+    """No Python loop runs over the classes in the many-class body: each
+    head kernel's traced body holds as many equations at 355 classes as
+    at 9, the fewest it serves, and as at 2 when it is made to serve
+    them.  Up to ``STORE_MAX_CLASSES`` the few-class body, which unrolls
+    over the classes, serves instead."""
+    nine, many = _kernel_eqns(9), _kernel_eqns(355)
+    assert set(nine) == {"loss_head_many_fwd", "loss_head_many_bwd",
+                         "loss_head_many_eval"}
+    assert nine == many
+    assert set(_kernel_eqns(loss_head.STORE_MAX_CLASSES)) == {
+        "loss_head_fwd", "loss_head_bwd", "loss_head_eval"}
+    monkeypatch.setattr(loss_head, "STORE_MAX_CLASSES", 0)
+    assert _kernel_eqns(2) == many

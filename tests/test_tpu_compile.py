@@ -128,14 +128,14 @@ def test_fused_layer_fwd_bwd_block128(one_chip, deep_lp, block_b):
     assert _n_kernels(c) == 2 * (deep_lp.depth - 1)
 
 
-def _loss_head_fwd_bwd(pop, one_chip):
+def _loss_head_fwd_bwd(pop, one_chip, o=2):
     def fwd_bwd(h, w, b, y):
         per, vjp = jax.vjp(lambda h, w, b: ops.loss_head(
             h, w, b, y, pop.block_segment_ids, block_h=128), h, w, b)
         return per, vjp(jnp.ones_like(per))
 
     return _compile(fwd_bwd, one_chip, _sds((BATCH, pop.total_hidden)),
-                    _sds((2, pop.total_hidden)), _sds((pop.num_members, 2)),
+                    _sds((o, pop.total_hidden)), _sds((pop.num_members, o)),
                     _sds((BATCH,), jnp.int32))
 
 
@@ -166,6 +166,23 @@ def test_loss_head_deep_last_layer(one_chip):
     c = _loss_head_fwd_bwd(pop, one_chip)
     assert _n_kernels(c) == 2
     assert c.memory_analysis().temp_size_in_bytes <= HEAD_TEMP_BYTES
+
+
+@pytest.mark.parametrize("o", [100, 355])
+def test_loss_head_many_classes(one_chip, paper_lp, o):
+    """The head at P = 10,000 one-block members (the paper grid's layout)
+    with helena's 100 classes and dionis's 355, forward and backward.  Its
+    temporaries grow with O only through the carry entering each tile,
+    nt·O·B f32 (65.5 MB at O = 100, 233 MB at O = 355, nt = 625 tiles of
+    16 blocks): held to an eighth of the P·O·B f32 that dlogits stored per
+    block would take (1.02 GB at O = 100, 3.64 GB at O = 355), plus the
+    O = 2 allowance."""
+    pop = paper_lp.layer_pop(0)
+    c = _loss_head_fwd_bwd(pop, one_chip, o)
+    assert _n_kernels(c) == 2
+    dlogits = pop.num_members * o * BATCH * 4
+    assert (c.memory_analysis().temp_size_in_bytes
+            <= dlogits // 8 + HEAD_TEMP_BYTES)
 
 
 @pytest.mark.parametrize("log_probs", [False, True])
