@@ -204,8 +204,7 @@ def input_xla(x: jax.Array, w_in: jax.Array, b_in: jax.Array,
 
 def input_fused(x: jax.Array, w_in: jax.Array, b_in: jax.Array,
                 lp: LayeredPopulation, act_impl: str = "sliced", *,
-                interpret: bool | None = None,
-                block_b: int = 128) -> jax.Array:
+                interpret: bool | None = None) -> jax.Array:
     """FUSED input layer: dense GEMM + bias + per-segment activation +
     padding mask in one Pallas pass (kernels/fused_input.py, DESIGN.md §9)
     — no standalone seg_act pass, z0 never in HBM.  ``act_impl`` is
@@ -213,8 +212,7 @@ def input_fused(x: jax.Array, w_in: jax.Array, b_in: jax.Array,
     from repro.kernels.ops import fused_input  # lazy: kernels import pallas
     p0 = lp.layer_pop(0)
     return fused_input(x, w_in, b_in.astype(jnp.float32), p0.block_act_ids,
-                       p0.hidden_mask, block=lp.block, block_b=block_b,
-                       interpret=interpret)
+                       p0.hidden_mask, block=lp.block, interpret=interpret)
 
 
 def input_fused_infer(x: jax.Array, w_in: jax.Array, b_in: jax.Array,

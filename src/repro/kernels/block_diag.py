@@ -41,6 +41,24 @@ from jax.experimental.pallas import tpu as pltpu
 # the default nor for more than the chip has.
 VMEM_BYTES = 128 * 1024 * 1024
 _VMEM_DEFAULT_LIMIT = 16 * 1024 * 1024
+# bytes of its largest operand one grid step of a hidden-tiled kernel
+# (loss head, fused input layer) aims to move: enough to hide the fixed
+# cost of a step behind the HBM transfer, little enough to double-buffer
+TILE_BYTES = 2 * 1024 * 1024
+
+
+def tile_blocks(n_blocks: int, block_bytes: int, *, cap: int | None = None,
+                multiple: int = 1) -> int:
+    """Hidden blocks a grid step takes when each block brings
+    ``block_bytes`` of its largest operand: about ``TILE_BYTES``, at most
+    ``cap``, rounded down to ``multiple`` (and at least that) unless one
+    step takes every block."""
+    g = max(1, TILE_BYTES // block_bytes)
+    if cap is not None:
+        g = min(g, cap)
+    if g >= n_blocks:
+        return n_blocks
+    return max(multiple, g // multiple * multiple)
 
 
 def tpu_compiler_params(dimension_semantics, *block_shapes, dtype_bytes=4):

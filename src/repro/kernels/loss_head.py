@@ -77,11 +77,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.block_diag import tpu_compiler_params
+from repro.kernels.block_diag import tile_blocks, tpu_compiler_params
 
-# h bytes one grid step aims to read: enough to hide the fixed cost of a
-# step behind the HBM transfer, little enough to double-buffer h and dh
-TILE_BYTES = 2 * 1024 * 1024
 # members in one (8, 128) slab of the few-class member table
 _SLAB = 8 * 128
 # classes up to which the forward stores the dlogits for the backward
@@ -101,12 +98,11 @@ def stores_dlogits(o: int) -> bool:
 
 def blocks_per_tile(n_blocks: int, batch: int, block_h: int,
                     itemsize: int) -> int:
-    """Hidden blocks per grid step: ~TILE_BYTES of h, a multiple of 8 (the
-    sublane tile of the dlogits block) unless one tile takes every block."""
-    g = min(max(1, TILE_BYTES // (batch * block_h * itemsize)), _SLAB)
-    if g >= n_blocks:
-        return n_blocks
-    return max(8, g // 8 * 8)
+    """Hidden blocks per grid step: ``block_diag.TILE_BYTES`` of h, at most
+    one member-table slab, a multiple of 8 (the sublane tile of the
+    dlogits block) unless one tile takes every block."""
+    return tile_blocks(n_blocks, batch * block_h * itemsize, cap=_SLAB,
+                       multiple=8)
 
 
 def _tiles(seg: jax.Array, g: int):

@@ -391,48 +391,54 @@ def fused_layer_infer_int8(h: jax.Array, wb_q: jax.Array,
 # fused input layer: dense GEMM + bias + activation epilogue            #
 # --------------------------------------------------------------------- #
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _fin_core(x, w, b, act_ids, mask, block, block_b, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _fin_core(x, w, b, act_ids, mask, block, interpret):
     """Primal (no-grad contexts, e.g. eval): single-output kernel.  The
     per-block activation ids and the hidden mask are OPERANDS (not static
     arguments), so a member-sharded caller can hand each shard its own
-    slice (core.deep's shard_map path)."""
+    slice (core.deep's shard_map path).  Every grid step takes the whole
+    batch."""
     return _fik.fused_input_fwd(
         x, w, jnp.reshape(b, (1, -1)).astype(jnp.float32), mask, act_ids,
-        block=block, block_b=block_b, with_deriv=False, interpret=interpret)
+        block=block, block_b=x.shape[0], with_deriv=False,
+        interpret=interpret)
 
 
-def _fin_fwd(x, w, b, act_ids, mask, block, block_b, interpret):
+def _fin_fwd(x, w, b, act_ids, mask, block, interpret):
+    # symbolic zeros: each operand arrives with whether it is perturbed.
+    # W_in is kept only for dx, which only a perturbed x needs: a training
+    # step differentiates the parameters, and layer 0's input is the batch
     y, gp = _fik.fused_input_fwd(
-        x, w, jnp.reshape(b, (1, -1)).astype(jnp.float32), mask, act_ids,
-        block=block, block_b=block_b, with_deriv=True, interpret=interpret)
-    return y, (x, w, gp)
+        x.value, w.value, jnp.reshape(b.value, (1, -1)).astype(jnp.float32),
+        mask.value, act_ids.value, block=block, block_b=x.value.shape[0],
+        with_deriv=True, interpret=interpret)
+    return y, (x.value, w.value if x.perturbed else None, gp)
 
 
-def _fin_bwd(block, block_b, interpret, res, dy):
+def _fin_bwd(block, interpret, res, dy):
     x, w, gp = res
-    dx, dw = _fik.fused_input_bwd(dy, gp, x, w, block=block,
-                                  block_b=block_b, interpret=interpret)
-    # bias cotangent: one fused XLA reduce over tiles that exist anyway
-    db = (dy.astype(jnp.float32) * gp.astype(jnp.float32)).sum(axis=0)
-    return dx, dw, db.astype(jnp.float32), None, None
+    dw, db, dx = _fik.fused_input_bwd(dy, gp, x, w, block=block,
+                                      interpret=interpret)
+    return dx, dw, db, None, None
 
 
-_fin_core.defvjp(_fin_fwd, _fin_bwd)
+_fin_core.defvjp(_fin_fwd, _fin_bwd, symbolic_zeros=True)
 
 
 def fused_input(x: jax.Array, w_in: jax.Array, b_in: jax.Array,
-                block_act_ids, mask, *, block: int, block_b: int = 128,
+                block_act_ids, mask, *, block: int,
                 interpret: bool | None = None) -> jax.Array:
     """Dense input projection + bias + per-segment activation + padding
     mask in one Pallas pass (kernels/fused_input.py; DESIGN.md §9);
-    differentiable (fused one-pass custom VJP); pads B and F.
+    differentiable (fused one-pass custom VJP: dW_in, db_in, and dx only
+    where x is perturbed); pads B to 8 and F to the feature tiling.
 
     x (B, F), w_in (H, F) the stacked first-layer weight, ``b_in`` (H,),
     ``block_act_ids`` the first hidden layer's per-block activation ids,
     ``mask`` its hidden mask → (B, H) of ``act(x·W_in^T + b_in)·mask``;
     the two tables may be numpy constants or traced arrays (one member
-    shard's slice under ``shard_map``).
+    shard's slice under ``shard_map``).  Every grid step takes the whole
+    batch and ``fused_input.tile_plan``'s hidden blocks.
     H must already be block-aligned (Population guarantees this).
     ``interpret=None`` auto-selects: compiled on TPU, interpreted on CPU.
     """
@@ -445,16 +451,13 @@ def fused_input(x: jax.Array, w_in: jax.Array, b_in: jax.Array,
         raise ValueError(f"feature axis {x.shape[1]} != {w_in.shape[1]}")
     if b_in.shape != (h,):
         raise ValueError(f"bias shape {b_in.shape} != ({h},)")
-    block_b = min(block_b, max(8, 1 << (x.shape[0] - 1).bit_length()))
-    xp, b0 = _pad_axis(x, 0, block_b)
-    # feature padding: whole-F lane register when small, 128-lane reduction
-    # tiles when large (pick_block_f)
-    fmult = 8 if x.shape[1] <= 128 else 128
-    xp, _ = _pad_axis(xp, 1, fmult)
-    wp, _ = _pad_axis(w_in, 1, fmult)
+    xp, b0 = _pad_axis(x, 0, 8)
+    f_pad = _fik.padded_features(x.shape[1])
+    xp, _ = _pad_axis(xp, 1, f_pad)
+    wp, _ = _pad_axis(w_in, 1, f_pad)
     y = _fin_core(xp, wp, b_in, jnp.asarray(block_act_ids, jnp.int32),
                   jnp.asarray(mask, jnp.float32).reshape(1, -1), block,
-                  block_b, interpret)
+                  interpret)
     return y[:b0]
 
 
@@ -476,9 +479,9 @@ def fused_input_infer(x: jax.Array, w_in: jax.Array, b_in: jax.Array,
         raise ValueError(f"bias shape {b_in.shape} != ({h},)")
     block_b = min(block_b, max(8, 1 << (x.shape[0] - 1).bit_length()))
     xp, b0 = _pad_axis(x, 0, block_b)
-    fmult = 8 if x.shape[1] <= 128 else 128
-    xp, _ = _pad_axis(xp, 1, fmult)
-    wp, _ = _pad_axis(w_in, 1, fmult)
+    f_pad = _fik.padded_features(x.shape[1])
+    xp, _ = _pad_axis(xp, 1, f_pad)
+    wp, _ = _pad_axis(w_in, 1, f_pad)
     y = _fik.fused_input_fwd(
         xp, wp, jnp.reshape(b_in, (1, -1)).astype(jnp.float32),
         jnp.asarray(np.asarray(mask, np.float32)).reshape(1, -1),
@@ -503,8 +506,7 @@ def fused_input_infer_int8(x: jax.Array, w_q: jax.Array, w_scale: jax.Array,
         raise ValueError(f"hidden axis {h} not {block}-aligned")
     if w_q.dtype != jnp.int8:
         raise ValueError(f"int8 serve path got {w_q.dtype} input weight")
-    fmult = 8 if x.shape[1] <= 128 else 128
-    f_pad = x.shape[1] + ((-x.shape[1]) % fmult)
+    f_pad = _fik.padded_features(x.shape[1])
     if w_q.shape[1] != f_pad:
         raise ValueError(
             f"int8 input weight has F={w_q.shape[1]}, expected the "
@@ -515,7 +517,7 @@ def fused_input_infer_int8(x: jax.Array, w_q: jax.Array, w_scale: jax.Array,
         raise ValueError(f"bias shape {b_in.shape} != ({h},)")
     block_b = min(block_b, max(8, 1 << (x.shape[0] - 1).bit_length()))
     xp, b0 = _pad_axis(x, 0, block_b)
-    xp, _ = _pad_axis(xp, 1, fmult)
+    xp, _ = _pad_axis(xp, 1, f_pad)
     y = _fik.fused_input_int8_fwd(
         xp, w_q, w_scale.astype(jnp.float32).reshape(-1),
         jnp.reshape(b_in, (1, -1)).astype(jnp.float32),

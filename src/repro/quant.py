@@ -56,15 +56,6 @@ def dequantize(q: jax.Array, scale) -> jax.Array:
 # serve-copy packer                                                     #
 # --------------------------------------------------------------------- #
 
-def _input_f_pad(f: int) -> int:
-    """The feature padding the fused input kernel uses (ops.py: whole-F
-    lane register when small, 128-lane reduction tiles when large) — the
-    packed ``w_in`` is stored pre-padded so the serve forward never pads
-    weight bytes per call."""
-    fmult = 8 if f <= 128 else 128
-    return f + ((-f) % fmult)
-
-
 def quantize_population(params, lp):
     """The int8 serve copy of a population's parameters.
 
@@ -87,6 +78,7 @@ def quantize_population(params, lp):
     ride the existing layout metadata — the packer only changes the bytes
     each tile stores, never which tile a step loads."""
     from repro.core.deep import pack_weight_tiles  # lazy: deep imports pallas
+    from repro.kernels.fused_input import padded_features
     blk = lp.block
     f32 = jnp.float32
 
@@ -94,7 +86,9 @@ def quantize_population(params, lp):
     h0, f = w_in.shape
     s_in = symmetric_scale(w_in.reshape(h0 // blk, blk * f), axis=1)
     q_in = quantize(w_in, jnp.repeat(s_in, blk)[:, None])
-    f_pad = _input_f_pad(f)
+    # stored pre-padded to the fused input kernel's feature tiling, so the
+    # serve forward never pads weight bytes per call
+    f_pad = padded_features(f)
     if f_pad != f:                       # zero columns are exact under int8
         q_in = jnp.pad(q_in, ((0, 0), (0, f_pad - f)))
 

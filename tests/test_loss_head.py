@@ -13,7 +13,7 @@ from repro.core.activations import ACTIVATION_ORDER
 from repro.core.deep import init_params
 from repro.core.m3 import (FUSED_LOSS_IMPLS, LOSS_IMPLS, m3, m3_loss_head)
 from repro.core.population import LayeredPopulation
-from repro.kernels import loss_head
+from repro.kernels import block_diag, loss_head
 
 _WIDTHS = ((5, 3), (12, 9), (7,), (17, 9, 5), (8, 8),
            (5, 3), (3, 11, 2), (24, 16), (4,), (9, 9, 9))
@@ -165,7 +165,7 @@ _TILED_CASES = ([(o, b) for o in (2, 3) for b in (9, 32, 256)]
 def _tile(monkeypatch, b, g):
     """Steer the head to ``g`` blocks of 8 lanes per grid step at batch b
     (padded to 8 rows) through the bytes a step aims to read."""
-    monkeypatch.setattr(loss_head, "TILE_BYTES", -(-b // 8) * 8 * 8 * 4 * g)
+    monkeypatch.setattr(block_diag, "TILE_BYTES", -(-b // 8) * 8 * 8 * 4 * g)
 
 
 @pytest.mark.parametrize("o,b", _TILED_CASES)
@@ -285,13 +285,13 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.core import deep
 from repro.core.population import LayeredPopulation
 from repro.distributed.sharding import population_shardings
-from repro.kernels import loss_head
+from repro.kernels import block_diag
 from repro.launch.mesh import make_mesh
 
 B = 16
 # 8 blocks of 8 lanes per grid step at B = 16, so each shard's head runs
 # several tiles with members crossing their boundaries
-loss_head.TILE_BYTES = B * 8 * 4 * 8
+block_diag.TILE_BYTES = B * 8 * 4 * 8
 # four equal quarters, so each shard owns whole members' blocks
 widths = tuple((w,) for w in (3, 12, 20, 7, 17, 24, 5, 9, 14, 2, 23, 11) * 4)
 acts = tuple(("relu", "tanh", "gelu")[i % 3] for i in range(len(widths)))

@@ -15,6 +15,7 @@ backend would otherwise pick the interpreter.
 """
 import os
 import pathlib
+import re
 import sys
 
 import jax
@@ -26,7 +27,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import deep
 from repro.core.activations import PAPER_TEN
 from repro.core.population import LayeredPopulation
-from repro.kernels import ops
+from repro.kernels import fused_input, ops
 
 HBM_BYTES = 16 * 1000 ** 3     # one TPU v5e chip: 16 GB of HBM
 BATCH = 256
@@ -88,21 +89,81 @@ def _n_kernels(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
 
 
-def test_fused_input_fwd_bwd_paper_width(one_chip, paper_lp):
-    """The input layer at H = 1,280,000, forward with its g' residual and
-    the one-pass backward (whose on-chip scratch no longer grows with H)."""
-    p0 = paper_lp.layer_pop(0)
+def _input_layer(one_chip, p0, batch, features, with_x):
+    """The fused input layer, forward with its g' residual and the one-pass
+    backward, compiled at ``p0``'s width: the gradient over W_in and b_in
+    (a training step's) or over x too."""
     h = p0.total_hidden
-    assert h == 1_280_000
 
     def fwd_bwd(x, w, b, dy):
-        y, vjp = jax.vjp(lambda x, w, b: ops.fused_input(
-            x, w, b, p0.block_act_ids, p0.hidden_mask, block=128), x, w, b)
+        def f(*a):
+            xx, ww, bb = a if with_x else (x, *a)
+            return ops.fused_input(xx, ww, bb, p0.block_act_ids,
+                                   p0.hidden_mask, block=128)
+        y, vjp = jax.vjp(f, *((x, w, b) if with_x else (w, b)))
         return y, vjp(dy)
 
-    c = _compile(fwd_bwd, one_chip, _sds((BATCH, 100)), _sds((h, 100)),
-                 _sds((h,)), _sds((BATCH, h)))
+    return _compile(fwd_bwd, one_chip, _sds((batch, features)),
+                    _sds((h, features)), _sds((h,)), _sds((batch, h)))
+
+
+def _bias_reduces(compiled, h) -> int:
+    """XLA reduces to an (H,) row: the bias gradient's Σ_b dy·g', before
+    the backward kernel made it."""
+    return len(re.findall(rf"= f32\[{h}\]\S* reduce\(", compiled.as_text()))
+
+
+def _bwd_outputs(compiled) -> int:
+    """Arrays the compiled backward kernel returns: dW_in, db_in [, dx]."""
+    line = next(ln for ln in compiled.as_text().splitlines()
+                if "fused_input_bwd" in ln and "custom-call(" in ln)
+    return line.split(" custom-call(")[0].count("f32[")
+
+
+def test_fused_input_fwd_bwd_paper_width(one_chip, paper_lp):
+    """The input layer at H = 1,280,000, forward with its g' residual and
+    the one-pass backward (whose on-chip memory does not grow with H): two
+    kernels, the bias gradient made inside the backward, so no XLA reduce
+    over (B, H) is left, and dx made because x is differentiated."""
+    p0 = paper_lp.layer_pop(0)
+    assert p0.total_hidden == 1_280_000
+    c = _input_layer(one_chip, p0, BATCH, 100, with_x=True)
     assert _n_kernels(c) == 2
+    assert _bias_reduces(c, p0.total_hidden) == 0
+    assert _bwd_outputs(c) == 3
+
+
+@pytest.mark.parametrize("batch", [256, 32])
+def test_fused_input_deep_layer0(one_chip, batch):
+    """deep-1k's layer 0 (1,024 members of 512/256/384/128 first-layer
+    units, H = 327,680) at both of its batches, differentiated as a
+    training step differentiates it: two kernels, no (B, H) reduce, and a
+    backward that returns dW_in and db_in only."""
+    widths = ((512, 256), (256, 128, 64), (384,), (128,))
+    members = [(widths[i % 4], PAPER_TEN[(i // 4) % 10])
+               for i in range(1024)]
+    lp = LayeredPopulation(100, 2, tuple(w for w, _ in members),
+                           tuple((a,) * len(w) for w, a in members),
+                           block=128).sorted()
+    p0 = lp.layer_pop(0)
+    assert p0.total_hidden == 327_680
+    c = _input_layer(one_chip, p0, batch, 100, with_x=False)
+    assert _n_kernels(c) == 2
+    assert _bias_reduces(c, p0.total_hidden) == 0
+    assert _bwd_outputs(c) == 2
+
+
+@pytest.mark.parametrize("features", [128, 784])
+def test_fused_input_small_batch_wide_features(one_chip, paper_lp, features):
+    """Batch 8 at H = 1,280,000 with x differentiated: a block brings more
+    bytes of w_in and dW_in (block_f = 128 columns) than of the (8, H)
+    activations, so the tile is sized by the weight's columns and the
+    backward's blocks, dx's among them, fit the chip's VMEM."""
+    p0 = paper_lp.layer_pop(0)
+    assert fused_input.tile_plan(8, p0.total_hidden, features, 128).g == 32
+    c = _input_layer(one_chip, p0, 8, features, with_x=True)
+    assert _n_kernels(c) == 2
+    assert _bwd_outputs(c) == 3
 
 
 @pytest.mark.parametrize("block_b", [128, 256])
