@@ -36,8 +36,7 @@ def config() -> ArchSpec:
         optimizer="sgd", lr=1e-2,
         skip_shapes=("prefill_32k", "decode_32k", "long_500k"),
         skip_reason="tabular MLP population: LM shapes are not defined; "
-                    "the paper's own shape grid lives in "
-                    "benchmarks/bench_paper_tables.py",
+                    "the chip benchmark's cells live in chipbench/",
         source="[the reproduced paper, §4.2]",
         notes="10,000 members, total fused hidden = 1,280,000 (128-aligned); "
               "population axis = 'model'.")

@@ -4,14 +4,13 @@ heterogeneous-depth) populations, feature selection, per-member learning
 rates."""
 from repro.core.activations import ACTIVATIONS, ACTIVATION_ORDER, PAPER_TEN
 from repro.core.lifecycle import HalvingSchedule, compact, survivors
-from repro.core.m3 import M3_IMPLS, m3, m3_bucketed, m3_onehot, m3_pallas, m3_scatter
+from repro.core.m3 import m3
 from repro.core.parallel_mlp import (extract_member, forward, fused_loss, init_params,
                                      member_forward, member_losses, sgd_step)
 from repro.core.population import LayeredPopulation, Population
 
 __all__ = [
-    "ACTIVATIONS", "ACTIVATION_ORDER", "PAPER_TEN", "M3_IMPLS", "m3",
-    "m3_scatter", "m3_onehot", "m3_bucketed", "m3_pallas", "Population",
+    "ACTIVATIONS", "ACTIVATION_ORDER", "PAPER_TEN", "m3", "Population",
     "LayeredPopulation", "HalvingSchedule", "compact", "survivors",
     "init_params", "forward", "fused_loss", "member_losses", "sgd_step",
     "extract_member", "member_forward",

@@ -1,13 +1,15 @@
-"""The paper's ten activation functions and three strategies for applying a
+"""The paper's ten activation functions and the ways of applying a
 *different* activation to different column slices of a fused hidden tensor.
 
 Strategies (cross-validated against each other in tests):
   * ``apply_activations_sliced``  — static contiguous slices, one pass per run
-    (efficient when the population is sorted by activation; what XLA fuses best).
+    (efficient when the population is sorted by activation; what XLA fuses
+    best) — the ``bd_impl="einsum"`` path.
   * ``apply_activations_masked``  — branchless select over all 10 functions
     (the paper's masking strawman; used as oracle).
-  * kernels/seg_act.py            — Pallas tile-wise ``lax.switch`` on a
-    scalar-prefetched activation id (one read per tile; TPU-native).
+  * the fused kernels' epilogues (kernels/epilogue.py) — a tile-wise
+    ``lax.switch`` on a scalar-prefetched activation id, applied while the
+    projection's accumulator is in VMEM — the ``bd_impl="fused"`` path.
 """
 from __future__ import annotations
 

@@ -9,14 +9,18 @@ on top of the layered layout (``repro.core.population.LayeredPopulation``):
   * layer 0:            ordinary fused matmul  (H1_tot × F)       — as paper
   * layers 1..L-1:      BLOCK-DIAGONAL segment matmul: member m's units in
                         layer l+1 contract ONLY member m's units in layer l.
-                        Two registered implementations (``BD_IMPLS``):
-                          einsum — per-bucket batched einsum
-                                   (B, n, h_in) × (n, h_out, h_in) → (B, n, h_out)
-                          pallas — ONE dense segment-blocked matmul
-                                   (kernels/block_diag.py, custom VJP), the
-                                   moe_gemm weight-tile-selection trick with
-                                   member-id = "expert"-id (DESIGN.md §3)
   * output layer:       the paper's M3 (repro.core.m3).
+
+The engine has ONE implementation decision, ``bd_impl`` (DESIGN.md §3):
+
+  einsum — XLA: the input layer is a dot, each mid layer a per-bucket
+           batched einsum (B, n, h_in) × (n, h_out, h_in) → (B, n, h_out),
+           each activation the sliced pass plus the padding mask, the head
+           ``m3`` and the loss ``log_softmax``;
+  fused  — Pallas: ``fused_input``, ``fused_layer`` and ``loss_head``, each
+           projection with its bias, activation and mask (or softmax
+           cross-entropy) in its epilogue; ``infer=True`` swaps in their
+           forward-only twins, ``weights_dtype="int8"`` their int8 ones.
 
 Members may have DIFFERENT depths: a shallow member's final activations ride
 through later layers as exact identity pass-throughs (no weight, no bias, no
@@ -39,6 +43,7 @@ import numpy as np
 
 from repro.core.activations import ACTIVATIONS, apply_activations_sliced
 from repro.core.m3 import m3 as _m3_apply
+from repro.core.m3 import m3_infer_head, m3_infer_head_int8, m3_loss_head
 from repro.core.population import LayeredPopulation, Population
 
 # The unified engine: DeepPopulation (uniform depth, one activation per
@@ -47,7 +52,23 @@ DeepPopulation = LayeredPopulation
 
 
 # ---------------------------------------------------------------------- #
-# block-diagonal mid-layer projection (registry, like m3.M3_IMPLS)       #
+# the implementation decision                                            #
+# ---------------------------------------------------------------------- #
+
+BD_CHOICES = ("einsum", "fused")
+
+
+def _is_fused(bd_impl: str) -> bool:
+    """``bd_impl`` → whether the run takes the fused Pallas kernels
+    (``"fused"``) or the XLA ops (``"einsum"``); nothing else exists."""
+    if bd_impl not in BD_CHOICES:
+        raise ValueError(f"bd_impl must be one of {BD_CHOICES}, "
+                         f"got {bd_impl!r}")
+    return bd_impl == "fused"
+
+
+# ---------------------------------------------------------------------- #
+# block-diagonal mid-layer projection                                    #
 # ---------------------------------------------------------------------- #
 
 def block_diag_einsum(h: jax.Array, w_buckets, lp: LayeredPopulation,
@@ -89,15 +110,6 @@ def pack_weight_tiles(w_buckets, lp: LayeredPopulation, l: int) -> jax.Array:
                      .transpose(0, 1, 3, 2, 4)
                      .reshape(n * ob * ib, blk, blk))
     return jnp.concatenate(tiles, axis=0)
-
-
-def block_diag_pallas(h: jax.Array, w_buckets, lp: LayeredPopulation, l: int,
-                      *, interpret: bool | None = None,
-                      block_b: int = 128) -> jax.Array:
-    from repro.kernels.ops import block_diag_gemm  # lazy: kernels import pallas
-    wb = pack_weight_tiles(w_buckets, lp, l)
-    return block_diag_gemm(h, wb.astype(h.dtype), lp.bd_layout(l),
-                           block_b=block_b, interpret=interpret)
 
 
 def block_diag_fused(h: jax.Array, w_buckets, lp: LayeredPopulation, l: int,
@@ -158,57 +170,26 @@ def block_diag_fused_infer_int8(h: jax.Array, qlayer: dict,
         interpret=interpret)
 
 
-BD_IMPLS = {
-    "einsum": block_diag_einsum,
-    "pallas": block_diag_pallas,
-    "fused": block_diag_fused,
-}
-
-# the ``infer=True`` registry: XLA impls are already residual-free, the
-# fused impl swaps in its forward-only twin.  The ``fused_int8`` entry is
-# the ``weights_dtype="int8"`` route — NOT selectable via ``bd_impl``
-# (its signature consumes the quantized layer dict, not bucket arrays).
-BD_INFER_IMPLS = {
-    "einsum": block_diag_einsum,
-    "pallas": block_diag_pallas,
-    "fused": block_diag_fused_infer,
-    "fused_int8": block_diag_fused_infer_int8,
-}
-
-# impls whose kernel epilogue already applies bias + activation + mask —
-# ``forward`` must hand them the bias and skip its own ``_act``
-FUSED_BD_IMPLS = frozenset(["fused"])
-
-
-def block_diag_matmul(h: jax.Array, w_buckets, lp: LayeredPopulation, l: int,
-                      impl: str = "einsum", **kw) -> jax.Array:
-    """Member-block-diagonal projection of layer l → l+1.  ``impl="fused"``
-    additionally needs ``bias=`` and returns the ACTIVATED layer (epilogue
-    fusion), not the raw projection."""
-    return BD_IMPLS[impl](h, w_buckets, lp, l, **kw)
-
-
 # ---------------------------------------------------------------------- #
-# input-layer projection (registry, like BD_IMPLS)                       #
+# input-layer projection                                                 #
 # ---------------------------------------------------------------------- #
 
 def input_xla(x: jax.Array, w_in: jax.Array, b_in: jax.Array,
-              lp: LayeredPopulation, act_impl: str = "sliced") -> jax.Array:
+              lp: LayeredPopulation) -> jax.Array:
     """Input projection as an XLA dot (f32 accumulate) + bias + the
-    per-layer ``_act`` pass — the pre-§9 path."""
+    per-layer ``_act`` pass — the einsum path's input layer."""
     z0 = jax.lax.dot_general(x, w_in,
                              dimension_numbers=(((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    return _act(lp, 0, z0 + b_in, act_impl)
+    return _act(lp, 0, z0 + b_in)
 
 
 def input_fused(x: jax.Array, w_in: jax.Array, b_in: jax.Array,
-                lp: LayeredPopulation, act_impl: str = "sliced", *,
+                lp: LayeredPopulation, *,
                 interpret: bool | None = None) -> jax.Array:
     """FUSED input layer: dense GEMM + bias + per-segment activation +
     padding mask in one Pallas pass (kernels/fused_input.py, DESIGN.md §9)
-    — no standalone seg_act pass, z0 never in HBM.  ``act_impl`` is
-    ignored: the epilogue IS the activation."""
+    — no standalone activation pass, z0 never in HBM."""
     from repro.kernels.ops import fused_input  # lazy: kernels import pallas
     p0 = lp.layer_pop(0)
     return fused_input(x, w_in, b_in.astype(jnp.float32), p0.block_act_ids,
@@ -216,7 +197,7 @@ def input_fused(x: jax.Array, w_in: jax.Array, b_in: jax.Array,
 
 
 def input_fused_infer(x: jax.Array, w_in: jax.Array, b_in: jax.Array,
-                      lp: LayeredPopulation, act_impl: str = "sliced", *,
+                      lp: LayeredPopulation, *,
                       interpret: bool | None = None,
                       block_b: int | None = None) -> jax.Array:
     """Forward-only ``input_fused`` through ``ops.fused_input_infer`` — no
@@ -230,8 +211,7 @@ def input_fused_infer(x: jax.Array, w_in: jax.Array, b_in: jax.Array,
 
 
 def input_fused_infer_int8(x: jax.Array, w_q: jax.Array, w_scale: jax.Array,
-                           b_in: jax.Array, lp: LayeredPopulation,
-                           act_impl: str = "sliced", *,
+                           b_in: jax.Array, lp: LayeredPopulation, *,
                            interpret: bool | None = None,
                            block_b: int | None = None) -> jax.Array:
     """``input_fused_infer`` over the int8 serve copy: the pre-padded int8
@@ -244,34 +224,6 @@ def input_fused_infer_int8(x: jax.Array, w_q: jax.Array, w_scale: jax.Array,
         p0.hidden_mask, block=lp.block,
         block_b=INFER_BLOCK_B if block_b is None else block_b,
         interpret=interpret)
-
-
-IN_IMPLS = {
-    "xla": input_xla,
-    "fused": input_fused,
-}
-
-# ``infer=True`` twins of IN_IMPLS (same rule as BD_INFER_IMPLS);
-# ``fused_int8`` is the ``weights_dtype="int8"`` route, not an ``in_impl``
-IN_INFER_IMPLS = {
-    "xla": input_xla,
-    "fused": input_fused_infer,
-    "fused_int8": input_fused_infer_int8,
-}
-
-# input impls whose kernel epilogue already applies bias + activation + mask
-FUSED_IN_IMPLS = frozenset(["fused"])
-
-
-def _resolve_in_impl(in_impl, bd_impl: str) -> str:
-    """``None`` follows the mid layers: a fused ``bd_impl`` gets the fused
-    input kernel, anything else the XLA dot."""
-    if in_impl is None:
-        return "fused" if bd_impl in FUSED_BD_IMPLS else "xla"
-    if in_impl not in IN_IMPLS:
-        raise ValueError(f"unknown in_impl {in_impl!r} "
-                         f"(have {sorted(IN_IMPLS)})")
-    return in_impl
 
 
 # ---------------------------------------------------------------------- #
@@ -467,24 +419,11 @@ def grow_state(opt_state, lp: LayeredPopulation,
 # forward / loss / step                                                  #
 # ---------------------------------------------------------------------- #
 
-def _act(lp: LayeredPopulation, l: int, h: jax.Array,
-         act_impl: str = "sliced") -> jax.Array:
-    """Per-layer activation + padding mask: ``sliced`` (one XLA pass per
-    contiguous activation run), ``masked`` (branchless select oracle), or
-    ``pallas`` (kernels/seg_act: one tile-wise lax.switch pass, activation
-    id scalar-prefetched, mask fused — the ROADMAP follow-up)."""
+def _act(lp: LayeredPopulation, l: int, h: jax.Array) -> jax.Array:
+    """Per-layer activation + padding mask: one XLA pass per contiguous
+    activation run (``apply_activations_sliced``), then the mask."""
     pop = lp.layer_pop(l)
-    if act_impl == "sliced":
-        h = apply_activations_sliced(h, pop.act_runs)
-    elif act_impl == "masked":
-        from repro.core.activations import apply_activations_masked
-        h = apply_activations_masked(h, pop.act_ids)
-    elif act_impl == "pallas":
-        from repro.kernels.ops import seg_act  # lazy: kernels import pallas
-        return seg_act(h, pop.block_act_ids, pop.hidden_mask,
-                       block_h=lp.block)
-    else:
-        raise ValueError(f"unknown act_impl {act_impl!r}")
+    h = apply_activations_sliced(h, pop.act_runs)
     return h * jnp.asarray(pop.hidden_mask, h.dtype)
 
 
@@ -516,71 +455,55 @@ def _resolve_weights_dtype(weights_dtype):
                      "(DESIGN.md §12)")
 
 
-def _hidden(params, x, lp: LayeredPopulation, bd_impl: str = "einsum",
-            act_impl: str = "sliced", bd_kwargs: dict | None = None,
-            compute_dtype=None, in_impl=None, infer: bool = False,
-            weights_dtype=None):
+def _hidden(params, x, lp: LayeredPopulation, *, bd_impl: str = "einsum",
+            compute_dtype=None, infer: bool = False, weights_dtype=None):
     """Input layer + every mid layer → the last hidden activations
-    (B, H_last_tot).  The shared trunk of ``forward`` and the fused loss
-    head; ``in_impl`` routing as in ``forward``.  ``infer=True`` swaps the
-    fused impls for their forward-only twins (``*_INFER_IMPLS``): no
+    (B, H_last_tot).  The shared trunk of ``forward`` and ``fused_loss``.
+    ``infer=True`` swaps the fused kernels for their forward-only twins: no
     custom_vjp attached, no residual emitted, bigger batch tiles.
     ``weights_dtype="int8"`` (serving only) routes through the
     fused-dequant twins over a ``quantize_population`` tree."""
+    fused = _is_fused(bd_impl)
     cd = _resolve_compute_dtype(compute_dtype)
     cast = (lambda a: a) if cd is None else (lambda a: a.astype(cd))
-    wd = _resolve_weights_dtype(weights_dtype)
-    if bd_impl.endswith("_int8"):
-        raise ValueError(f"bd_impl {bd_impl!r} is the weights_dtype='int8' "
-                         "route — request it via weights_dtype, not bd_impl")
-    if wd is not None:
+    if _resolve_weights_dtype(weights_dtype) is not None:
         if not infer:
             raise ValueError(
                 "weights_dtype='int8' is a serving-only path — the "
                 "quantized copy is not differentiable; pass infer=True")
-        in_impl = _resolve_in_impl(in_impl, bd_impl)
-        if bd_impl not in FUSED_BD_IMPLS or in_impl not in FUSED_IN_IMPLS:
+        if not fused:
             raise ValueError(
                 "weights_dtype='int8' needs the fused serving kernels "
-                f"(bd_impl='fused'), got bd_impl={bd_impl!r}, "
-                f"in_impl={in_impl!r}")
-        h = IN_INFER_IMPLS[in_impl + "_int8"](
+                f"(bd_impl='fused'), got bd_impl={bd_impl!r}")
+        h = input_fused_infer_int8(
             cast(x), params["w_in"], params["w_in_scale"], params["b_in"],
-            lp, act_impl)
+            lp)
         for l in range(lp.depth - 1):
-            h = BD_INFER_IMPLS[bd_impl + "_int8"](
-                cast(h), params["mid"][l], lp, l, **(bd_kwargs or {}))
+            h = block_diag_fused_infer_int8(cast(h), params["mid"][l], lp, l)
         return h
-    in_impl = _resolve_in_impl(in_impl, bd_impl)
-    bd_impls = BD_INFER_IMPLS if infer else BD_IMPLS
-    in_impls = IN_INFER_IMPLS if infer else IN_IMPLS
-    if bd_impl not in bd_impls:
-        raise ValueError(f"unknown bd_impl {bd_impl!r} "
-                         f"(have {sorted(bd_impls)})")
-    h = in_impls[in_impl](cast(x), cast(params["w_in"]), params["b_in"],
-                          lp, act_impl)
+    if not fused:
+        h = input_xla(cast(x), cast(params["w_in"]), params["b_in"], lp)
+        for l in range(lp.depth - 1):
+            z = block_diag_einsum(
+                cast(h), [cast(w) for w in params["mid"][l]["w"]], lp, l)
+            h = z + params["mid"][l]["b"] * jnp.asarray(
+                lp.active_unit_mask(l + 1), jnp.float32)
+            h = _act(lp, l + 1, h)
+        return h
+    # bias + activation + mask live in the kernel epilogues; each output
+    # is the next layer's (operand-dtype) activations
+    layer_in = input_fused_infer if infer else input_fused
+    layer_mid = block_diag_fused_infer if infer else block_diag_fused
+    h = layer_in(cast(x), cast(params["w_in"]), params["b_in"], lp)
     for l in range(lp.depth - 1):
-        hb = cast(h)
-        wl = [cast(w) for w in params["mid"][l]["w"]]
-        if bd_impl in FUSED_BD_IMPLS:
-            # bias + activation + mask live in the kernel epilogue; the
-            # output is layer l+1's (operand-dtype) activations
-            h = bd_impls[bd_impl](hb, wl, lp, l,
-                                  bias=params["mid"][l]["b"],
-                                  **(bd_kwargs or {}))
-            continue
-        z = bd_impls[bd_impl](hb, wl, lp, l, **(bd_kwargs or {}))
-        h = z + params["mid"][l]["b"] * jnp.asarray(
-            lp.active_unit_mask(l + 1), jnp.float32)
-        h = _act(lp, l + 1, h, act_impl)
+        h = layer_mid(cast(h), [cast(w) for w in params["mid"][l]["w"]],
+                      lp, l, bias=params["mid"][l]["b"])
     return h
 
 
-def forward(params, x, lp: LayeredPopulation, m3_impl: str = "bucketed",
-            bd_impl: str = "einsum", act_impl: str = "sliced",
-            bd_kwargs: dict | None = None, m3_kwargs: dict | None = None,
-            compute_dtype=None, in_impl=None, infer: bool = False,
-            head_impl=None, log_probs: bool = False, weights_dtype=None):
+def forward(params, x, lp: LayeredPopulation, *, bd_impl: str = "einsum",
+            compute_dtype=None, infer: bool = False,
+            log_probs: bool = False, weights_dtype=None):
     """x (B, F) → logits (B, P, O) — every member an independent deep MLP.
 
     ``compute_dtype="bfloat16"`` applies the mixed-precision policy: matmul
@@ -589,18 +512,16 @@ def forward(params, x, lp: LayeredPopulation, m3_impl: str = "bucketed",
     VMEM scratch in the kernels), biases and the logits stay f32, and the
     f32 master parameters are untouched — gradients arrive f32.
 
-    ``bd_impl="fused"`` routes every mid layer through the fused Pallas
-    kernel (projection + bias + activation + mask in one pass, DESIGN.md
-    §7).  ``in_impl`` picks the input-layer path (``IN_IMPLS``); the
-    default ``None`` follows ``bd_impl`` — a fused run gets the fused
-    input kernel (DESIGN.md §9) so no standalone seg_act pass survives
-    anywhere in the forward.
+    ``bd_impl`` is the one implementation decision (``BD_CHOICES``):
+    ``"einsum"`` runs every layer as XLA ops and the head as ``m3``;
+    ``"fused"`` runs the input and mid layers through the fused Pallas
+    kernels (projection + bias + activation + mask in one pass, DESIGN.md
+    §7, §9).
 
     ``infer=True`` is the serving hot path (DESIGN.md §10): every fused
-    impl is swapped for its forward-only twin (no custom_vjp, no residual
-    emission, INFER_BLOCK_B batch tiles) and the output projection runs
-    through ``head_impl`` (``HEAD_IMPLS``; default ``None`` follows
-    ``bd_impl``) — ``"fused"`` is the one-launch infer-head kernel with the
+    kernel is swapped for its forward-only twin (no custom_vjp, no
+    residual emission, INFER_BLOCK_B batch tiles) and, on the fused path,
+    the output projection is the one-launch infer-head kernel with the
     per-member bias (and, under ``log_probs=True``, the log-softmax) in its
     epilogue, making the whole forward exactly depth+1 launches
     (``launch_count.fused_infer_budget``).  Numerics match the training
@@ -610,76 +531,45 @@ def forward(params, x, lp: LayeredPopulation, m3_impl: str = "bucketed",
     must be a ``quant.quantize_population`` tree; every projection runs
     its fused-dequant int8 twin — int8 weight tiles + f32 scales are the
     ONLY weight bytes the program touches, at the same depth+1 launch
-    budget.  Requires ``infer=True`` and the fused impls."""
+    budget.  Requires ``infer=True`` and ``bd_impl="fused"``."""
     cd = _resolve_compute_dtype(compute_dtype)
     cast = (lambda a: a) if cd is None else (lambda a: a.astype(cd))
-    wd = _resolve_weights_dtype(weights_dtype)
-    h = _hidden(params, x, lp, bd_impl, act_impl, bd_kwargs, compute_dtype,
-                in_impl, infer, weights_dtype)
-    if infer:
-        from repro.core.m3 import (HEAD_IMPLS, m3_infer_head,
-                                   m3_infer_head_int8)
-        if head_impl is None:
-            head_impl = (("fused_int8" if wd is not None else "fused")
-                         if bd_impl in FUSED_BD_IMPLS else "xla")
-        if head_impl not in HEAD_IMPLS:
-            raise ValueError(f"unknown head_impl {head_impl!r} "
-                             f"(have {sorted(HEAD_IMPLS)})")
-        if wd is not None and head_impl != "fused_int8":
-            raise ValueError(
-                f"weights_dtype='int8' serves through head_impl="
-                f"'fused_int8' (the int8 head store has no f32 twin), "
-                f"got {head_impl!r}")
-        if head_impl == "fused_int8":
-            if wd is None:
-                raise ValueError("head_impl='fused_int8' needs "
-                                 "weights_dtype='int8'")
+    h = _hidden(params, x, lp, bd_impl=bd_impl, compute_dtype=compute_dtype,
+                infer=infer, weights_dtype=weights_dtype)
+    plast = lp.layer_pop(lp.depth - 1)
+    if infer and _is_fused(bd_impl):
+        # bias (and optional log-softmax) live in the kernel epilogue
+        if _resolve_weights_dtype(weights_dtype) is not None:
             return m3_infer_head_int8(
                 cast(h), params["w_out"], params["w_out_scale"],
-                params["b_out"], lp.layer_pop(lp.depth - 1),
-                log_probs=log_probs, **(m3_kwargs or {}))
-        if head_impl == "fused":
-            # bias (and optional log-softmax) live in the kernel epilogue
-            return m3_infer_head(cast(h), cast(params["w_out"]),
-                                 params["b_out"],
-                                 lp.layer_pop(lp.depth - 1),
-                                 log_probs=log_probs, **(m3_kwargs or {}))
-    y = _m3_apply(cast(h), cast(params["w_out"]),
-                  lp.layer_pop(lp.depth - 1), impl=m3_impl,
-                  **(m3_kwargs or {}))
+                params["b_out"], plast, log_probs=log_probs)
+        return m3_infer_head(cast(h), cast(params["w_out"]), params["b_out"],
+                             plast, log_probs=log_probs)
+    y = _m3_apply(cast(h), cast(params["w_out"]), plast)
     y = y.astype(jnp.float32) + params["b_out"][None]
     if log_probs:
         y = jax.nn.log_softmax(y, axis=-1)
     return y
 
 
-def fused_loss(params, x, targets, lp: LayeredPopulation,
-               m3_impl: str = "bucketed", bd_impl: str = "einsum",
-               act_impl: str = "sliced", compute_dtype=None,
-               in_impl=None, loss_impl=None):
+def fused_loss(params, x, targets, lp: LayeredPopulation, *,
+               bd_impl: str = "einsum", compute_dtype=None):
     """Summed per-member softmax cross-entropy → ``(loss, per)`` with
     ``per`` (P,) the per-member mean NLL.
 
-    ``loss_impl`` picks the head: ``"xla"`` materialises logits via
-    ``forward`` and runs log_softmax in XLA; ``"fused"`` skips ``m3``
-    entirely and runs projection + softmax-XE + dlogits in one Pallas
-    launch per direction (``core.m3.m3_loss_head``, DESIGN.md §9).  The
-    default ``None`` follows ``bd_impl``, so a fused run's whole
+    ``bd_impl="einsum"`` materialises logits via ``forward`` and runs
+    log_softmax in XLA; ``"fused"`` skips ``m3`` entirely and runs
+    projection + softmax-XE + dlogits in one Pallas launch per direction
+    (``core.m3.m3_loss_head``, DESIGN.md §9), so a fused run's whole
     forward+backward is a fixed number of launches per layer at any batch
     size."""
-    from repro.core.m3 import LOSS_IMPLS, m3_loss_head
-    if loss_impl is None:
-        loss_impl = "fused" if bd_impl in FUSED_BD_IMPLS else "xla"
-    if loss_impl not in LOSS_IMPLS:
-        raise ValueError(f"unknown loss_impl {loss_impl!r} "
-                         f"(have {sorted(LOSS_IMPLS)})")
-    if loss_impl == "fused":
+    if _is_fused(bd_impl):
         cd = _resolve_compute_dtype(compute_dtype)
         cast = (lambda a: a) if cd is None else (lambda a: a.astype(cd))
         from repro.distributed.sharding import pop_axis_size
         n = pop_axis_size()
         if n > 1:
-            why = _member_sharded_unsupported(lp, bd_impl, in_impl, n)
+            why = _member_sharded_unsupported(lp, n)
             if why is None:
                 return _fused_loss_member_sharded(params, x, targets, lp,
                                                   cast, n)
@@ -687,14 +577,13 @@ def fused_loss(params, x, targets, lp: LayeredPopulation,
             if not _resolve_interpret(None):
                 raise NotImplementedError(why)
             # interpret-mode kernels are plain HLO that XLA partitions
-        h = _hidden(params, x, lp, bd_impl, act_impl, None, compute_dtype,
-                    in_impl)
+        h = _hidden(params, x, lp, bd_impl=bd_impl,
+                    compute_dtype=compute_dtype)
         per = m3_loss_head(cast(h), cast(params["w_out"]), params["b_out"],
                            targets, lp.layer_pop(lp.depth - 1))
         return per.sum(), per
-    logits = forward(params, x, lp, m3_impl=m3_impl, bd_impl=bd_impl,
-                     act_impl=act_impl, compute_dtype=compute_dtype,
-                     in_impl=in_impl)
+    logits = forward(params, x, lp, bd_impl=bd_impl,
+                     compute_dtype=compute_dtype)
     logp = jax.nn.log_softmax(logits, axis=-1)
     nll = -jnp.take_along_axis(
         logp, targets[:, None, None].astype(jnp.int32), axis=-1)[..., 0]
@@ -702,12 +591,11 @@ def fused_loss(params, x, targets, lp: LayeredPopulation,
     return per.sum(), per
 
 
-def _member_sharded_unsupported(lp: LayeredPopulation, bd_impl: str,
-                                in_impl, n: int):
+def _member_sharded_unsupported(lp: LayeredPopulation, n: int):
     """Why ``_fused_loss_member_sharded`` cannot run this layout with its
     members split ``n`` ways on the ambient mesh, or None when it can."""
     from repro.distributed.sharding import mesh_axis_sizes
-    if lp.depth != 1 or _resolve_in_impl(in_impl, bd_impl) != "fused":
+    if lp.depth != 1:
         return ("member-sharded fused kernels cover depth-1 populations; "
                 "train deeper layouts on a mesh with --bd-impl einsum")
     if mesh_axis_sizes().get("data", 1) > 1:
@@ -783,9 +671,8 @@ def member_lr_tree(lp: LayeredPopulation, lr) -> dict:
     return tree
 
 
-def _sgd_update(params, x, targets, lr, lp: LayeredPopulation,
-                m3_impl: str = "bucketed", bd_impl: str = "einsum",
-                act_impl: str = "sliced", compute_dtype=None):
+def _sgd_update(params, x, targets, lr, lp: LayeredPopulation, *,
+                bd_impl: str = "einsum", compute_dtype=None):
     """The un-jitted SGD step body (shared by ``sgd_step`` and the scanned
     ``make_population_train_step``).  ``lr`` may be a scalar or a
     per-member (P,) vector.  Under ``compute_dtype="bfloat16"`` the forward
@@ -793,7 +680,7 @@ def _sgd_update(params, x, targets, lr, lp: LayeredPopulation,
     gradients (and the update) stay f32 — mixed precision never touches the
     optimizer math."""
     (loss, per), grads = jax.value_and_grad(fused_loss, has_aux=True)(
-        params, x, targets, lp, m3_impl, bd_impl, act_impl, compute_dtype)
+        params, x, targets, lp, bd_impl=bd_impl, compute_dtype=compute_dtype)
     lr = jnp.asarray(lr)
     if lr.ndim == 0:
         new = jax.tree.map(lambda p, g: p - lr * g, params, grads)
@@ -803,20 +690,17 @@ def _sgd_update(params, x, targets, lr, lp: LayeredPopulation,
     return new, loss, per
 
 
-@partial(jax.jit, static_argnames=("lp", "m3_impl", "bd_impl", "act_impl",
-                                   "compute_dtype"))
-def sgd_step(params, x, targets, lr, lp: LayeredPopulation,
-             m3_impl: str = "bucketed", bd_impl: str = "einsum",
-             act_impl: str = "sliced", compute_dtype=None):
+@partial(jax.jit, static_argnames=("lp", "bd_impl", "compute_dtype"))
+def sgd_step(params, x, targets, lr, lp: LayeredPopulation, *,
+             bd_impl: str = "einsum", compute_dtype=None):
     """One fused SGD step.  ``lr`` may be a scalar or a per-member (P,)
     vector."""
-    return _sgd_update(params, x, targets, lr, lp, m3_impl, bd_impl,
-                       act_impl, compute_dtype)
+    return _sgd_update(params, x, targets, lr, lp, bd_impl=bd_impl,
+                       compute_dtype=compute_dtype)
 
 
 def _opt_update(params, opt_state, x, targets, lr, opt,
-                lp: LayeredPopulation, m3_impl: str = "bucketed",
-                bd_impl: str = "einsum", act_impl: str = "sliced",
+                lp: LayeredPopulation, *, bd_impl: str = "einsum",
                 compute_dtype=None, grad_clip=None):
     """The optimizer-generic step body (``_sgd_update``'s successor):
     fused loss + grads → optional global-norm clip → ``opt.update`` →
@@ -830,10 +714,10 @@ def _opt_update(params, opt_state, x, targets, lr, opt,
     negate/multiply/subtract make the two exactly equal — regression-tested
     in tests/test_population_optim.py, which is what lets the driver run
     every optimizer through ONE engine without perturbing the plain-SGD
-    baselines (BENCH_*.json, halving invariants)."""
+    trajectories (halving invariants)."""
     from repro.optim.optimizers import apply_updates, clip_by_global_norm
     (loss, per), grads = jax.value_and_grad(fused_loss, has_aux=True)(
-        params, x, targets, lp, m3_impl, bd_impl, act_impl, compute_dtype)
+        params, x, targets, lp, bd_impl=bd_impl, compute_dtype=compute_dtype)
     gnorm = None
     if grad_clip:
         grads, gnorm = clip_by_global_norm(grads, grad_clip)
@@ -845,24 +729,22 @@ def _opt_update(params, opt_state, x, targets, lr, opt,
     return apply_updates(params, upd), opt_state, loss, per, gnorm
 
 
-@partial(jax.jit, static_argnames=("opt", "lp", "m3_impl", "bd_impl",
-                                   "act_impl", "compute_dtype", "grad_clip"))
+@partial(jax.jit, static_argnames=("opt", "lp", "bd_impl", "compute_dtype",
+                                   "grad_clip"))
 def opt_step(params, opt_state, x, targets, lr, opt, lp: LayeredPopulation,
-             m3_impl: str = "bucketed", bd_impl: str = "einsum",
-             act_impl: str = "sliced", compute_dtype=None, grad_clip=None):
+             *, bd_impl: str = "einsum", compute_dtype=None, grad_clip=None):
     """One fused optimizer step with state (``sgd_step``'s successor) →
     ``(params, opt_state, loss, per_member_losses, grad_norm)``;
     ``grad_norm`` is None unless ``grad_clip`` is set."""
-    return _opt_update(params, opt_state, x, targets, lr, opt, lp, m3_impl,
-                       bd_impl, act_impl, compute_dtype, grad_clip)
+    return _opt_update(params, opt_state, x, targets, lr, opt, lp,
+                       bd_impl=bd_impl, compute_dtype=compute_dtype,
+                       grad_clip=grad_clip)
 
 
 def make_population_train_step(lp: LayeredPopulation, *,
                                optimizer=None,
                                grad_clip=None,
-                               m3_impl: str = "bucketed",
                                bd_impl: str = "einsum",
-                               act_impl: str = "sliced",
                                scan_steps: int = 1,
                                donate: bool = True,
                                donate_batch: bool = False,
@@ -895,7 +777,7 @@ def make_population_train_step(lp: LayeredPopulation, *,
     multiplier (they are excluded from selection regardless).  With
     ``lr_schedule=None`` the signatures and the emitted program are
     EXACTLY the pre-schedule ones: the plain-SGD chunk stays bit-identical
-    to the committed baselines.
+    to the unscheduled one.
 
     ``donate_batch`` additionally donates the ``xs``/``ys`` slabs (only
     meaningful with ``donate``): the streaming data plane
@@ -914,6 +796,7 @@ def make_population_train_step(lp: LayeredPopulation, *,
     optimizer moments included (``LayeredPopulation.opt_specs``)."""
     if scan_steps < 1:
         raise ValueError(f"scan_steps must be >= 1, got {scan_steps}")
+    _is_fused(bd_impl)  # refuse an unknown implementation before tracing
 
     if optimizer is None:
         if grad_clip:
@@ -925,9 +808,9 @@ def make_population_train_step(lp: LayeredPopulation, *,
             def chunk(params, xs, ys, lr):
                 def body(p, batch):
                     x, y = batch
-                    p, loss, per = _sgd_update(p, x, y, lr, lp, m3_impl,
-                                               bd_impl, act_impl,
-                                               compute_dtype)
+                    p, loss, per = _sgd_update(
+                        p, x, y, lr, lp, bd_impl=bd_impl,
+                        compute_dtype=compute_dtype)
                     return p, (loss, per)
                 params, (losses, pers) = jax.lax.scan(body, params, (xs, ys))
                 return params, losses, pers
@@ -937,9 +820,9 @@ def make_population_train_step(lp: LayeredPopulation, *,
                     p, g = carry
                     x, y = batch
                     lr_t = jnp.asarray(lr) * lr_schedule(g)
-                    p, loss, per = _sgd_update(p, x, y, lr_t, lp, m3_impl,
-                                               bd_impl, act_impl,
-                                               compute_dtype)
+                    p, loss, per = _sgd_update(
+                        p, x, y, lr_t, lp, bd_impl=bd_impl,
+                        compute_dtype=compute_dtype)
                     return (p, g + 1), (loss, per)
                 (params, _), (losses, pers) = jax.lax.scan(
                     body, (params, jnp.asarray(step0, jnp.int32)), (xs, ys))
@@ -954,8 +837,8 @@ def make_population_train_step(lp: LayeredPopulation, *,
                 p, st = carry
                 x, y = batch
                 p, st, loss, per, gnorm = _opt_update(
-                    p, st, x, y, lr, optimizer, lp, m3_impl, bd_impl,
-                    act_impl, compute_dtype, grad_clip)
+                    p, st, x, y, lr, optimizer, lp, bd_impl=bd_impl,
+                    compute_dtype=compute_dtype, grad_clip=grad_clip)
                 return (p, st), (loss, per, gnorm)
             (params, opt_state), (losses, pers, gnorms) = jax.lax.scan(
                 body, (params, opt_state), (xs, ys))
@@ -971,8 +854,8 @@ def make_population_train_step(lp: LayeredPopulation, *,
                 else:
                     lr_t = jnp.asarray(lr) * mult
                 p, st, loss, per, gnorm = _opt_update(
-                    p, st, x, y, lr_t, optimizer, lp, m3_impl, bd_impl,
-                    act_impl, compute_dtype, grad_clip)
+                    p, st, x, y, lr_t, optimizer, lp, bd_impl=bd_impl,
+                    compute_dtype=compute_dtype, grad_clip=grad_clip)
                 return (p, st, g + 1), (loss, per, gnorm)
             (params, opt_state, _), (losses, pers, gnorms) = jax.lax.scan(
                 body, (params, opt_state, jnp.asarray(step0, jnp.int32)),

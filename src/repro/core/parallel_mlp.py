@@ -47,9 +47,11 @@ def init_params(key: jax.Array, pop: Population, dtype=jnp.float32) -> dict:
 
 
 def forward(params: dict, x: jax.Array, pop: Population, *,
-            m3_impl: str = "bucketed", act_impl: str = "sliced",
-            m3_kwargs: dict | None = None) -> jax.Array:
-    """x (B, in) → logits (B, P, out).  The paper's steps 1–4."""
+            act_impl: str = "sliced") -> jax.Array:
+    """x (B, in) → logits (B, P, out).  The paper's steps 1–4.
+    ``act_impl`` picks the activation pass: ``sliced`` (one pass per
+    contiguous activation run) or ``masked`` (branchless select over all
+    ten, for layouts not sorted by activation)."""
     h = x @ params["w1"].T + params["b1"]                     # 1. fused matmul
     if act_impl == "sliced":
         h = apply_activations_sliced(h, pop.act_runs)          # 2. per-member act
@@ -58,8 +60,7 @@ def forward(params: dict, x: jax.Array, pop: Population, *,
     else:
         raise ValueError(f"unknown act_impl {act_impl!r}")
     h = h * jnp.asarray(pop.hidden_mask, h.dtype)              # kill padding units
-    y = _m3_apply(h, params["w2"], pop, impl=m3_impl,
-                  **(m3_kwargs or {}))                         # 3+4. M3
+    y = _m3_apply(h, params["w2"], pop)                        # 3+4. M3
     return y + params["b2"][None, :, :]
 
 
@@ -95,10 +96,9 @@ def member_accuracy(logits: jax.Array, targets: jax.Array) -> jax.Array:
 # examples/quickstart.py — this compact step keeps the core standalone.   #
 # ---------------------------------------------------------------------- #
 
-@partial(jax.jit, static_argnames=("pop", "task", "m3_impl", "act_impl"))
+@partial(jax.jit, static_argnames=("pop", "task", "act_impl"))
 def sgd_step(params, x, targets, lr, pop: Population,
-             task: Task = "classification",
-             m3_impl: str = "bucketed", act_impl: str = "sliced"):
+             task: Task = "classification", *, act_impl: str = "sliced"):
     """One fused SGD step over the whole population.
 
     ``lr`` may be a scalar (paper) or a per-member vector (P,) — the paper's
@@ -106,7 +106,7 @@ def sgd_step(params, x, targets, lr, pop: Population,
     every parameter belongs to exactly one member.
     """
     (loss, per), grads = jax.value_and_grad(fused_loss, has_aux=True)(
-        params, x, targets, pop, task, m3_impl=m3_impl, act_impl=act_impl)
+        params, x, targets, pop, task, act_impl=act_impl)
     lr = jnp.asarray(lr)
     if lr.ndim == 0:
         scale = {"w1": lr, "b1": lr, "w2": lr, "b2": lr}

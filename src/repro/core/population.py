@@ -213,7 +213,8 @@ class Population:
 
     @cached_property
     def block_act_ids(self) -> np.ndarray:
-        """Activation id per hidden block (scalar prefetch for seg_act)."""
+        """Activation id per hidden block (scalar prefetch for the fused
+        kernels' activation epilogue)."""
         assert self.total_hidden % self.block == 0
         return self.act_ids[:: self.block].copy()
 
@@ -307,7 +308,7 @@ class Population:
 class BlockDiagLayout:
     """Static scalar-prefetch metadata for one block-diagonal l→l+1
     projection run as a single Pallas segment-blocked matmul
-    (kernels/block_diag.py; DESIGN.md §3/§7).
+    (kernels/fused_layer.py; DESIGN.md §3/§7).
 
     The fused weight is a flat array of (block × block) tiles, member-major,
     row-major over each member's (out_tile, in_tile) grid, with ONE shared

@@ -36,31 +36,13 @@ def extract_member(params, layout, m: int) -> dict:
     return _pmlp.extract_member(params, layout, m)
 
 
-_DICT_TAG = "__dict__"
-
-
-def _freeze_kwargs(fw: dict) -> tuple:
-    """Forward kwargs → hashable jit-static key (dict values — bd_kwargs /
-    m3_kwargs — become tagged item tuples)."""
-    return tuple(sorted(
-        (k, (_DICT_TAG, tuple(sorted(v.items())))
-         if isinstance(v, dict) else v)
-        for k, v in fw.items()))
-
-
-def _thaw_kwargs(fw: tuple) -> dict:
-    return {k: dict(v[1])
-            if isinstance(v, tuple) and v and v[0] == _DICT_TAG else v
-            for k, v in fw}
-
-
 @partial(jax.jit, static_argnames=("pop", "task", "fw"))
 def _eval_batch(params, xb, tb, pop, task, fw):
     """One jitted eval batch under the training sharding (cached across
     ``evaluate_population`` calls on the jit cache — layouts are static
     hashable dataclasses, exactly like ``deep.sgd_step``)."""
     from repro.distributed.sharding import POP_LOGITS, POP_MEMBER, constrain
-    logits = constrain(_forward(params, xb, pop, **_thaw_kwargs(fw)),
+    logits = constrain(_forward(params, xb, pop, **dict(fw)),
                        POP_LOGITS)
     loss = constrain(member_losses(logits, tb, task), POP_MEMBER)
     acc = (constrain(member_accuracy(logits, tb), POP_MEMBER)
@@ -86,7 +68,7 @@ def evaluate_population(params, pop, x, targets,
     published set without ever touching the training kernels.
 
     Returns (losses (P,), accuracies (P,) or None)."""
-    fw_key = _freeze_kwargs(fw)
+    fw_key = tuple(sorted(fw.items()))      # hashable jit-static key
     n = x.shape[0]
     loss_sum = jnp.zeros(pop.num_members)
     acc_sum = jnp.zeros(pop.num_members)

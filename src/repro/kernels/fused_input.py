@@ -32,7 +32,7 @@ member whose pre-activation sits at a discontinuous activation's
 threshold (hardshrink's ±0.5) falls on the same side whatever the tiling.
 
 Tiling.  Each grid step takes the WHOLE (padded) batch and ``G``
-consecutive hidden blocks — about ``block_diag.TILE_BYTES`` (2 MiB) of
+consecutive hidden blocks — about ``tiling.TILE_BYTES`` (2 MiB) of
 the largest operand a block brings, B_pad rows of the (B, H) arrays or
 ``block_f`` columns of w_in and dW_in; 16 blocks of 128 lanes at B = 256
 (``tile_plan``) — so the fixed cost of a grid step is spread over enough
@@ -62,7 +62,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.block_diag import tile_blocks, tpu_compiler_params
+from repro.kernels.tiling import tile_blocks, tpu_compiler_params
 from repro.kernels.epilogue import VAL_BRANCHES, VAL_DERIV_BRANCHES
 
 
@@ -91,7 +91,7 @@ def tile_plan(b: int, h: int, f: int, block: int, *, itemsize: int = 4,
     batch padded to 8 rows, ``G`` blocks of ``block`` lanes a step, and dx
     only for a perturbed x.  A block brings B_pad rows of each (B, H)
     operand and ``block_f`` columns of each (H, F_pad) one (w_in, dW_in);
-    ``G`` holds the larger of the two at about ``block_diag.TILE_BYTES``.
+    ``G`` holds the larger of the two at about ``tiling.TILE_BYTES``.
     At paper-40k's per-chip shape, ``tile_plan(256, 1_280_000, 100, 128)``
     is 16 blocks and 625 steps a pass, with no dx."""
     nb = h // block

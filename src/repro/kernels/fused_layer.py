@@ -1,12 +1,13 @@
 """Fused population-layer kernel: block-diagonal GEMM + per-member bias +
 per-segment activation in ONE Pallas pass (DESIGN.md §7).
 
-The unfused ``bd_impl=pallas`` path runs every mid layer as three HBM round
-trips — block-diag GEMM writes the pre-activations z, an XLA pass adds the
-bias, seg_act reads z+b back and writes act(z+b)·mask — and the backward
-mirrors them (seg_act_bwd materialises dz, then the transposed GEMM and dw
-kernels read it).  Here the epilogue runs while the accumulator tile is
-still in VMEM:
+Member m's units in layer l+1 contract ONLY member m's units in layer l,
+so the fused l→l+1 weight is block-diagonal, stored as a flat array of
+(block × block) tiles.  Run unfused, every mid layer would be three HBM
+round trips — the GEMM writes the pre-activations z, a pass adds the bias,
+another reads z+b back and writes act(z+b)·mask — and the backward would
+mirror them.  Here the epilogue runs while the accumulator tile is still
+in VMEM:
 
   forward   y  = act(z + b) · mask            (one kernel, z never in HBM)
             g' = act'(z + b) · mask           (the activation derivative,
@@ -23,12 +24,11 @@ still in VMEM:
             db = Σ_b dy·g' is one XLA fused reduce over arrays that exist
             anyway.
 
-Grid/tile metadata is the ragged flattened step layout shared with
-``kernels/block_diag.py`` (``BlockDiagLayout``); the per-step activation id
-(the OUTPUT tile's segment activation) is scalar-prefetched and dispatched
+Grid/tile metadata is the ragged flattened step layout of
+``core.population.BlockDiagLayout``; the per-step activation id (the
+OUTPUT tile's segment activation) is scalar-prefetched and dispatched
 through ``lax.switch`` over the kernel forms of the ten paper activations
-(kernels/epilogue.py), exactly like kernels/seg_act.py — but only on the
-flush step of each output tile.
+(kernels/epilogue.py), only on the flush step of each output tile.
 
 Mixed precision: operand tiles may be bf16 (``--compute-dtype bfloat16``);
 the accumulator and the bias add are always f32 (``preferred_element_type``
@@ -41,7 +41,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.block_diag import tpu_compiler_params
+from repro.kernels.tiling import tpu_compiler_params
 from repro.kernels.epilogue import VAL_BRANCHES, VAL_DERIV_BRANCHES
 
 

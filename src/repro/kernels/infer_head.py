@@ -42,7 +42,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.block_diag import tpu_compiler_params
+from repro.kernels.tiling import tpu_compiler_params
 
 
 def _make_kernel(log_probs: bool):

@@ -77,7 +77,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.block_diag import tile_blocks, tpu_compiler_params
+from repro.kernels.tiling import tile_blocks, tpu_compiler_params
 
 # members in one (8, 128) slab of the few-class member table
 _SLAB = 8 * 128
@@ -98,7 +98,7 @@ def stores_dlogits(o: int) -> bool:
 
 def blocks_per_tile(n_blocks: int, batch: int, block_h: int,
                     itemsize: int) -> int:
-    """Hidden blocks per grid step: ``block_diag.TILE_BYTES`` of h, at most
+    """Hidden blocks per grid step: ``tiling.TILE_BYTES`` of h, at most
     one member-table slab, a multiple of 8 (the sublane tile of the
     dlogits block) unless one tile takes every block."""
     return tile_blocks(n_blocks, batch * block_h * itemsize, cap=_SLAB,

@@ -1,9 +1,9 @@
 """Grouped GEMM — the M3 segment trick applied along the *row* axis.
 
 MoE expert computation: tokens sorted by expert id form contiguous row
-segments; each segment multiplies its own expert weight.  Identical structure
-to m3_matmul with the roles of rows/columns swapped: the scalar-prefetched
-per-tile expert id selects the *weight* block instead of the output block.
+segments; each segment multiplies its own expert weight.  Where M3 segments
+the reduction (hidden) axis by member, this segments the rows: the
+scalar-prefetched per-tile expert id selects the *weight* block.
 
     y[t] = x[t] @ w[expert(t)]        x (T, D), w (E, D, F) -> y (T, F)
 

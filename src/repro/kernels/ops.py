@@ -1,8 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 Handle: batch/feature padding to block multiples, dtype policy, the
-custom_vjp that routes the M3 backward through the transposed kernels, and
-the ``interpret`` switch: ``None`` (the default everywhere) compiles the
+custom VJPs that route each backward through its fused kernel, and the
+``interpret`` switch: ``None`` (the default everywhere) compiles the
 kernels with Mosaic on a TPU backend and runs the kernel body through the
 Pallas interpreter on the CPU backend — where the tests validate them.  Any
 other backend raises: no accelerator silently falls back to the
@@ -18,15 +18,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import block_diag as _bdk
 from repro.kernels import flash_attn as _flashk
 from repro.kernels import fused_input as _fik
 from repro.kernels import fused_layer as _flk
 from repro.kernels import infer_head as _ihk
 from repro.kernels import loss_head as _lhk
-from repro.kernels import m3_matmul as _m3k
 from repro.kernels import moe_gemm as _moek
-from repro.kernels import seg_act as _segk
 
 
 def _resolve_interpret(interpret) -> bool:
@@ -70,57 +67,7 @@ def _pad_axis(x: jax.Array, axis: int, mult: int):
 
 
 # --------------------------------------------------------------------- #
-# m3_matmul with custom_vjp                                             #
-# --------------------------------------------------------------------- #
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6))
-def _m3_core(h, w2, block_seg_ids_t, num_members, block_h, block_b, interpret):
-    seg = jnp.asarray(np.asarray(block_seg_ids_t, np.int32))
-    return _m3k.m3_matmul_fwd(h, w2, seg, num_members,
-                              block_h=block_h, block_b=block_b,
-                              interpret=interpret)
-
-
-def _m3_fwd(h, w2, block_seg_ids_t, num_members, block_h, block_b, interpret):
-    y = _m3_core(h, w2, block_seg_ids_t, num_members, block_h, block_b, interpret)
-    return y, (h, w2)
-
-
-def _m3_bwd(block_seg_ids_t, num_members, block_h, block_b, interpret, res, dy):
-    h, w2 = res
-    seg = jnp.asarray(np.asarray(block_seg_ids_t, np.int32))
-    dh = _m3k.m3_matmul_dh(dy, w2, seg, block_h=block_h, block_b=block_b,
-                           interpret=interpret)
-    dw = _m3k.m3_matmul_dw(dy, h, seg, block_h=block_h, block_b=block_b,
-                           interpret=interpret)
-    return dh, dw
-
-
-_m3_core.defvjp(_m3_fwd, _m3_bwd)
-
-
-def m3_matmul(h: jax.Array, w2: jax.Array, block_seg_ids: np.ndarray,
-              num_members: int, *, block_h: int, block_b: int = 128,
-              interpret: bool | None = None) -> jax.Array:
-    """Segment-blocked matmul; differentiable; pads B and O to block multiples.
-
-    h (B, H), w2 (O, H), per-block member ids (H/block_h,) -> (B, M, O).
-    H must already be block_h-aligned (Population guarantees this).
-    ``interpret=None`` auto-selects: compiled on TPU, interpreted on CPU.
-    """
-    interpret = _resolve_interpret(interpret)
-    _check_block(block_h, interpret)
-    if h.shape[1] % block_h:
-        raise ValueError(f"hidden axis {h.shape[1]} not {block_h}-aligned")
-    block_b = min(block_b, max(8, 1 << (h.shape[0] - 1).bit_length()))
-    hp, b0 = _pad_axis(h, 0, block_b)
-    seg_t = tuple(int(s) for s in np.asarray(block_seg_ids, np.int32))
-    y = _m3_core(hp, w2, seg_t, num_members, block_h, block_b, interpret)
-    return y[:b0]
-
-
-# --------------------------------------------------------------------- #
-# block-diagonal GEMM with custom_vjp (layered-population mid layers)   #
+# fused layer: block-diag GEMM + bias + activation epilogue             #
 # --------------------------------------------------------------------- #
 
 def _bd_ids(layout, transposed: bool):
@@ -143,76 +90,35 @@ def _bd_augment(wb: jax.Array, layout) -> jax.Array:
 
 def _bd_transposed_tiles(wb, layout):
     """Per-member-transposed augmented tile array (static permutation +
-    per-tile transpose) — the dh weight of both custom VJPs."""
+    per-tile transpose) — the dh weight of the fused layer's custom VJP."""
     import numpy as _np
     return jnp.transpose(
         _bd_augment(wb, layout)[_np.asarray(layout.perm_t, _np.int32)],
         (0, 2, 1))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
-def _bd_core(h, wb, layout, block_b, interpret):
-    ids = _bd_ids(layout, transposed=False)
-    return _bdk.block_diag_fwd(
-        h, _bd_augment(wb, layout), *ids,
-        n_out_tiles=layout.n_out_tiles, n_steps=layout.n_steps,
-        block=layout.block, block_b=block_b, interpret=interpret)
+class _StaticArray:
+    """Hashable wrapper making a numpy constant usable as a jit /
+    custom_vjp STATIC argument without materialising a per-element Python
+    tuple (the fused hidden mask is 10^5-10^6 floats at paper scale —
+    hashing the raw bytes once beats building and caching a tuple)."""
+    __slots__ = ("arr", "_hash")
 
+    def __init__(self, arr, dtype):
+        self.arr = np.ascontiguousarray(np.asarray(arr, dtype))
+        self.arr.setflags(write=False)
+        self._hash = hash((self.arr.shape, self.arr.dtype.str,
+                           self.arr.tobytes()))
 
-def _bd_fwd(h, wb, layout, block_b, interpret):
-    return _bd_core(h, wb, layout, block_b, interpret), (h, wb)
+    def __hash__(self):
+        return self._hash
 
+    def __eq__(self, other):
+        return (isinstance(other, _StaticArray)
+                and self.arr.dtype == other.arr.dtype
+                and self.arr.shape == other.arr.shape
+                and np.array_equal(self.arr, other.arr))
 
-def _bd_bwd(layout, block_b, interpret, res, dy):
-    import numpy as _np
-    h, wb = res
-    # dh: the transposed block-diagonal — same kernel, transposed tiles and
-    # swapped (ragged-step) metadata.
-    ids_t = _bd_ids(layout, transposed=True)
-    dh = _bdk.block_diag_fwd(
-        dy, _bd_transposed_tiles(wb, layout), *ids_t,
-        n_out_tiles=layout.n_in_tiles, n_steps=layout.n_steps_t,
-        block=layout.block, block_b=block_b, interpret=interpret)
-    dwb = _bdk.block_diag_dw(
-        dy, h,
-        jnp.asarray(_np.asarray(layout.wb_out_tile, _np.int32)),
-        jnp.asarray(_np.asarray(layout.wb_in_tile, _np.int32)),
-        n_param_blocks=layout.n_param_blocks, block=layout.block,
-        block_b=block_b, interpret=interpret)
-    return dh, dwb
-
-
-_bd_core.defvjp(_bd_fwd, _bd_bwd)
-
-
-def block_diag_gemm(h: jax.Array, wb: jax.Array, layout, *,
-                    block_b: int = 128,
-                    interpret: bool | None = None) -> jax.Array:
-    """Block-diagonal member projection; differentiable; pads B.
-
-    h (B, n_in_tiles·blk), wb (n_param_blocks, blk, blk) tile array,
-    ``layout`` a static ``repro.core.population.BlockDiagLayout`` →
-    (B, n_out_tiles·blk).  Pass-through members are identity-copied via the
-    shared appended identity tile and contribute no weight gradient.
-    ``interpret=None`` auto-selects: compiled on TPU, interpreted on CPU.
-    """
-    interpret = _resolve_interpret(interpret)
-    _check_block(layout.block, interpret)
-    if h.shape[1] != layout.n_in_tiles * layout.block:
-        raise ValueError(f"input axis {h.shape[1]} != "
-                         f"{layout.n_in_tiles}×{layout.block}")
-    if wb.shape != (layout.n_param_blocks, layout.block, layout.block):
-        raise ValueError(f"weight tiles {wb.shape} != "
-                         f"({layout.n_param_blocks}, {layout.block}, {layout.block})")
-    block_b = min(block_b, max(8, 1 << (h.shape[0] - 1).bit_length()))
-    hp, b0 = _pad_axis(h, 0, block_b)
-    y = _bd_core(hp, wb, layout, block_b, interpret)
-    return y[:b0]
-
-
-# --------------------------------------------------------------------- #
-# fused layer: block-diag GEMM + bias + activation epilogue             #
-# --------------------------------------------------------------------- #
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _fused_core(h, wb, b_eff, layout, acts_s, mask_s, block_b, interpret):
@@ -524,75 +430,6 @@ def fused_input_infer_int8(x: jax.Array, w_q: jax.Array, w_scale: jax.Array,
         jnp.asarray(np.asarray(mask, np.float32)).reshape(1, -1),
         jnp.asarray(np.asarray(block_act_ids, np.int32)),
         block=block, block_b=block_b, interpret=interpret)
-    return y[:b0]
-
-
-# --------------------------------------------------------------------- #
-# segmented activation                                                  #
-# --------------------------------------------------------------------- #
-
-class _StaticArray:
-    """Hashable wrapper making a numpy constant usable as a jit /
-    custom_vjp STATIC argument without materialising a per-element Python
-    tuple (the fused hidden mask is 10^5-10^6 floats at paper scale —
-    hashing the raw bytes once beats building and caching a tuple)."""
-    __slots__ = ("arr", "_hash")
-
-    def __init__(self, arr, dtype):
-        self.arr = np.ascontiguousarray(np.asarray(arr, dtype))
-        self.arr.setflags(write=False)
-        self._hash = hash((self.arr.shape, self.arr.dtype.str,
-                           self.arr.tobytes()))
-
-    def __hash__(self):
-        return self._hash
-
-    def __eq__(self, other):
-        return (isinstance(other, _StaticArray)
-                and self.arr.dtype == other.arr.dtype
-                and self.arr.shape == other.arr.shape
-                and np.array_equal(self.arr, other.arr))
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5))
-def _seg_core(h, act_ids_s, mask_s, block_h, block_b, interpret):
-    ids = jnp.asarray(act_ids_s.arr)
-    m2 = jnp.asarray(mask_s.arr).reshape(1, -1)
-    return _segk.seg_act(h, ids, m2, block_h=block_h, block_b=block_b,
-                         interpret=interpret)
-
-
-def _seg_fwd(h, act_ids_s, mask_s, block_h, block_b, interpret):
-    return _seg_core(h, act_ids_s, mask_s, block_h, block_b, interpret), h
-
-
-def _seg_bwd(act_ids_s, mask_s, block_h, block_b, interpret, h, dy):
-    ids = jnp.asarray(act_ids_s.arr)
-    m2 = jnp.asarray(mask_s.arr).reshape(1, -1)
-    return (_segk.seg_act_bwd(h, dy, ids, m2, block_h=block_h,
-                              block_b=block_b, interpret=interpret),)
-
-
-_seg_core.defvjp(_seg_fwd, _seg_bwd)
-
-
-def seg_act(h: jax.Array, block_act_ids: np.ndarray, mask: np.ndarray, *,
-            block_h: int, block_b: int = 256,
-            interpret: bool | None = None) -> jax.Array:
-    """One-pass per-block activation + padding mask. h (B, H) -> (B, H).
-
-    Differentiable (custom VJP through the seg_act_bwd kernel).
-    ``interpret=None`` auto-selects: compiled on TPU, interpreted on CPU.
-    """
-    interpret = _resolve_interpret(interpret)
-    _check_block(block_h, interpret)
-    if h.shape[1] % block_h:
-        raise ValueError(f"hidden axis {h.shape[1]} not {block_h}-aligned")
-    block_b = min(block_b, max(8, 1 << (h.shape[0] - 1).bit_length()))
-    hp, b0 = _pad_axis(h, 0, block_b)
-    y = _seg_core(hp, _StaticArray(block_act_ids, np.int32),
-                  _StaticArray(mask, np.float32), block_h, block_b,
-                  interpret)
     return y[:b0]
 
 

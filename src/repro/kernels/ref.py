@@ -1,8 +1,9 @@
 """Pure-jnp oracles for every Pallas kernel in this package.
 
-These define the *semantics*; the kernels must match them (asserted across a
-shape/dtype sweep in tests/test_kernels.py).  They are also the lowering used
-on backends without Pallas TPU support.
+These define the *semantics* of the attention and grouped-GEMM kernels; the
+kernels must match them (asserted across a shape/dtype sweep in
+tests/test_kernels.py).  The population kernels' oracles are the XLA paths
+of ``core.deep`` and ``core.activations.apply_activations_masked``.
 """
 from __future__ import annotations
 
@@ -10,44 +11,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.activations import ACTIVATION_FNS
-
 
 def expand_block_ids(block_ids: np.ndarray, block: int) -> np.ndarray:
     """Per-block id array -> per-unit id array."""
     return np.repeat(np.asarray(block_ids), block)
-
-
-def m3_matmul_ref(h: jax.Array, w2: jax.Array, block_seg_ids: np.ndarray,
-                  num_members: int, block_h: int) -> jax.Array:
-    """y[b,m,o] = sum_{j: seg(j)==m} h[b,j] * w2[o,j]   (f32 accumulation).
-
-    h (B, H), w2 (O, H) -> (B, M, O)."""
-    seg = jnp.asarray(expand_block_ids(block_seg_ids, block_h))
-    s = h.astype(jnp.float32)[:, None, :] * w2.astype(jnp.float32)[None, :, :]
-    y = jax.ops.segment_sum(jnp.moveaxis(s, -1, 0), seg,
-                            num_segments=num_members, indices_are_sorted=True)
-    return jnp.moveaxis(y, 0, 1).astype(h.dtype)
-
-
-def m3_matmul_ref_f32out(h, w2, block_seg_ids, num_members, block_h):
-    seg = jnp.asarray(expand_block_ids(block_seg_ids, block_h))
-    s = h.astype(jnp.float32)[:, None, :] * w2.astype(jnp.float32)[None, :, :]
-    y = jax.ops.segment_sum(jnp.moveaxis(s, -1, 0), seg,
-                            num_segments=num_members, indices_are_sorted=True)
-    return jnp.moveaxis(y, 0, 1)
-
-
-def seg_act_ref(h: jax.Array, block_act_ids: np.ndarray, block_h: int,
-                mask: np.ndarray | None = None) -> jax.Array:
-    """Per-block activation id applied column-wise, then optional unit mask."""
-    ids = jnp.asarray(expand_block_ids(block_act_ids, block_h))
-    out = jnp.zeros_like(h)
-    for i, fn in enumerate(ACTIVATION_FNS):
-        out = jnp.where(ids == i, fn(h), out)
-    if mask is not None:
-        out = out * jnp.asarray(mask, h.dtype)
-    return out
 
 
 def flash_attn_ref(q, k, v, *, scale: float, causal: bool, window: int):

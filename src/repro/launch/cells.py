@@ -295,8 +295,7 @@ def _population_train_cell(arch: ArchSpec, sh: ShapeSpec, mesh) -> Cell:
         # (§Perf paper-cell iteration 3; confirmed ~2× on the memory term).
         (loss, per), grads = jax.value_and_grad(
             parallel_mlp.fused_loss, has_aux=True)(
-                params, x, y, pop, "classification", m3_impl="bucketed",
-                act_impl="masked")
+                params, x, y, pop, "classification", act_impl="masked")
         new = jax.tree.map(lambda p, g: p - lr * g, params, grads)
         return new, loss, per
 

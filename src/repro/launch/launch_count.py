@@ -15,7 +15,7 @@ layer as exactly ONE launch — input layer, depth−1 mid layers, loss head —
 so a train step costs 2·(depth+1) launches at ANY batch size.  The
 two-level-grid backward (kernels/fused_layer.py) is what removed the batch
 dependence; ``fused_step_budget`` is the committed invariant that
-benchmarks and CI enforce against regressions.
+tests/test_launch_budget.py enforces against regressions.
 """
 from __future__ import annotations
 
@@ -73,8 +73,8 @@ def phase_launches(loss_fn, *args) -> dict:
 
 
 def fused_step_budget(depth: int) -> dict:
-    """The §9 invariant for the fully fused path (``bd_impl="fused"`` with
-    default input/loss routing): one launch per layer per direction —
+    """The §9 invariant for the fully fused path (``bd_impl="fused"``):
+    one launch per layer per direction —
     input + (depth−1) mids + loss head — independent of batch size."""
     per_dir = depth + 1
     return {"fwd": per_dir, "bwd": per_dir, "total": 2 * per_dir}

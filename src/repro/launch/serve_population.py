@@ -17,7 +17,7 @@ Request lifecycle:
      (``core.ensemble``): best-member routing, top-k soft-vote, or
      all-members soft-vote, each with disagreement uncertainty;
   4. per-request latency = flush wait + step wall; the driver reports
-     p50/p99 and req/s per mode (BENCH_serve.json rows).
+     p50/p99 and req/s per mode (``--json-out`` rows).
 
 The served member set comes from ``selection.leaderboard`` over a
 calibration split evaluated with the SAME infer-path kernels
@@ -39,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import set_mesh
 
+from repro.core.deep import BD_CHOICES
 from repro.core.ensemble import ENSEMBLE_MODES, ensemble_predict, real_slots
 from repro.core.selection import evaluate_population, leaderboard
 from repro.launch.launch_count import (count_pallas_launches,
@@ -50,7 +51,7 @@ class PopulationServer:
     population.  ``modes``: any of ``("best1", "topk", "all")``."""
 
     def __init__(self, params, layout, *, mesh=None, bd_impl: str = "fused",
-                 act_impl: str = "pallas", compute_dtype=None,
+                 compute_dtype=None,
                  weights_dtype=None, batch: int = 32, topk: int = 4,
                  max_latency_ms: float = 5.0):
         self.params = params
@@ -60,8 +61,8 @@ class PopulationServer:
         self.topk = int(topk)
         self.max_latency_ms = float(max_latency_ms)
         self.weights_dtype = weights_dtype
-        self._fw = dict(bd_impl=bd_impl, act_impl=act_impl,
-                        compute_dtype=compute_dtype, infer=True)
+        self._fw = dict(bd_impl=bd_impl, compute_dtype=compute_dtype,
+                        infer=True)
         if weights_dtype is not None:
             self._fw["weights_dtype"] = weights_dtype
         # int8 serve copy (DESIGN.md §12): built lazily, once, from the
@@ -275,8 +276,7 @@ def main(argv=None):
     ap.add_argument("--sharded", action="store_true",
                     help="restore + serve on the host mesh (population "
                     "axis sharded across devices)")
-    ap.add_argument("--bd-impl", default="fused")
-    ap.add_argument("--act-impl", default="pallas")
+    ap.add_argument("--bd-impl", default="fused", choices=BD_CHOICES)
     ap.add_argument("--compute-dtype", default=None)
     ap.add_argument("--weights-dtype", default=None, choices=["int8"],
                     help="int8: quantize the restored weights once "
@@ -295,8 +295,7 @@ def main(argv=None):
     server, step = PopulationServer.from_checkpoint(
         args.ckpt_dir, step=args.step, mesh=mesh, batch=args.batch,
         topk=args.topk, max_latency_ms=args.max_latency_ms,
-        bd_impl=args.bd_impl, act_impl=args.act_impl,
-        compute_dtype=args.compute_dtype,
+        bd_impl=args.bd_impl, compute_dtype=args.compute_dtype,
         weights_dtype=args.weights_dtype)
     lp = server.layout
     print(f"restored step {step}: {real_slots(lp)} members "

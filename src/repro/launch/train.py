@@ -13,10 +13,9 @@ all-reduce), --ckpt-every / --resume.
 Population archs (``--arch parallelmlp-10k``) train through the layered
 population engine (core.deep): ``--population-depths "64,32,16;13,5;7"``
 builds a heterogeneous-depth LayeredPopulation (members separated by ';',
-per-layer widths by ','), ``--bd-impl pallas`` routes mid layers through the
-block-diagonal Pallas kernel, ``--act-impl pallas`` routes per-layer
-activations through the seg_act kernel, ``--per-member-lr`` samples one
-step size per member, and checkpoints carry the fused layout
+per-layer widths by ','), ``--bd-impl fused`` runs every layer through the
+fused Pallas kernels (``einsum``, the default, through XLA ops),
+``--per-member-lr`` samples one step size per member, and checkpoints carry the fused layout
 (checkpoint.save_population) so ``--resume`` needs no flags re-supplied.
 The population path is distribution-native: the layout shard-pads to the
 mesh's 'model' axis, params are born sharded, batches shard over 'data',
@@ -46,6 +45,7 @@ from jax.profiler import StepTraceAnnotation
 
 from repro.checkpoint import latest_steps, restore
 from repro.configs import get_arch
+from repro.core.deep import BD_CHOICES
 from repro.data import TabularTask, TokenTask
 from repro.distributed import TrainRunner, StragglerPolicy
 from repro.distributed.sharding import logical_to_sharding
@@ -532,7 +532,7 @@ def run_population(arch, args, report=None, mesh=None):
             return m
 
         train_meta = {"compute_dtype": args.compute_dtype,
-                      "bd_impl": args.bd_impl, "act_impl": args.act_impl,
+                      "bd_impl": args.bd_impl,
                       "optimizer": opt_record,
                       "lr_schedule": args.lr_schedule}
 
@@ -584,8 +584,7 @@ def run_population(arch, args, report=None, mesh=None):
                 chunk_fn = chunk_cache[chunk_key] = \
                     deep.make_population_train_step(
                         lp, optimizer=opt, grad_clip=grad_clip,
-                        m3_impl=args.m3_impl, bd_impl=args.bd_impl,
-                        act_impl=args.act_impl, scan_steps=scan,
+                        bd_impl=args.bd_impl, scan_steps=scan,
                         donate_batch=pipeline,
                         compute_dtype=args.compute_dtype,
                         lr_schedule=lr_sched)
@@ -782,7 +781,7 @@ def run_population(arch, args, report=None, mesh=None):
             if server is None:
                 server = PopulationServer(
                     params, lp, mesh=mesh, bd_impl=args.bd_impl,
-                    act_impl=args.act_impl, batch=args.batch,
+                    batch=args.batch,
                     topk=min(4, lp.num_real))
             else:
                 server.refresh(params, lp)
@@ -1012,7 +1011,7 @@ def run_population(arch, args, report=None, mesh=None):
                   f"model-steps/s); loss {loss0:.4f} -> {loss:.4f}")
             if refill_mode != "off":
                 # every id ever issued is a distinct model the search
-                # visited — the bench's models-explored-per-second metric
+                # visited — models explored per second
                 print(f"explored {next_id} models "
                       f"({stats.get('refilled', 0)} refilled) in {dt:.1f}s "
                       f"({next_id / max(dt, 1e-9):.2f} models/s); "
@@ -1084,13 +1083,10 @@ def main(argv=None, report=None, mesh=None):
     ap.add_argument("--population-classes", type=int, default=2)
     ap.add_argument("--population-block", type=int, default=8)
     ap.add_argument("--samples", type=int, default=2048)
-    ap.add_argument("--m3-impl", default="bucketed",
-                    choices=["scatter", "onehot", "bucketed", "pallas"])
-    ap.add_argument("--bd-impl", default="einsum",
-                    choices=["einsum", "pallas", "fused"],
-                    help="mid-layer projection: per-bucket einsum, the "
-                         "block-diag Pallas kernel, or the FUSED kernel "
-                         "(projection + bias + activation in one pass, "
+    ap.add_argument("--bd-impl", default="einsum", choices=BD_CHOICES,
+                    help="the one implementation decision: XLA ops "
+                         "(einsum) or the FUSED Pallas kernels (projection "
+                         "+ bias + activation in one pass per layer, "
                          "DESIGN.md §7)")
     ap.add_argument("--compute-dtype", default="float32",
                     choices=["float32", "bfloat16"],
@@ -1103,11 +1099,6 @@ def main(argv=None, report=None, mesh=None):
                          "full split; the FINAL leaderboard eval always "
                          "runs the full split) — successive halving only "
                          "needs rank fidelity at the cut line")
-    ap.add_argument("--act-impl", default="sliced",
-                    choices=["sliced", "masked", "pallas"],
-                    help="per-layer activation dispatch: contiguous XLA "
-                         "slices, branchless masking, or the seg_act "
-                         "Pallas kernel")
     ap.add_argument("--scan-steps", type=int, default=8,
                     help="population path: optimizer steps fused into one "
                          "jitted lax.scan chunk (donated params, one "

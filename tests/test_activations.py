@@ -1,5 +1,6 @@
-"""Per-member activation application: all three strategies agree, and each
-activation matches its torch-default definition on known points."""
+"""Per-member activation application: the sliced pass, the masked oracle and
+the fused kernels' epilogue table agree, and each activation matches its
+torch-default definition on known points."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,8 +11,7 @@ from repro.core.activations import (ACTIVATIONS, ACTIVATION_ORDER, PAPER_TEN,
                                     apply_activations_masked,
                                     apply_activations_sliced)
 from repro.core.population import Population
-from repro.kernels import seg_act
-from repro.kernels.ref import seg_act_ref
+from repro.kernels.epilogue import VAL_BRANCHES
 
 
 def test_paper_has_ten():
@@ -55,9 +55,12 @@ def test_strategies_agree(pop, sort):
     b = apply_activations_masked(h, pop.act_ids)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=1e-6, atol=1e-6)
-    # Pallas kernel (interpret) with fused padding mask
-    c = seg_act(h, pop.block_act_ids, pop.hidden_mask, block_h=pop.block,
-                interpret=True)
+    # the fused kernels' epilogue: one branch of the lax.switch table per
+    # block, picked by the block's activation id, then the padding mask
+    blocks = jnp.split(h, len(pop.block_act_ids), axis=-1)
+    c = jnp.concatenate([jax.lax.switch(int(i), VAL_BRANCHES, hb)
+                         for i, hb in zip(pop.block_act_ids, blocks)], -1)
+    c = c * jnp.asarray(pop.hidden_mask)
     want = np.asarray(b) * np.asarray(pop.hidden_mask)
     np.testing.assert_allclose(np.asarray(c), want, rtol=1e-6, atol=1e-6)
 

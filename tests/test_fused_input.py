@@ -1,21 +1,22 @@
 """Fused input layer (kernels/fused_input.py, DESIGN.md §9): dense
 input-feature matmul + bias + per-segment activation + padding mask in ONE
-Pallas pass, replacing the XLA dot + standalone seg_act epilogue for
+Pallas pass, replacing the XLA dot + standalone activation pass for
 layer 0 of the fused population path.  Interpret-mode equivalence vs the
 XLA reference (``input_xla``) — values and per-operand gradients — on
 ragged layouts, the wide-feature (F > 128, tiled reduction) path, tiles of
 several hidden blocks by the whole batch (``tile_plan``), the in-kernel
 bias gradient, the dx the backward makes only for a perturbed x, and the
-registry's default routing."""
+routing of layer 0 by ``bd_impl``."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.deep import (IN_IMPLS, FUSED_IN_IMPLS, _resolve_in_impl,
-                             init_params, input_fused, input_xla)
+from repro.core.deep import (forward, fused_loss, init_params, input_fused,
+                             input_xla)
 from repro.core.population import LayeredPopulation
-from repro.kernels import block_diag, fused_input, ops
+from repro.kernels import fused_input, ops, tiling
+from _kernel_routing import kernel_names
 
 LP = LayeredPopulation(20, 3, ((5, 3), (12, 9), (7,), (17, 9, 5)),
                        ("relu", "gelu", "tanh", "mish"), block=8)
@@ -31,18 +32,35 @@ def _inputs(lp, b=9, seed=0):
     return x, params["w_in"], params["b_in"]
 
 
+def _train_step_kernels(bd_impl, lp=LP):
+    x, _, _ = _inputs(lp)
+    params = init_params(jax.random.PRNGKey(0), lp)
+    y = jnp.zeros((x.shape[0],), jnp.int32)
+    return kernel_names(jax.grad(
+        lambda p: fused_loss(p, x, y, lp, bd_impl=bd_impl)[0]), params)
+
+
 def test_registry_has_fused():
-    assert set(IN_IMPLS) == {"xla", "fused"}
-    assert "fused" in FUSED_IN_IMPLS
+    """The fused train step runs layer 0 through the fused input kernel,
+    both directions."""
+    assert {"fused_input_fwd", "fused_input_bwd"} <= _train_step_kernels(
+        "fused")
 
 
 def test_default_routing_follows_bd_impl():
-    assert _resolve_in_impl(None, "fused") == "fused"
-    assert _resolve_in_impl(None, "einsum") == "xla"
-    assert _resolve_in_impl(None, "pallas") == "xla"
-    assert _resolve_in_impl("xla", "fused") == "xla"   # explicit override
-    with pytest.raises(ValueError, match="in_impl"):
-        _resolve_in_impl("cutlass", "fused")
+    """Layer 0 follows ``bd_impl``: the fused path's train step and
+    forward launch the fused input kernels, the einsum path's launch no
+    kernel at all (XLA dot + sliced activation)."""
+    x, _, _ = _inputs(LP)
+    params = init_params(jax.random.PRNGKey(0), LP)
+    assert _train_step_kernels("fused") == {
+        "fused_input_fwd", "fused_input_bwd", "fused_mid_fwd",
+        "fused_mid_bwd", "loss_head_fwd", "loss_head_bwd"}
+    assert _train_step_kernels("einsum") == set()
+    assert "fused_input_infer" in kernel_names(
+        lambda p: forward(p, x, LP, bd_impl="fused", infer=True), params)
+    assert kernel_names(lambda p: forward(p, x, LP, bd_impl="einsum"),
+                        params) == set()
 
 
 @pytest.mark.parametrize("lp", [LP, LP_WIDE], ids=["narrow", "wide_f"])
@@ -91,7 +109,7 @@ def _tile(monkeypatch, b, g, lp=LP_TILED):
     rows of the activations and ``block_f`` columns of the weight."""
     block_f = fused_input.pick_block_f(
         fused_input.padded_features(lp.in_features))
-    monkeypatch.setattr(block_diag, "TILE_BYTES",
+    monkeypatch.setattr(tiling, "TILE_BYTES",
                         max(-(-b // 8) * 8, block_f) * 8 * 4 * g)
     assert fused_input.tile_plan(b, lp.layer_pop(0).total_hidden,
                                  lp.in_features, 8).g == g
@@ -272,6 +290,19 @@ def test_param_grad_makes_no_dx(monkeypatch):
 ])
 def test_tile_plan(args, want):
     assert tuple(fused_input.tile_plan(*args)) == want
+
+
+@pytest.mark.parametrize("args,kw,want", [
+    # 2,048 blocks of 1 KiB make the step's 2 MiB; the cap holds it to 8
+    ((100, 1024), {"cap": 8}, 8),
+    # 20 blocks fit, rounded down to a multiple of 8; 3 fit, raised to 8
+    ((100, tiling.TILE_BYTES // 20), {"multiple": 8}, 16),
+    ((100, tiling.TILE_BYTES // 3), {"multiple": 8}, 8),
+    # one step takes every block, whatever the multiple
+    ((5, 1024), {"multiple": 8}, 5),
+], ids=["cap", "multiple", "multiple-floor", "all-blocks"])
+def test_tile_blocks(args, kw, want):
+    assert tiling.tile_blocks(*args, **kw) == want
 
 
 def test_tile_plan_dx_follows_x():

@@ -12,10 +12,12 @@ import numpy as np
 import pytest
 
 from repro.core.activations import ACTIVATION_ORDER
-from repro.core.deep import (BD_IMPLS, FUSED_BD_IMPLS, block_diag_matmul,
-                             forward, fused_loss, init_params, pad_params,
-                             sgd_step)
+from repro.core.deep import (BD_CHOICES, _act, block_diag_einsum,
+                             block_diag_fused, forward, fused_loss,
+                             init_params, make_population_train_step,
+                             pad_params, sgd_step)
 from repro.core.population import LayeredPopulation
+from _kernel_routing import kernel_names
 
 # one member per paper activation, ragged widths AND ragged depths: every
 # bucket shape (odd fan-ins, duplicate shapes, pass-throughs) in one layout
@@ -33,8 +35,24 @@ def _params_and_batch(lp, b=9, seed=0):
 
 
 def test_registry_has_fused():
-    assert "fused" in BD_IMPLS
-    assert "fused" in FUSED_BD_IMPLS
+    """``bd_impl`` takes exactly the XLA path and the fused one; the fused
+    train step's mid layers run the fused mid-layer kernel."""
+    assert BD_CHOICES == ("einsum", "fused")
+    params, x, y = _params_and_batch(LP_ALL)
+    names = kernel_names(jax.grad(
+        lambda p: fused_loss(p, x, y, LP_ALL, bd_impl="fused")[0]), params)
+    assert {"fused_mid_fwd", "fused_mid_bwd"} <= names
+
+
+@pytest.mark.parametrize("bad", ["pallas", "cutlass"])
+def test_forward_and_train_step_refuse_unknown_impl(bad):
+    """A removed or typo'd implementation fails loudly, naming the two
+    that exist, instead of falling back to another one."""
+    params, x, _ = _params_and_batch(LP_ALL)
+    with pytest.raises(ValueError, match="einsum.*fused"):
+        forward(params, x, LP_ALL, bd_impl=bad)
+    with pytest.raises(ValueError, match="einsum.*fused"):
+        make_population_train_step(LP_ALL, bd_impl=bad)
 
 
 def test_forward_matches_einsum_every_activation():
@@ -49,7 +67,7 @@ def test_grad_matches_einsum_every_activation():
     params, x, y = _params_and_batch(LP_ALL)
 
     def loss(impl):
-        return lambda p: fused_loss(p, x, y, LP_ALL, "bucketed", impl)[0]
+        return lambda p: fused_loss(p, x, y, LP_ALL, bd_impl=impl)[0]
 
     ge = jax.grad(loss("einsum"))(params)
     gf = jax.grad(loss("fused"))(params)
@@ -66,7 +84,7 @@ def test_grad_matches_multi_batch_tile_one_pass():
     params, x, y = _params_and_batch(LP_ALL, b=160, seed=5)
 
     def loss(impl):
-        return lambda p: fused_loss(p, x, y, LP_ALL, "bucketed", impl)[0]
+        return lambda p: fused_loss(p, x, y, LP_ALL, bd_impl=impl)[0]
 
     ge = jax.grad(loss("einsum"))(params)
     gf = jax.grad(loss("fused"))(params)
@@ -86,16 +104,14 @@ def test_grad_matches_large_batch_direct_kernel():
     w, bia = params["mid"][0]["w"], params["mid"][0]["b"]
     h = jax.random.normal(jax.random.PRNGKey(8),
                           (1024, lp.layer_pop(0).total_hidden))
-    from repro.core.deep import _act
 
     def ref(hh, ww, bb):
-        z = block_diag_matmul(hh, ww, lp, 0, impl="einsum")
+        z = block_diag_einsum(hh, ww, lp, 0)
         z = z + bb * jnp.asarray(lp.active_unit_mask(1), jnp.float32)
-        return _act(lp, 1, z, "sliced")
+        return _act(lp, 1, z)
 
     def fus(hh, ww, bb):
-        return block_diag_matmul(hh, ww, lp, 0, impl="fused", bias=bb,
-                                 block_b=128)
+        return block_diag_fused(hh, ww, lp, 0, bias=bb, block_b=128)
 
     np.testing.assert_allclose(np.asarray(ref(h, w, bia)),
                                np.asarray(fus(h, w, bia)),
@@ -116,12 +132,10 @@ def test_bf16_grad_multi_batch_tile():
     bf16 tolerance across the batch-tile loop (accumulator dtype bugs
     amplify with more inner steps, so this is where they'd show)."""
     params, x, y = _params_and_batch(LP_ALL, b=160, seed=11)
-    ge = jax.grad(lambda p: fused_loss(p, x, y, LP_ALL, "bucketed",
-                                       "einsum", "sliced",
-                                       "bfloat16")[0])(params)
-    gf = jax.grad(lambda p: fused_loss(p, x, y, LP_ALL, "bucketed",
-                                       "fused", "pallas",
-                                       "bfloat16")[0])(params)
+    ge = jax.grad(lambda p: fused_loss(p, x, y, LP_ALL, bd_impl="einsum",
+                                       compute_dtype="bfloat16")[0])(params)
+    gf = jax.grad(lambda p: fused_loss(p, x, y, LP_ALL, bd_impl="fused",
+                                       compute_dtype="bfloat16")[0])(params)
     for a, b in zip(jax.tree.leaves(ge), jax.tree.leaves(gf)):
         assert b.dtype == jnp.float32
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -138,7 +152,6 @@ def test_fused_matches_einsum_ragged_layouts(widths, acts, block):
     call (bias + activation composed manually for the reference)."""
     lp = LayeredPopulation(5, 2, widths, acts, block=block)
     params = init_params(jax.random.PRNGKey(3), lp)
-    from repro.core.deep import _act
     for l in range(lp.depth - 1):
         w = params["mid"][l]["w"]
         b = params["mid"][l]["b"]
@@ -146,12 +159,12 @@ def test_fused_matches_einsum_ragged_layouts(widths, acts, block):
                               (7, lp.layer_pop(l).total_hidden))
 
         def ref(hh, ww, bb):
-            z = block_diag_matmul(hh, ww, lp, l, impl="einsum")
+            z = block_diag_einsum(hh, ww, lp, l)
             z = z + bb * jnp.asarray(lp.active_unit_mask(l + 1), jnp.float32)
-            return _act(lp, l + 1, z, "sliced")
+            return _act(lp, l + 1, z)
 
         def fus(hh, ww, bb):
-            return block_diag_matmul(hh, ww, lp, l, impl="fused", bias=bb)
+            return block_diag_fused(hh, ww, lp, l, bias=bb)
 
         ye, yf = ref(h, w, b), fus(h, w, b)
         np.testing.assert_allclose(np.asarray(ye), np.asarray(yf),
@@ -181,39 +194,38 @@ def test_fused_with_shard_pad_fillers():
     yf = forward(params, x, lp_pad, bd_impl="fused")
     np.testing.assert_allclose(np.asarray(ye), np.asarray(yf),
                                rtol=1e-5, atol=1e-6)
-    ge = jax.grad(lambda p: fused_loss(p, x, y, lp_pad, "bucketed",
-                                       "einsum")[0])(params)
-    gf = jax.grad(lambda p: fused_loss(p, x, y, lp_pad, "bucketed",
-                                       "fused")[0])(params)
+    ge = jax.grad(lambda p: fused_loss(p, x, y, lp_pad,
+                                       bd_impl="einsum")[0])(params)
+    gf = jax.grad(lambda p: fused_loss(p, x, y, lp_pad,
+                                       bd_impl="fused")[0])(params)
     jax.tree.map(
         lambda a, b: np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-6), ge, gf)
 
 
-@pytest.mark.parametrize("bd_impl", sorted(BD_IMPLS))
+@pytest.mark.parametrize("bd_impl", BD_CHOICES)
 def test_bf16_policy_tracks_f32(bd_impl):
-    """bf16 operands / f32 accumulators: every impl's bf16 loss and
+    """bf16 operands / f32 accumulators: both paths' bf16 loss and
     gradients stay within bf16 tolerance of its f32 run, and the f32
     master-parameter update keeps its dtype."""
     params, x, y = _params_and_batch(LP_ALL)
-    l32, _ = fused_loss(params, x, y, LP_ALL, "bucketed", bd_impl)
-    l16, _ = fused_loss(params, x, y, LP_ALL, "bucketed", bd_impl,
+    l32, _ = fused_loss(params, x, y, LP_ALL, bd_impl=bd_impl)
+    l16, _ = fused_loss(params, x, y, LP_ALL, bd_impl=bd_impl,
                         compute_dtype="bfloat16")
     assert l16.dtype == jnp.float32          # fp32 loss under the policy
     np.testing.assert_allclose(float(l32), float(l16), rtol=5e-2)
 
-    g32 = jax.grad(lambda p: fused_loss(p, x, y, LP_ALL, "bucketed",
-                                        bd_impl)[0])(params)
-    g16 = jax.grad(lambda p: fused_loss(p, x, y, LP_ALL, "bucketed",
-                                        bd_impl, "sliced",
-                                        "bfloat16")[0])(params)
+    g32 = jax.grad(lambda p: fused_loss(p, x, y, LP_ALL,
+                                        bd_impl=bd_impl)[0])(params)
+    g16 = jax.grad(lambda p: fused_loss(p, x, y, LP_ALL, bd_impl=bd_impl,
+                                        compute_dtype="bfloat16")[0])(params)
     for a, b in zip(jax.tree.leaves(g32), jax.tree.leaves(g16)):
         assert b.dtype == jnp.float32        # grads land f32 on f32 masters
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-1, atol=5e-2)
 
-    new, _, _ = sgd_step(params, x, y, 0.05, LP_ALL, "bucketed", bd_impl,
-                         "sliced", "bfloat16")
+    new, _, _ = sgd_step(params, x, y, 0.05, LP_ALL, bd_impl=bd_impl,
+                         compute_dtype="bfloat16")
     assert all(p.dtype == jnp.float32 for p in jax.tree.leaves(new))
 
 
@@ -228,18 +240,3 @@ def test_bf16_fused_matches_bf16_einsum():
                  compute_dtype="bfloat16")
     np.testing.assert_allclose(np.asarray(ye), np.asarray(yf),
                                rtol=2e-2, atol=2e-2)
-
-
-def test_bench_refuses_unknown_impl():
-    """Bench hygiene: a typo'd / backend-missing impl aborts loudly instead
-    of silently falling back to another implementation."""
-    import pathlib
-    import sys
-    root = pathlib.Path(__file__).resolve().parents[1]
-    sys.path.insert(0, str(root / "benchmarks"))
-    try:
-        import bench_m3_variants
-        with pytest.raises(SystemExit, match="not available"):
-            bench_m3_variants._require_impl("cutlass")
-    finally:
-        sys.path.remove(str(root / "benchmarks"))

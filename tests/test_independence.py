@@ -4,8 +4,9 @@ EXACTLY as they would standalone — gradients never mix across members.
 Method: init a fused population; extract each member; train the fused
 network with SGD for several steps; train each extracted member standalone
 on the same batches; the fused member slices must equal the standalone
-parameters to float tolerance.  Also covers per-member learning rates
-(paper §7) and loss equality."""
+parameters to float tolerance — on the single-layer engine and on the
+layered engine the benchmark runs, through both of its paths.  Also covers
+per-member learning rates (paper §7) and loss equality."""
 import dataclasses
 
 import jax
@@ -13,13 +14,36 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import (Population, extract_member, forward, init_params,
-                        member_forward, sgd_step)
+from repro.core import (Population, deep, extract_member, forward,
+                        init_params, member_forward, sgd_step)
 from repro.core.activations import ACTIVATIONS
 from repro.core.parallel_mlp import member_losses
+from repro.core.population import LayeredPopulation
 
 POP = Population(6, 3, (3, 9, 1, 20, 9),
                  ("relu", "tanh", "identity", "mish", "sigmoid"), block=8)
+# the same members as a depth-1 layered population
+LP = LayeredPopulation(6, 3, tuple((h,) for h in POP.hidden_sizes),
+                       POP.activations, block=8)
+
+
+def _engine(name):
+    """(init, step, extract) of the single-layer engine ("bucketed": its
+    M3 is the bucketed einsum) or of the layered engine through
+    ``bd_impl`` ("einsum", "fused"), with members as w1/b1/w2/b2."""
+    if name == "bucketed":
+        return (lambda k: init_params(k, POP),
+                lambda p, x, y, lr: sgd_step(p, x, y, lr, POP),
+                lambda p, m: extract_member(p, POP, m))
+
+    def extract(p, m):
+        e = deep.extract_member(p, LP, m)
+        return {"w1": e["w_in"], "b1": e["b_in"], "w2": e["w_out"],
+                "b2": e["b_out"], "activation": e["activation"]}
+
+    return (lambda k: deep.init_params(k, LP),
+            lambda p, x, y, lr: deep.sgd_step(p, x, y, lr, LP, bd_impl=name),
+            extract)
 
 
 def standalone_step(member, x, y, lr):
@@ -39,11 +63,11 @@ def member_forward_dict(m, x, act):
     return h @ m["w2"].T + m["b2"]
 
 
-@pytest.mark.parametrize("m3_impl", ["scatter", "bucketed", "onehot"])
-def test_fused_equals_standalone(m3_impl):
-    key = jax.random.PRNGKey(42)
-    params = init_params(key, POP)
-    members = [extract_member(params, POP, m) for m in range(POP.num_members)]
+@pytest.mark.parametrize("engine", ["bucketed", "einsum", "fused"])
+def test_fused_equals_standalone(engine):
+    init, step_fn, extract = _engine(engine)
+    params = init(jax.random.PRNGKey(42))
+    members = [extract(params, m) for m in range(POP.num_members)]
 
     kx = jax.random.PRNGKey(7)
     lr = 0.05
@@ -52,11 +76,11 @@ def test_fused_equals_standalone(m3_impl):
         kx, k1, k2 = jax.random.split(kx, 3)
         x = jax.random.normal(k1, (16, 6))
         y = jax.random.randint(k2, (16,), 0, 3)
-        fused, _, _ = sgd_step(fused, x, y, lr, POP, m3_impl=m3_impl)
+        fused, _, _ = step_fn(fused, x, y, lr)
         members = [standalone_step(m, x, y, lr) for m in members]
 
     for m in range(POP.num_members):
-        got = extract_member(fused, POP, m)
+        got = extract(fused, m)
         want = members[m]
         for k in ("w1", "b1", "w2", "b2"):
             np.testing.assert_allclose(

@@ -27,6 +27,7 @@ from repro.core.selection import (evaluate_population, leaderboard,
                                   member_metrics)
 from repro.launch.launch_count import (count_pallas_launches,
                                        fused_infer_budget, max_eqn_outputs)
+from _kernel_routing import kernel_names
 
 # one member per activation — the reference sweep covers the whole table
 _WIDTHS = ((5, 3), (12, 9), (7,), (17, 9, 5), (8, 8),
@@ -44,12 +45,11 @@ def _x(b=B, lp=LP):
 
 
 def _infer(params, x, lp=LP, **kw):
-    return deep.forward(params, x, lp, bd_impl="fused", act_impl="pallas",
-                        infer=True, **kw)
+    return deep.forward(params, x, lp, bd_impl="fused", infer=True, **kw)
 
 
 def _ref(params, x, lp=LP):
-    return deep.forward(params, x, lp, bd_impl="einsum", act_impl="sliced")
+    return deep.forward(params, x, lp, bd_impl="einsum")
 
 
 # --------------------------------------------------------------------- #
@@ -76,16 +76,24 @@ def test_infer_log_probs_in_kernel():
 
 
 def test_infer_xla_head_routing():
-    """``head_impl="xla"`` keeps the fused hidden stack but runs the
-    bucketed output projection — numerics identical."""
+    """The head follows ``bd_impl``: the fused serving forward ends in the
+    infer-head kernel after the forward-only layer kernels, the einsum
+    serving forward is all XLA (the bucketed M3 head) — numerics equal."""
     params, x = _params(), _x()
-    np.testing.assert_allclose(_infer(params, x, head_impl="xla"),
-                               _ref(params, x), rtol=1e-5, atol=1e-6)
+    assert kernel_names(lambda p: _infer(p, x), params) == {
+        "fused_input_infer", "fused_mid_infer", "infer_head"}
+    xla = deep.forward(params, x, LP, bd_impl="einsum", infer=True)
+    assert kernel_names(lambda p: deep.forward(
+        p, x, LP, bd_impl="einsum", infer=True), params) == set()
+    np.testing.assert_allclose(xla, _infer(params, x), rtol=1e-5, atol=1e-6)
 
 
 def test_infer_rejects_unknown_head():
-    with pytest.raises(ValueError, match="head_impl"):
-        _infer(_params(), _x(), head_impl="nope")
+    """Serving has no implementation of its own: a name other than the
+    two paths is refused, the removed ``pallas`` included."""
+    for bad in ("nope", "pallas"):
+        with pytest.raises(ValueError, match="einsum.*fused"):
+            deep.forward(_params(), _x(), LP, bd_impl=bad, infer=True)
 
 
 def test_infer_bf16_policy():
@@ -155,7 +163,7 @@ def test_train_reuse_keeps_residuals_alive():
 
     def reuse(p):
         return jax.vjp(lambda q: deep.forward(
-            q, x, LP, bd_impl="fused", act_impl="pallas"), p)[0]
+            q, x, LP, bd_impl="fused"), p)[0]
 
     assert max_eqn_outputs(reuse, params) == 2
 
@@ -261,7 +269,7 @@ def test_eval_routes_through_infer_path():
     y = jax.random.randint(jax.random.PRNGKey(2), (64,), 0, LP.out_features)
     l_ref, a_ref = evaluate_population(params, LP, x, y)
     l_inf, a_inf = evaluate_population(params, LP, x, y, bd_impl="fused",
-                                       act_impl="pallas", infer=True)
+                                       infer=True)
     np.testing.assert_allclose(l_inf, l_ref, rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(a_inf, a_ref, rtol=1e-6)
 

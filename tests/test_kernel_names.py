@@ -32,9 +32,6 @@ NAMES = {
                      "loss_head_many_fwd", "loss_head_many_eval",
                      "loss_head_many_bwd"},
     "infer_head.py": {"infer_head", "infer_head_int8"},
-    "seg_act.py": {"seg_act_fwd", "seg_act_bwd"},
-    "block_diag.py": {"block_diag_fwd", "block_diag_dw"},
-    "m3_matmul.py": {"m3_fwd", "m3_dh", "m3_dw"},
     "moe_gemm.py": {"moe_gemm"},
     "flash_attn.py": {"flash_attn"},
 }

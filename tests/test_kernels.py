@@ -1,12 +1,14 @@
-"""Per-kernel validation: Pallas (interpret=True on CPU) vs the pure-jnp
-oracles in kernels/ref.py, swept over shapes and dtypes, values + grads."""
+"""Per-kernel validation: Pallas (interpret=True on CPU) vs pure-jnp
+oracles (kernels/ref.py, brute-force loops, the masked activation select),
+swept over shapes and dtypes, values + grads."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import m3_matmul, moe_gemm, seg_act
-from repro.kernels import ref
+from repro.core.activations import ACTIVATION_ORDER, apply_activations_masked
+from repro.kernels import moe_gemm, ref
+from repro.kernels.ops import fused_input, loss_head
 
 
 def _seg_layout(rng, n_members, blocks_per=3, block_h=8):
@@ -16,53 +18,79 @@ def _seg_layout(rng, n_members, blocks_per=3, block_h=8):
     return ids, int(ids.size * block_h)
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("b,o,members,block_h", [
-    (4, 3, 2, 8), (16, 2, 5, 8), (7, 9, 3, 16), (1, 1, 1, 8), (32, 4, 7, 8),
-])
-def test_m3_matmul_kernel(b, o, members, block_h, dtype, rng):
+def _head_brute_force(h, w2, b2, y, unit_ids, members):
+    """Per-member mean NLL by the obvious loop: member m's logits from its
+    own hidden columns only, then ``log_softmax``."""
+    per = []
+    for m in range(members):
+        cols = np.flatnonzero(unit_ids == m)
+        logits = h[:, cols] @ w2[:, cols].T + b2[m]
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        per.append(-jnp.take_along_axis(logp, y[:, None], axis=1).mean())
+    return jnp.stack(per)
+
+
+def _head_inputs(rng, b, o, members, block_h, dtype):
     ids, hh = _seg_layout(rng, members, block_h=block_h)
     h = jnp.asarray(rng.normal(0, 1, (b, hh)), dtype)
-    w2 = jnp.asarray(rng.normal(0, 1, (o, hh)), dtype)
-    got = m3_matmul(h, w2, ids, members, block_h=block_h, interpret=True)
-    want = ref.m3_matmul_ref(h, w2, ids, members, block_h)
+    w2 = jnp.asarray(rng.normal(0, 0.5, (o, hh)), dtype)
+    b2 = jnp.asarray(rng.normal(0, 1, (members, o)), jnp.float32)
+    y = jnp.asarray(rng.integers(0, o, b), jnp.int32)
+    return ids, h, w2, b2, y
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("b,o,members,block_h", [
+    (4, 3, 2, 8), (16, 2, 5, 8), (7, 9, 3, 16), (1, 2, 1, 8), (32, 4, 7, 8),
+])
+def test_loss_head_matches_brute_force(b, o, members, block_h, dtype, rng):
+    """The fused loss head against the per-member loop: B = 1 and 7 pad
+    the batch, O = 9 takes the many-class body."""
+    ids, h, w2, b2, y = _head_inputs(rng, b, o, members, block_h, dtype)
+    got = loss_head(h, w2, b2, y, ids, block_h=block_h, interpret=True)
+    want = _head_brute_force(h.astype(jnp.float32), w2.astype(jnp.float32),
+                             b2, y, np.repeat(ids, block_h), members)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32),
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=tol, atol=tol)
 
 
-def test_m3_matmul_kernel_grads(rng):
-    ids, hh = _seg_layout(rng, 4, block_h=8)
-    h = jnp.asarray(rng.normal(0, 1, (8, hh)), jnp.float32)
-    w2 = jnp.asarray(rng.normal(0, 1, (3, hh)), jnp.float32)
+def test_loss_head_grads_match_brute_force(rng):
+    ids, h, w2, b2, y = _head_inputs(rng, 7, 9, 3, 16, jnp.float32)
+    units = np.repeat(ids, 16)
 
-    def loss_k(hh_, ww):
-        return (m3_matmul(hh_, ww, ids, 4, block_h=8, interpret=True) ** 2) \
-            .sum()
+    def loss_k(hh, ww, bb):
+        return (loss_head(hh, ww, bb, y, ids, block_h=16, interpret=True)
+                * jnp.arange(1.0, 4.0)).sum()
 
-    def loss_r(hh_, ww):
-        return (ref.m3_matmul_ref_f32out(hh_, ww, ids, 4, 8) ** 2).sum()
+    def loss_r(hh, ww, bb):
+        return (_head_brute_force(hh, ww, bb, y, units, 3)
+                * jnp.arange(1.0, 4.0)).sum()
 
-    gk = jax.grad(loss_k, argnums=(0, 1))(h, w2)
-    gr = jax.grad(loss_r, argnums=(0, 1))(h, w2)
+    gk = jax.grad(loss_k, argnums=(0, 1, 2))(h, w2, b2)
+    gr = jax.grad(loss_r, argnums=(0, 1, 2))(h, w2, b2)
     for a, b in zip(gk, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-4, atol=2e-4)
+                                   rtol=2e-4, atol=2e-5)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("b,blocks,block_h", [(4, 3, 8), (9, 10, 8), (2, 4, 16)])
-def test_seg_act_kernel(b, blocks, block_h, dtype, rng):
-    ids = jnp.asarray(rng.integers(0, 10, blocks), jnp.int32)
-    hh = blocks * block_h
+def test_fused_input_epilogue_matches_masked(b, blocks, block_h, dtype, rng):
+    """The fused input layer's activation epilogue against the branchless
+    oracle, a different activation in every block and a random mask."""
+    ids = rng.permutation(len(ACTIVATION_ORDER))[:blocks].astype(np.int32)
+    hh, f = blocks * block_h, 5
     mask = (rng.random(hh) > 0.2).astype(np.float32)
-    h = jnp.asarray(rng.normal(0, 1, (b, hh)), dtype)
-    got = seg_act(h, np.asarray(ids), mask, block_h=block_h, interpret=True)
-    want = ref.seg_act_ref(h, np.asarray(ids), block_h, mask)
-    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-6
+    x = jnp.asarray(rng.normal(0, 1, (b, f)), dtype)
+    w = jnp.asarray(rng.normal(0, 1, (hh, f)), dtype)
+    bias = jnp.asarray(rng.normal(0, 1, hh), jnp.float32)
+    got = fused_input(x, w, bias, ids, mask, block=block_h, interpret=True)
+    z = x.astype(jnp.float32) @ w.astype(jnp.float32).T + bias
+    want = (apply_activations_masked(z, np.repeat(ids, block_h)) * mask)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
     np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32),
+                               np.asarray(want.astype(dtype), np.float32),
                                rtol=tol, atol=tol)
 
 
@@ -83,18 +111,6 @@ def test_moe_gemm_kernel(e, d, f, block_t, dtype, rng):
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                rtol=tol, atol=tol)
-
-
-def test_m3_kernel_used_by_population():
-    """End-to-end: the Pallas path through the ParallelMLP forward."""
-    from repro.core import Population, forward, init_params
-    pop = Population(5, 3, (3, 9, 17), ("relu", "tanh", "gelu"), block=8)
-    params = init_params(jax.random.PRNGKey(0), pop)
-    x = jax.random.normal(jax.random.PRNGKey(1), (8, 5))
-    y_pallas = forward(params, x, pop, m3_impl="pallas")
-    y_ref = forward(params, x, pop, m3_impl="scatter")
-    np.testing.assert_allclose(np.asarray(y_pallas), np.asarray(y_ref),
-                               rtol=2e-5, atol=2e-5)
 
 
 def test_interpret_only_on_cpu(monkeypatch):

@@ -22,8 +22,7 @@ LP = LayeredPopulation(6, 3, _WIDTHS, ACTIVATION_ORDER, block=8)
 
 def _loss(x, y):
     def loss(p):
-        return deep.fused_loss(p, x, y, LP, "bucketed", "fused",
-                               "pallas")[0]
+        return deep.fused_loss(p, x, y, LP, bd_impl="fused")[0]
     return loss
 
 
@@ -50,7 +49,7 @@ def test_xla_path_launches_nothing():
     y = jnp.zeros((9,), jnp.int32)
 
     def loss(p):
-        return deep.fused_loss(p, x, y, LP, "bucketed", "einsum")[0]
+        return deep.fused_loss(p, x, y, LP, bd_impl="einsum")[0]
     assert phase_launches(loss, params) == {"fwd": 0, "bwd": 0, "total": 0}
 
 
@@ -61,7 +60,7 @@ def test_scan_chunk_is_budget_times_trip_count():
     scan_steps = 4
     params = deep.init_params(jax.random.PRNGKey(0), LP)
     chunk = deep.make_population_train_step(
-        LP, bd_impl="fused", act_impl="pallas", scan_steps=scan_steps,
+        LP, bd_impl="fused", scan_steps=scan_steps,
         donate=False)
     xs = jnp.zeros((scan_steps, 9, LP.in_features))
     ys = jnp.zeros((scan_steps, 9), jnp.int32)

@@ -1,15 +1,16 @@
 """Layered populations (the unified engine): heterogeneous member DEPTHS and
 per-layer activations stay exactly independent under fused training, and the
-block-diagonal Pallas kernel agrees with the einsum bucket loop — values and
-gradients — over odd widths/buckets."""
+fused block-diagonal Pallas layer agrees with the einsum bucket loop plus
+bias and activation — values and gradients — over odd widths/buckets."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core.activations import ACTIVATIONS
-from repro.core.deep import (BD_IMPLS, block_diag_matmul, extract_member,
-                             forward, fused_loss, init_params, member_forward,
+from repro.core.deep import (BD_CHOICES, _act, block_diag_einsum,
+                             block_diag_fused, extract_member, forward,
+                             fused_loss, init_params, member_forward,
                              member_lr_tree, sgd_step)
 from repro.core.population import LayeredPopulation
 
@@ -55,7 +56,7 @@ def _standalone_step(member, x, y, lr):
             "w_out": new[3], "b_out": new[4], "activations": acts}
 
 
-@pytest.mark.parametrize("bd_impl", sorted(BD_IMPLS))
+@pytest.mark.parametrize("bd_impl", BD_CHOICES)
 def test_heterogeneous_depth_training_is_independent(bd_impl):
     """Fused SGD over mixed depths + per-member learning rates equals every
     member trained standalone (acceptance criterion: ≤1e-4 after ≥3 steps)."""
@@ -67,7 +68,7 @@ def test_heterogeneous_depth_training_is_independent(bd_impl):
         key, k1, k2 = jax.random.split(key, 3)
         x = jax.random.normal(k1, (16, 6))
         y = jax.random.randint(k2, (16,), 0, 3)
-        params, _, _ = sgd_step(params, x, y, lrs, LP, "bucketed", bd_impl)
+        params, _, _ = sgd_step(params, x, y, lrs, LP, bd_impl=bd_impl)
         members = [_standalone_step(mem, x, y, float(lrs[m]))
                    for m, mem in enumerate(members)]
     for m in range(LP.num_members):
@@ -94,34 +95,40 @@ def test_heterogeneous_depth_training_is_independent(bd_impl):
     (((1, 1), (2, 3), (2, 3), (6, 6)), ("relu", "relu", "tanh", "mish"), 8),
     (((11, 3, 5), (4,), (11, 3, 5)), ("gelu", "sigmoid", "gelu"), 2),
 ])
-def test_block_diag_pallas_matches_einsum(widths, acts, block):
-    """block_diag_gemm (interpret) vs the einsum reference over odd widths
-    and bucket patterns, values AND gradients, every mid layer."""
+def test_block_diag_fused_matches_einsum(widths, acts, block):
+    """The fused mid layer (interpret) vs the einsum bucket loop + bias +
+    sliced activation over odd widths and bucket patterns, values AND
+    gradients (h, weights, bias), every mid layer."""
     lp = LayeredPopulation(5, 2, widths, acts, block=block)
     params = init_params(jax.random.PRNGKey(3), lp)
     x = jax.random.normal(jax.random.PRNGKey(4), (7, 5))
     for l in range(lp.depth - 1):
-        w = params["mid"][l]["w"]
+        w, b = params["mid"][l]["w"], params["mid"][l]["b"]
         h = jax.random.normal(jax.random.PRNGKey(10 + l),
                               (7, lp.layer_pop(l).total_hidden))
-        ye = block_diag_matmul(h, w, lp, l, impl="einsum")
-        yp = block_diag_matmul(h, w, lp, l, impl="pallas")
-        np.testing.assert_allclose(np.asarray(ye), np.asarray(yp),
+
+        def ref(hh, ww, bb):
+            z = block_diag_einsum(hh, ww, lp, l) + bb * jnp.asarray(
+                lp.active_unit_mask(l + 1), jnp.float32)
+            return _act(lp, l + 1, z)
+
+        def fus(hh, ww, bb):
+            return block_diag_fused(hh, ww, lp, l, bias=bb)
+
+        np.testing.assert_allclose(np.asarray(ref(h, w, b)),
+                                   np.asarray(fus(h, w, b)),
                                    rtol=1e-5, atol=1e-6)
-
-        def loss(impl):
-            return lambda hh, ww: (
-                block_diag_matmul(hh, ww, lp, l, impl=impl) ** 2).sum()
-
-        ge = jax.grad(loss("einsum"), argnums=(0, 1))(h, w)
-        gp = jax.grad(loss("pallas"), argnums=(0, 1))(h, w)
+        ge = jax.grad(lambda *a: (ref(*a) ** 2).sum(), argnums=(0, 1, 2))(
+            h, w, b)
+        gf = jax.grad(lambda *a: (fus(*a) ** 2).sum(), argnums=(0, 1, 2))(
+            h, w, b)
         jax.tree.map(
             lambda a, b: np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-6), ge, gp)
+                np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5), ge, gf)
     # whole-network logits agreement (acceptance criterion: 1e-5)
     ye = forward(params, x, lp, bd_impl="einsum")
-    yp = forward(params, x, lp, bd_impl="pallas")
-    np.testing.assert_allclose(np.asarray(ye), np.asarray(yp),
+    yf = forward(params, x, lp, bd_impl="fused")
+    np.testing.assert_allclose(np.asarray(ye), np.asarray(yf),
                                rtol=1e-5, atol=1e-6)
 
 
@@ -135,10 +142,9 @@ def test_passthrough_slices_carry_final_activations():
     h0 = jnp.tanh(x @ params["w_in"][p0.member_slice(0)].T
                   + params["b_in"][p0.member_slice(0)])
     # run the fused stack up to the last hidden layer
-    from repro.core.deep import _act
     h = _act(lp, 0, x @ params["w_in"].T + params["b_in"])
     for l in range(lp.depth - 1):
-        h = block_diag_matmul(h, params["mid"][l]["w"], lp, l)
+        h = block_diag_einsum(h, params["mid"][l]["w"], lp, l)
         h = h + params["mid"][l]["b"] * jnp.asarray(
             lp.active_unit_mask(l + 1), h.dtype)
         h = _act(lp, l + 1, h)

@@ -10,10 +10,11 @@ import numpy as np
 import pytest
 
 from repro.core.activations import ACTIVATION_ORDER
-from repro.core.deep import init_params
-from repro.core.m3 import (FUSED_LOSS_IMPLS, LOSS_IMPLS, m3, m3_loss_head)
+from repro.core.deep import fused_loss, init_params
+from repro.core.m3 import m3, m3_loss_head
 from repro.core.population import LayeredPopulation
-from repro.kernels import block_diag, loss_head
+from repro.kernels import loss_head, tiling
+from _kernel_routing import kernel_names
 
 _WIDTHS = ((5, 3), (12, 9), (7,), (17, 9, 5), (8, 8),
            (5, 3), (3, 11, 2), (24, 16), (4,), (9, 9, 9))
@@ -32,15 +33,27 @@ def _head_inputs(b=9, seed=0):
 
 def _per_ref(h, w2, b2, y):
     """The pre-§9 XLA loss head: M3 logits in HBM + log_softmax + NLL."""
-    logits = m3(h, w2, POP, impl="bucketed") + b2
+    logits = m3(h, w2, POP) + b2
     logp = jax.nn.log_softmax(logits, axis=-1)
     nll = -jnp.take_along_axis(logp, y[:, None, None], axis=2)[:, :, 0]
     return nll.mean(axis=0)
 
 
 def test_registry():
-    assert set(LOSS_IMPLS) == {"xla", "fused"}
-    assert "fused" in FUSED_LOSS_IMPLS
+    """The fused path's loss is the loss-head kernel, both directions, and
+    its no-gradient evaluation the eval variant; the einsum path's loss
+    (m3 logits + log_softmax) launches no kernel."""
+    params = init_params(jax.random.PRNGKey(0), LP)
+    x = jax.random.normal(jax.random.PRNGKey(1), (9, LP.in_features))
+    y = jnp.zeros((9,), jnp.int32)
+
+    def loss(bd_impl):
+        return lambda p: fused_loss(p, x, y, LP, bd_impl=bd_impl)[0]
+
+    assert {"loss_head_fwd", "loss_head_bwd"} <= kernel_names(
+        jax.grad(loss("fused")), params)
+    assert "loss_head_eval" in kernel_names(loss("fused"), params)
+    assert kernel_names(jax.grad(loss("einsum")), params) == set()
 
 
 def test_per_member_loss_matches_xla():
@@ -145,7 +158,7 @@ def _tiled_inputs(o, b, seed):
 
 
 def _per_ref_pop(pop, h, w2, b2, y):
-    logits = m3(h, w2, pop, impl="bucketed") + b2
+    logits = m3(h, w2, pop) + b2
     logp = jax.nn.log_softmax(logits, axis=-1)
     nll = -jnp.take_along_axis(logp, y[:, None, None], axis=2)[:, :, 0]
     return nll.mean(axis=0)
@@ -165,7 +178,7 @@ _TILED_CASES = ([(o, b) for o in (2, 3) for b in (9, 32, 256)]
 def _tile(monkeypatch, b, g):
     """Steer the head to ``g`` blocks of 8 lanes per grid step at batch b
     (padded to 8 rows) through the bytes a step aims to read."""
-    monkeypatch.setattr(block_diag, "TILE_BYTES", -(-b // 8) * 8 * 8 * 4 * g)
+    monkeypatch.setattr(tiling, "TILE_BYTES", -(-b // 8) * 8 * 8 * 4 * g)
 
 
 @pytest.mark.parametrize("o,b", _TILED_CASES)
@@ -285,19 +298,19 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.core import deep
 from repro.core.population import LayeredPopulation
 from repro.distributed.sharding import population_shardings
-from repro.kernels import block_diag
+from repro.kernels import tiling
 from repro.launch.mesh import make_mesh
 
 B = 16
 # 8 blocks of 8 lanes per grid step at B = 16, so each shard's head runs
 # several tiles with members crossing their boundaries
-block_diag.TILE_BYTES = B * 8 * 4 * 8
+tiling.TILE_BYTES = B * 8 * 4 * 8
 # four equal quarters, so each shard owns whole members' blocks
 widths = tuple((w,) for w in (3, 12, 20, 7, 17, 24, 5, 9, 14, 2, 23, 11) * 4)
 acts = tuple(("relu", "tanh", "gelu")[i % 3] for i in range(len(widths)))
 lp = LayeredPopulation(6, 2, widths, acts, block=8).shard_pad(4)
 seg = np.asarray(lp.layer_pop(0).block_segment_ids)
-assert deep._member_sharded_unsupported(lp, "fused", None, 4) is None
+assert deep._member_sharded_unsupported(lp, 4) is None
 assert len(seg) // 4 > 16, len(seg)
 params = deep.init_params(jax.random.PRNGKey(0), lp)
 x = jax.random.normal(jax.random.PRNGKey(1), (B, 6))

@@ -1,12 +1,12 @@
-"""M3 semantics: all four implementations agree (values AND gradients) with
-a brute-force per-member loop, across hypothesis-driven layouts/dtypes."""
+"""M3 semantics: the bucketed M3 agrees (values AND gradients) with a
+brute-force per-member loop, across hypothesis-driven layouts/dtypes."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
-from repro.core.m3 import M3_IMPLS
+from repro.core.m3 import m3
 from repro.core.population import Population
 
 ACTS = st.sampled_from(["relu", "tanh", "gelu"])
@@ -31,9 +31,9 @@ def layouts(draw):
     return sizes, block, b, o
 
 
-@given(layouts(), st.sampled_from(sorted(M3_IMPLS)))
+@given(layouts())
 @settings(max_examples=40, deadline=None)
-def test_m3_matches_brute_force(layout, impl):
+def test_m3_matches_brute_force(layout):
     sizes, block, b, o = layout
     pop = Population(4, o, tuple(sizes), ("relu",) * len(sizes), block=block)
     key = jax.random.PRNGKey(hash((tuple(sizes), block, b, o)) % 2**31)
@@ -42,14 +42,18 @@ def test_m3_matches_brute_force(layout, impl):
     h = h * jnp.asarray(pop.hidden_mask)        # padding units are zero
     w2 = jax.random.normal(k2, (o, pop.total_hidden))
     want = brute_force(h, w2, pop)
-    got = M3_IMPLS[impl](h, w2, pop)
+    got = m3(h, w2, pop)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("impl", sorted(M3_IMPLS))
-def test_m3_gradients_match(impl):
-    pop = Population(4, 3, (5, 17, 2, 8), ("relu",) * 4, block=8)
+@pytest.mark.parametrize("sizes,block", [
+    ((5, 17, 2, 8), 8),
+    ((1, 9, 1, 4), 8),     # one-unit members, mostly padding
+    ((5, 17, 2, 30), 16),
+], ids=["bucketed", "one-unit", "block16"])
+def test_m3_gradients_match(sizes, block):
+    pop = Population(4, 3, sizes, ("relu",) * len(sizes), block=block)
     key = jax.random.PRNGKey(0)
     h = jax.random.normal(key, (6, pop.total_hidden)) \
         * jnp.asarray(pop.hidden_mask)
@@ -63,7 +67,7 @@ def test_m3_gradients_match(impl):
         return lambda hh, ww: (fn(hh * mask, ww, pop) ** 2).sum()
 
     want = jax.grad(loss(brute_force), argnums=(0, 1))(h, w2)
-    got = jax.grad(loss(M3_IMPLS[impl]), argnums=(0, 1))(h, w2)
+    got = jax.grad(loss(m3), argnums=(0, 1))(h, w2)
     for g, w in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                    rtol=2e-4, atol=2e-4)
@@ -75,7 +79,6 @@ def test_m3_bf16():
                           jnp.bfloat16)
     w2 = jax.random.normal(jax.random.PRNGKey(1), (2, pop.total_hidden),
                            jnp.bfloat16)
-    ys = {n: np.asarray(f(h, w2, pop), np.float32)
-          for n, f in M3_IMPLS.items()}
-    for n, y in ys.items():
-        np.testing.assert_allclose(y, ys["scatter"], rtol=5e-2, atol=5e-2)
+    got = np.asarray(m3(h, w2, pop), np.float32)
+    want = brute_force(h.astype(jnp.float32), w2.astype(jnp.float32), pop)
+    np.testing.assert_allclose(got, np.asarray(want), rtol=5e-2, atol=5e-2)

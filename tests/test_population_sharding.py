@@ -144,20 +144,44 @@ def test_make_population_train_step_donates():
         deep.make_population_train_step(LP, scan_steps=0)
 
 
-@pytest.mark.parametrize("act_impl", ["masked", "pallas"])
-def test_act_impl_matches_sliced(act_impl):
-    """seg_act Pallas dispatch (and the masked oracle) agree with the
-    sliced default — forward AND gradients, through the whole deep net."""
+def _masked_oracle_loss(params, x, y, lp):
+    """The layered forward with every activation from the branchless masked
+    select (the activation oracle) and the plain XLA projections, then the
+    per-member NLL — independent of both paths' activation code."""
+    from repro.core.activations import apply_activations_masked
+    from repro.core.m3 import m3
+
+    def act(z, l):
+        pop = lp.layer_pop(l)
+        return (apply_activations_masked(z, pop.act_ids)
+                * jnp.asarray(pop.hidden_mask))
+
+    h = act(x @ params["w_in"].T + params["b_in"], 0)
+    for l in range(lp.depth - 1):
+        z = deep.block_diag_einsum(h, params["mid"][l]["w"], lp, l)
+        h = act(z + params["mid"][l]["b"]
+                * jnp.asarray(lp.active_unit_mask(l + 1)), l + 1)
+    logits = m3(h, params["w_out"], lp.layer_pop(lp.depth - 1)) \
+        + params["b_out"][None]
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    nll = -jnp.take_along_axis(logp, y[:, None, None], axis=-1)[..., 0]
+    return nll.mean(axis=0).sum(), logits
+
+
+@pytest.mark.parametrize("bd_impl", deep.BD_CHOICES)
+def test_bd_impl_matches_masked_oracle(bd_impl):
+    """Both paths agree with the masked-activation oracle — forward AND
+    gradients, through the whole deep net."""
     params = deep.init_params(jax.random.PRNGKey(0), LP)
     x = jax.random.normal(jax.random.PRNGKey(1), (9, 6))
     y = jax.random.randint(jax.random.PRNGKey(2), (9,), 0, 3)
-    ya = deep.forward(params, x, LP, act_impl=act_impl)
-    yb = deep.forward(params, x, LP)
-    np.testing.assert_allclose(np.asarray(ya), np.asarray(yb),
+    _, want = _masked_oracle_loss(params, x, y, LP)
+    got = deep.forward(params, x, LP, bd_impl=bd_impl)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
-    ga = jax.grad(lambda p: deep.fused_loss(
-        p, x, y, LP, "bucketed", "einsum", act_impl)[0])(params)
-    gb = jax.grad(lambda p: deep.fused_loss(p, x, y, LP)[0])(params)
+    ga = jax.grad(lambda p: deep.fused_loss(p, x, y, LP,
+                                            bd_impl=bd_impl)[0])(params)
+    gb = jax.grad(lambda p: _masked_oracle_loss(p, x, y, LP)[0])(params)
     jax.tree.map(lambda a, b: np.testing.assert_allclose(
         np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5), ga, gb)
 

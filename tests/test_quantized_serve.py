@@ -35,6 +35,7 @@ from repro.launch.launch_count import (count_pallas_launches,
 from repro.launch.serve_population import PopulationServer
 from repro.quant import (dequantize_population, quantize_population,
                          serve_copy_bytes)
+from _kernel_routing import kernel_names
 
 _WIDTHS = ((5, 3), (12, 9), (7,), (17, 9, 5), (8, 8),
            (5, 3), (3, 11, 2), (24, 16), (4,), (9, 9, 9))
@@ -51,12 +52,12 @@ def _x(b=B, lp=LP):
 
 
 def _infer_int8(qp, x, lp=LP, **kw):
-    return deep.forward(qp, x, lp, bd_impl="fused", act_impl="pallas",
-                        infer=True, weights_dtype="int8", **kw)
+    return deep.forward(qp, x, lp, bd_impl="fused", infer=True,
+                        weights_dtype="int8", **kw)
 
 
 def _ref(params, x, lp=LP):
-    return deep.forward(params, x, lp, bd_impl="einsum", act_impl="sliced")
+    return deep.forward(params, x, lp, bd_impl="einsum")
 
 
 # --------------------------------------------------------------------- #
@@ -124,8 +125,7 @@ def test_int8_forward_matches_dequant_reference():
 
 def test_int8_forward_bounded_error_vs_f32_masters():
     params, x = _params(), _x()
-    y_f32 = deep.forward(params, x, LP, bd_impl="fused",
-                         act_impl="pallas", infer=True)
+    y_f32 = deep.forward(params, x, LP, bd_impl="fused", infer=True)
     y_q = _infer_int8(quantize_population(params, LP), x)
     np.testing.assert_allclose(y_q, y_f32, rtol=0.1, atol=0.5)
 
@@ -176,29 +176,29 @@ def test_int8_keeps_infer_launch_budget():
 def test_int8_requires_infer():
     qp = quantize_population(_params(), LP)
     with pytest.raises(ValueError, match="serving-only"):
-        deep.forward(qp, _x(), LP, bd_impl="fused", act_impl="pallas",
-                     weights_dtype="int8")
+        deep.forward(qp, _x(), LP, bd_impl="fused", weights_dtype="int8")
 
 
 def test_int8_not_selectable_as_bd_impl():
-    with pytest.raises(ValueError, match="weights_dtype"):
-        deep.forward(_params(), _x(), LP, bd_impl="fused_int8",
-                     act_impl="pallas", infer=True)
+    with pytest.raises(ValueError, match="bd_impl"):
+        deep.forward(_params(), _x(), LP, bd_impl="fused_int8", infer=True)
 
 
 def test_int8_head_impl_must_match():
+    """The int8 store serves only through the int8 kernels — the head
+    included — and only on the fused path."""
     qp = quantize_population(_params(), LP)
-    with pytest.raises(ValueError, match="head_impl"):
-        _infer_int8(qp, _x(), head_impl="fused")
-    with pytest.raises(ValueError, match="head_impl"):
-        deep.forward(_params(), _x(), LP, bd_impl="fused",
-                     act_impl="pallas", infer=True, head_impl="fused_int8")
+    assert kernel_names(lambda p: _infer_int8(p, _x()), qp) == {
+        "fused_input_infer_int8", "fused_mid_infer_int8", "infer_head_int8"}
+    with pytest.raises(ValueError, match="bd_impl='fused'"):
+        deep.forward(qp, _x(), LP, bd_impl="einsum", infer=True,
+                     weights_dtype="int8")
 
 
 def test_unknown_weights_dtype_rejected():
     with pytest.raises(ValueError, match="weights_dtype"):
-        deep.forward(_params(), _x(), LP, bd_impl="fused",
-                     act_impl="pallas", infer=True, weights_dtype="int4")
+        deep.forward(_params(), _x(), LP, bd_impl="fused", infer=True,
+                     weights_dtype="int4")
 
 
 # --------------------------------------------------------------------- #

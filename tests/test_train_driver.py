@@ -80,6 +80,34 @@ def test_resume_prefers_checkpoint_layout(tmp_path):
         jax.tree_util.tree_structure(params)
 
 
+def test_resume_checkpoint_naming_removed_impls(tmp_path):
+    """A checkpoint whose meta still records the removed ``act_impl`` and
+    ``m3_impl`` options resumes: the run reads only what it still has."""
+    import json
+    import pathlib
+    _run(tmp_path, steps=4, ckpt_every=2)
+    for tree in pathlib.Path(tmp_path / "ck").glob("step_*/tree.json"):
+        d = json.loads(tree.read_text())
+        d["meta"]["train"].update(act_impl="pallas", m3_impl="scatter")
+        tree.write_text(json.dumps(d))
+    params, lp = _run(tmp_path, steps=6, ckpt_every=2, extra=["--resume"])
+    assert all(np.isfinite(np.asarray(p)).all()
+               for p in jax.tree.leaves(params))
+
+
+@pytest.mark.parametrize("flag,value", [
+    ("--bd-impl", "pallas"), ("--act-impl", "pallas"),
+    ("--m3-impl", "scatter")])
+def test_driver_refuses_removed_impls(flag, value, capsys):
+    """``--bd-impl`` takes the two paths only, and the removed options are
+    gone: each is refused before anything runs, with argparse's message."""
+    with pytest.raises(SystemExit):
+        main(["--arch", "parallelmlp-10k", "--reduced", flag, value])
+    err = capsys.readouterr().err
+    assert ("invalid choice: 'pallas'" in err if flag == "--bd-impl"
+            else f"unrecognized arguments: {flag}" in err)
+
+
 def test_driver_fused_bf16_halving_with_cheap_rungs(tmp_path):
     """The fused kernel + bf16 policy + subsampled rung evals compose with
     the halving lifecycle end to end: the driver prunes on schedule, the
@@ -95,7 +123,8 @@ def test_driver_fused_bf16_halving_with_cheap_rungs(tmp_path):
     meta, _ = ckpt_mod.load_meta(str(tmp_path / "ck"))
     assert meta["train"]["compute_dtype"] == "bfloat16"
     assert meta["train"]["bd_impl"] == "fused"
-    assert meta["train"]["act_impl"] == "sliced"
+    # bd_impl is the one implementation decision a run records
+    assert "act_impl" not in meta["train"] and "m3_impl" not in meta["train"]
     # the stateful-optimizer engine records its config too (sgd default)
     assert meta["train"]["optimizer"]["name"] == "sgd"
 
